@@ -1,62 +1,52 @@
 """Persistent XLA compile-cache policy — warm start as the default.
 
-The one real-silicon datapoint (BENCH_r02) paid 108.9 s of
-warmup+compile before the first useful iteration and CPU runs pay
-~29 s, yet until this module the persistent compilation cache existed
-only in ``hostenv.cpu_child_env`` (driver helper children) and the test
-conftest: a real training or serving process recompiled every program
-from scratch. This module is the ONE place that policy lives now, and
-every program-entry boundary routes through it:
+The only chip run on record before this policy (round 2; 112 s per
+iteration; record deleted with the plug-in it was taken through) paid
+108.9 s of warm-up and compile before the first useful iteration. This
+module is the ONE place the cache policy lives, and every program-entry
+boundary routes through it:
 
 - ``Booster.__init__`` / ``engine.train`` / ``engine.cv`` (training),
 - ``serve.ModelRegistry`` / ``serve_file`` (serving),
-- ``bench.py`` measurement children and ``hostenv.cpu_child_env``.
+- ``bench.py``, ``chip_smoke.py`` and ``hostenv.cpu_child_env``.
 
+Where the environment sets ``JAX_COMPILATION_CACHE_DIR``, that directory
+is the cache and nothing here sets another: whoever runs the program
+(an operator, the chip tool) placed it, so ``tpu_compile_cache_dir``
+and ``configure("on", dir)`` give way to it. Where it is unset,
 ``configure(mode, cache_dir)`` arms ``jax.config.jax_compilation_cache_dir``:
 
 - ``auto`` (the ``tpu_compile_cache`` default): enable the cache at the
-  default directory unless something already configured one — an
-  existing ``jax.config`` setting or ``JAX_COMPILATION_CACHE_DIR`` env
-  is respected, so tests/conftest and operator overrides win.
-- ``on``: force the cache to ``cache_dir`` (or the default directory),
-  replacing any prior setting.
+  repo-local ``.jax_cache`` unless ``jax.config`` already names one
+  (tests/conftest).
+- ``on``: force the cache to ``cache_dir`` (or the repo-local
+  directory), replacing any prior setting.
 - ``off``: never touch jax config (an already-armed cache is left
   alone — "off" opts this entry point out, it does not disarm others).
 
-Directory resolution: explicit ``cache_dir`` argument >
-``LGBM_TPU_COMPILE_CACHE_DIR`` env > ``JAX_COMPILATION_CACHE_DIR`` env >
-the repo-local ``.jax_cache`` (shared with the driver's helper children
-via ``hostenv``).
+No path is ever derived from a temp name, pid or time: the directory is
+part of the cache key's lookup, so one that moves never hits.
 
-Donation policy: buffer donation SEGFAULTS on executables deserialized
-from the persistent compilation cache on jaxlib<=0.4.36. That guard
-used to live inline in ``obs/xla.instrumented_jit``; it is now the
-version-gated ``donation_allowed()`` here, shared by every program
-boundary that donates — newer jaxlibs keep donation even with the
-cache armed, affected ones drop it (donation is a memory optimisation
-only), and ``LGBM_TPU_NO_DONATE`` force-drops regardless.
+Donation policy: ``donation_allowed()`` is consulted by every program
+boundary that donates (``obs/xla.instrumented_jit``);
+``LGBM_TPU_NO_DONATE`` force-drops donation (a memory optimisation
+only).
 
 Hygiene: the cache directory grows without bound on a long-lived host
 (every shape bucket of every model adds entries). ``prune_cache()`` is
 a best-effort LRU prune to the ``LGBM_TPU_COMPILE_CACHE_MAX_BYTES``
 budget (default 4 GiB; <=0 disables), run at most once per directory
 per process, and ONLY for directories this framework owns (our knob /
-``LGBM_TPU_COMPILE_CACHE_DIR`` / the repo-local default) — an
-inherited ``JAX_COMPILATION_CACHE_DIR`` may be shared with other
-projects and is never deleted from. A pruned entry is only a future
-cache miss — XLA regenerates it — so pruning can never break a
-running process.
+the repo-local default) — a ``JAX_COMPILATION_CACHE_DIR`` from the
+environment may be shared with other projects and is never deleted
+from. A pruned entry is only a future cache miss — XLA regenerates it —
+so pruning can never break a running process.
 """
 
 from __future__ import annotations
 
 import os
 from typing import Optional
-
-# first jaxlib where donating into an executable deserialized from the
-# persistent compilation cache no longer segfaults (the 0.4.36 crash —
-# see obs/xla.py history and the tier-1 conftest notes)
-DONATION_SAFE_JAXLIB = (0, 4, 37)
 
 _DEFAULT_MAX_BYTES = 4 << 30
 
@@ -65,17 +55,16 @@ _MODES = ("auto", "on", "off")
 
 
 def repo_cache_dir() -> str:
-    """The repo-local ``.jax_cache`` shared with hostenv's children."""
+    """The checkout-local ``.jax_cache`` (git-ignored)."""
     return os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         ".jax_cache")
 
 
 def default_cache_dir() -> str:
-    """Cache directory resolution (env overrides > repo-local)."""
-    return (os.environ.get("LGBM_TPU_COMPILE_CACHE_DIR")
-            or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or repo_cache_dir())
+    """``JAX_COMPILATION_CACHE_DIR`` where the environment sets it,
+    else the repo-local directory."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or repo_cache_dir()
 
 
 def cache_active() -> bool:
@@ -89,26 +78,11 @@ def cache_active() -> bool:
         return bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
 
 
-def _jaxlib_version() -> tuple:
-    try:
-        import jaxlib
-        return tuple(int(p) for p in
-                     str(jaxlib.__version__).split(".")[:3])
-    except Exception:
-        return (0, 0, 0)
-
-
 def donation_allowed() -> bool:
     """THE donation policy for every program boundary (obs/xla's
     ``instrumented_jit`` consults this before passing donate_argnums):
-    donation is dropped when ``LGBM_TPU_NO_DONATE`` is set, or when the
-    persistent cache is armed on a jaxlib where donating into a
-    cache-deserialized executable segfaults (<= 0.4.36)."""
-    if os.environ.get("LGBM_TPU_NO_DONATE"):
-        return False
-    if not cache_active():
-        return True
-    return _jaxlib_version() >= DONATION_SAFE_JAXLIB
+    donation stays on unless ``LGBM_TPU_NO_DONATE`` is set."""
+    return not os.environ.get("LGBM_TPU_NO_DONATE")
 
 
 def configure(mode: str = "auto", cache_dir: Optional[str] = None) -> bool:
@@ -127,23 +101,21 @@ def configure(mode: str = "auto", cache_dir: Optional[str] = None) -> bool:
         mode = "auto"
     if mode == "off":
         return False
-    if mode == "auto" and cache_active():
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if mode == "auto" and not env_dir and cache_active():
         return True
-    path = cache_dir or default_cache_dir()
+    path = env_dir or cache_dir or repo_cache_dir()
     # only ever prune a directory THIS framework owns: one named by our
-    # knob/env or the repo-local default. A user-managed
-    # JAX_COMPILATION_CACHE_DIR (possibly shared across projects) is
-    # used as-is but never deleted from.
-    owned = (cache_dir is not None
-             or bool(os.environ.get("LGBM_TPU_COMPILE_CACHE_DIR"))
-             or path == repo_cache_dir())
+    # knob or the repo-local default. A JAX_COMPILATION_CACHE_DIR from
+    # the environment (possibly shared across projects) is used as-is
+    # but never deleted from.
+    owned = not env_dir
     try:
         import jax
         jax.config.update("jax_compilation_cache_dir", path)
         # cache everything, however small/fast: warm start must make
         # compile_s_total ~0, and a skipped tiny program would still
-        # recompile every process (hostenv learned this the hard way
-        # with driver-timeout rounds 3+4)
+        # recompile every process
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     except Exception:

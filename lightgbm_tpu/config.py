@@ -657,21 +657,17 @@ class Config:
     tpu_profile: str = "off"
     tpu_profile_window: int = 5
     # persistent XLA compile cache (compile_cache.py; ROADMAP item 2 —
-    # kill cold start). "auto" (default) arms
+    # kill cold start). Where the environment sets
+    # JAX_COMPILATION_CACHE_DIR that directory is the cache and neither
+    # knob places it elsewhere. Otherwise "auto" (default) arms
     # jax.config.jax_compilation_cache_dir at the train/serve entry
-    # UNLESS something already configured one (an existing jax.config
-    # setting or JAX_COMPILATION_CACHE_DIR env wins); "on" forces it to
-    # tpu_compile_cache_dir (falling back to LGBM_TPU_COMPILE_CACHE_DIR
-    # env, then the repo-local .jax_cache); "off" opts this entry point
-    # out without disarming anything. A cache-warm second process pays
-    # ~zero compile seconds for the same programs (bench.py --coldstart
-    # measures it; perf-gate check 10 caps it). Donation caveat: with
-    # the cache armed on jaxlib<=0.4.36, buffer donation is dropped at
-    # every program boundary (compile_cache.donation_allowed) — donating
-    # into a cache-deserialized executable segfaults there; set "off"
-    # to keep donation (peak-HBM) instead on those jaxlibs. Framework-
-    # owned cache dirs are LRU-pruned once per process to
-    # LGBM_TPU_COMPILE_CACHE_MAX_BYTES (default 4 GiB).
+    # UNLESS jax.config already names one; "on" forces it to
+    # tpu_compile_cache_dir (default the repo-local .jax_cache); "off"
+    # opts this entry point out without disarming anything. A
+    # cache-warm second process pays ~zero compile seconds for the same
+    # programs (bench.py --coldstart measures it; perf-gate check 10
+    # caps it). Framework-owned cache dirs are LRU-pruned once per
+    # process to LGBM_TPU_COMPILE_CACHE_MAX_BYTES (default 4 GiB).
     tpu_compile_cache: str = "auto"
     tpu_compile_cache_dir: str = ""
     # fault-tolerant training (resilience/checkpoint.py). With

@@ -31,7 +31,7 @@ common.h:980,1044; global_timer dump at src/boosting/gbdt.cpp:29):
   ``LGBM_TPU_PROFILE_DIR``) parsed into per-program device-busy
   seconds keyed to the obs tags, a profiler-free
   ``block_until_ready`` fallback for CPU CI, and the roofline layer
-  (achieved bytes/s + utilization vs ``hostenv.platform_peaks`` + a
+  (achieved bytes/s + utilization vs ``hostenv.device_peaks`` + a
   memory/compute-bound verdict per tag).
 - ``obs.flightrec`` — crash flight recorder: a bounded ring of recent
   structured events (iterations, serve outcomes, health anomalies,

@@ -383,7 +383,7 @@ def render_openmetrics(registry=None,
                                "gauge", row["achieved_bytes_per_s"],
                                labels={"tag": tag},
                                help_text="achieved HBM bytes/s per tag "
-                                         "vs hostenv.platform_peaks")
+                                         "vs hostenv.device_peaks")
                 for res, key in (("bytes", "bytes_utilization"),
                                  ("flops", "flops_utilization")):
                     if key in row:
@@ -522,7 +522,10 @@ class MetricsTextfileFlusher:
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self._last = 0.0
+        # None = never flushed: the first maybe_flush() always writes
+        # (time.monotonic() starts near 0 on a freshly booted machine,
+        # so a numeric sentinel would throttle it)
+        self._last: Optional[float] = None
         self.rearm()
 
     def rearm(self) -> None:
@@ -540,7 +543,8 @@ class MetricsTextfileFlusher:
             return False
         now = time.monotonic()
         with self._lock:
-            if not force and now - self._last < self.interval_s:
+            if (not force and self._last is not None
+                    and now - self._last < self.interval_s):
                 return False
             self._last = now
         return self.flush()

@@ -303,10 +303,8 @@ def instrumented_jit(tag: str, fn: Callable, phase: Optional[str] = None,
     # is what the profiler trace shows, so map it back to the obs tag
     global_profile.register_tag(tag, phase, getattr(fn, "__name__", tag))
     if not donation_allowed():
-        # One version-gated policy (compile_cache.donation_allowed):
-        # buffer donation segfaults on executables deserialized from the
-        # persistent compilation cache on jaxlib<=0.4.36; donation is a
-        # memory optimisation only, so affected setups drop it.
+        # one policy (compile_cache.donation_allowed): donation is a
+        # memory optimisation only, LGBM_TPU_NO_DONATE drops it
         jit_kwargs.pop("donate_argnums", None)
     jitted = jax.jit(global_metrics.wrap_traced(tag, fn), **jit_kwargs)
     compiled_cache: Dict[Any, Any] = {}

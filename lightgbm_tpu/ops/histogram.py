@@ -60,14 +60,12 @@ def _hist_all_features(bins_fm: jax.Array, gh: jax.Array, max_bins: int,
 
 
 def cpu_backend() -> bool:
-    """True when the default jax backend is CPU (or unavailable) —
-    the shared sniff for backend-dependent implementation choices.
-    Only the backend-unavailable RuntimeError maps to "cpu"; any other
-    failure is a real bug in backend sniffing and must surface."""
-    try:
-        return jax.default_backend() == "cpu"
-    except RuntimeError:  # "Unable to initialize backend ..."
-        return True
+    """True when the default jax backend is CPU — the shared sniff for
+    backend-dependent implementation choices (XLA twin and Pallas
+    interpret mode on CPU, Mosaic kernels on TPU). A backend that fails
+    to initialise raises: a broken accelerator must never read as
+    "we are on CPU" and quietly select the CPU implementations."""
+    return jax.default_backend() == "cpu"
 
 
 def default_impl() -> str:

@@ -12,6 +12,16 @@ pre-masked), output hist [F, 3, B].
 
 Grid: (feature_blocks, row_chunks); row chunks accumulate into the same
 output block (TPU grids execute sequentially, minor-dim fastest).
+
+Every per-row operand (gh channels, row->leaf ids, the fused kernel's
+score/label/weight/mask) enters the kernels LANE-DENSE, as ``[k, N]``
+with the rows on the minor axis. Mosaic lays a 2-D HBM operand out in
+(sublane, 128-lane) tiles, so an ``[N, 1]`` or ``[N, 3]`` column operand
+is padded to 128 lanes — 512 bytes per row instead of 4: at N = 10.5M
+each such operand took 5 GB of HBM and the v5e compiler refused the
+iteration program at 30.5 GB (asked without a chip, PR 21). The
+leaf-selected gh operand is therefore built transposed, ``[128, R]``,
+and contracted against the one-hot's row axis (A x B^T on the MXU).
 """
 
 from __future__ import annotations
@@ -36,6 +46,9 @@ _PRECISIONS = {
 # byte-block width of the packed kernels' grid steps; bin_pack.PACK_ALIGN
 # guarantees every packed section is a multiple of this
 _PACKED_CHUNK_BYTES = 1024
+
+# A [M, R] x B [128, R] -> [M, 128]: contract the row (lane) axis of both
+_CONTRACT_ROWS = (((1,), (1,)), ((), ()))
 
 
 def resolve_precision(precise) -> lax.Precision:
@@ -75,10 +88,32 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, f_blk: int, max_bins: int,
         out_ref[f, :, :] += jax.lax.dot(gh, onehot, precision=prec)
 
 
-def _multi_kernel(bins_ref, ghT_ref, rlT_ref, leafsel_ref, out_ref, *,
+def _leaf_bop(g, h, w, rl, leafsel_ref, int8: bool):
+    """The MXU's leaf-block-diagonal gh operand, transposed: [128, R]
+    with sublane k = (leaf k//3, channel k%3) and the chunk's R rows on
+    lanes — shared by every multi-kernel variant. g/h/w/rl: [1, R]
+    rows; leafsel_ref: [128, 1] leaf id of each sublane."""
+    r = rl.shape[1]
+    csel = lax.broadcasted_iota(jnp.int32, (128, r), 0) % 3
+    if int8:
+        # compare and select in int32, cast once: Mosaic cannot move the
+        # i1 mask of a 32-bit compare onto the int8 operand's tiling
+        g, h, w = (x.astype(jnp.int32) for x in (g, h, w))
+    gsel = jnp.where(csel == 0, g, jnp.where(csel == 1, h, w))
+    bop = jnp.where(leafsel_ref[...] == rl, gsel,
+                    jnp.zeros((), gsel.dtype))
+    return bop.astype(jnp.int8) if int8 else bop
+
+
+def _gh_rows(gh):
+    """A [3, R] (grad, hess, weight) block as its three [1, R] rows."""
+    return gh[0:1], gh[1:2], gh[2:3]
+
+
+def _multi_kernel(bins_ref, gh_ref, rl_ref, leafsel_ref, out_ref, *,
                   f_blk: int, group: int, max_bins: int, precise: bool):
     """One grid step: f_blk features' transposed one-hots ([group*B, R]
-    per dot, built in VMEM) x a shared [R, 128] leaf-selected gh operand
+    per dot, built in VMEM) x a shared [128, R] leaf-selected gh operand
     -> accumulate [f_blk*B, 128]."""
     ch = pl.program_id(1)
 
@@ -86,15 +121,10 @@ def _multi_kernel(bins_ref, ghT_ref, rlT_ref, leafsel_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    rl = rlT_ref[...]      # [R, 1] int32 row -> leaf
-    gh = ghT_ref[...]      # [R, 3] f32 (grad, hess, weight)
-    r = rl.shape[0]
-    lanes = lax.broadcasted_iota(jnp.int32, (r, 128), 1)
-    csel = lanes % 3
-    gsel = jnp.where(csel == 0, gh[:, 0:1],
-                     jnp.where(csel == 1, gh[:, 1:2], gh[:, 2:3]))
-    # leaf-block-diagonal gh operand: lane k = (leaf k//3, channel k%3)
-    bop = jnp.where(rl == leafsel_ref[...], gsel, 0.0)  # [R, 128]
+    rl = rl_ref[...]       # [1, R] int32 row -> leaf
+    r = rl.shape[1]
+    # gh block: [3, R] f32 (grad, hess, weight)
+    bop = _leaf_bop(*_gh_rows(gh_ref[...]), rl, leafsel_ref, False)
     prec = resolve_precision(precise)
 
     rows = group * max_bins
@@ -106,8 +136,21 @@ def _multi_kernel(bins_ref, ghT_ref, rlT_ref, leafsel_ref, out_ref, *,
                 riota // max_bins == p,
                 bins_ref[q * group + p, :][None, :].astype(jnp.int32), b_eff)
         onehot_t = (b_eff == riota % max_bins).astype(jnp.float32)
-        out_ref[0, q * rows:(q + 1) * rows, :] += jax.lax.dot(
-            onehot_t, bop, precision=prec)
+        out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
+            onehot_t, bop, _CONTRACT_ROWS, precision=prec)
+
+
+def _row_operand_specs(row_chunk: int):
+    """BlockSpecs of the unpacked multi kernels' operands after the bin
+    block: gh [3, N], row_leaf [1, N], leafsel [128, 1]."""
+    return [
+        pl.BlockSpec((3, row_chunk), lambda j, i: (0, i),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, row_chunk), lambda j, i: (0, i),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((128, 1), lambda j, i: (0, 0),
+                     memory_space=pltpu.VMEM),
+    ]
 
 
 @functools.partial(jax.jit,
@@ -154,13 +197,6 @@ def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
         row_leaf = jnp.pad(row_leaf, (0, pad_n), constant_values=-1)
     npad = bins_fm.shape[1]
 
-    # lane k holds leaf_ids[k//3]; lanes beyond 3*num_slots get sentinel -2
-    # (never equals a row_leaf entry, which is >= 0 or -1 padding)
-    k = jnp.arange(128)
-    leafsel = jnp.where(k < 3 * num_slots,
-                        leaf_ids[jnp.minimum(k // 3, num_slots - 1)],
-                        -2).astype(jnp.int32)[None, :]
-
     fblocks = fp // f_blk
     rows = f_blk * max_bins
     grid = (fblocks, npad // row_chunk)
@@ -168,21 +204,15 @@ def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
         functools.partial(_multi_kernel, f_blk=f_blk, group=group,
                           max_bins=max_bins, precise=precise),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((f_blk, row_chunk), lambda j, i: (j, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((row_chunk, 3), lambda j, i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((row_chunk, 1), lambda j, i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 128), lambda j, i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[pl.BlockSpec((f_blk, row_chunk), lambda j, i: (j, i),
+                               memory_space=pltpu.VMEM)]
+        + _row_operand_specs(row_chunk),
         out_specs=pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), jnp.float32),
         interpret=_resolve_interpret(interpret),
-    )(bins_fm, ghT, row_leaf[:, None].astype(jnp.int32), leafsel)
+    )(bins_fm, ghT.T, row_leaf[None, :].astype(jnp.int32),
+      _leafsel_col(leaf_ids, num_slots))
     # [fblocks, f_blk*B, 128] -> [F, B, J, 3] -> [J, F, B, 3]
     out = out[:, :, :3 * num_slots]
     out = out.reshape(fp, max_bins, num_slots, 3)
@@ -190,7 +220,7 @@ def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
     return out[:, :num_features]
 
 
-def _multi_kernel_int8(bins_ref, ghT_ref, rlT_ref, leafsel_ref, out_ref, *,
+def _multi_kernel_int8(bins_ref, gh_ref, rl_ref, leafsel_ref, out_ref, *,
                        f_blk: int, group: int, max_bins: int):
     """Integer twin of _multi_kernel: int8 one-hot x int8 leaf-selected
     quantized (grad, hess, weight) -> int32 accumulation. This is the MXU
@@ -204,15 +234,10 @@ def _multi_kernel_int8(bins_ref, ghT_ref, rlT_ref, leafsel_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    rl = rlT_ref[...]      # [R, 1] int32 row -> leaf
-    gh = ghT_ref[...]      # [R, 3] int8 (g_int, h_int, weight)
-    r = rl.shape[0]
-    lanes = lax.broadcasted_iota(jnp.int32, (r, 128), 1)
-    csel = lanes % 3
-    gsel = jnp.where(csel == 0, gh[:, 0:1],
-                     jnp.where(csel == 1, gh[:, 1:2], gh[:, 2:3]))
-    bop = jnp.where(rl == leafsel_ref[...], gsel,
-                    jnp.int8(0)).astype(jnp.int8)  # [R, 128]
+    rl = rl_ref[...]       # [1, R] int32 row -> leaf
+    r = rl.shape[1]
+    # gh block: [3, R] int8 (g_int, h_int, weight)
+    bop = _leaf_bop(*_gh_rows(gh_ref[...]), rl, leafsel_ref, True)
 
     rows = group * max_bins
     riota = lax.broadcasted_iota(jnp.int32, (rows, r), 0)
@@ -223,8 +248,8 @@ def _multi_kernel_int8(bins_ref, ghT_ref, rlT_ref, leafsel_ref, out_ref, *,
                 riota // max_bins == p,
                 bins_ref[q * group + p, :][None, :].astype(jnp.int32), b_eff)
         onehot_t = (b_eff == riota % max_bins).astype(jnp.int8)
-        out_ref[0, q * rows:(q + 1) * rows, :] += jax.lax.dot_general(
-            onehot_t, bop, (((1,), (0,)), ((), ())),
+        out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
+            onehot_t, bop, _CONTRACT_ROWS,
             preferred_element_type=jnp.int32)
 
 
@@ -264,11 +289,6 @@ def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
         row_leaf = jnp.pad(row_leaf, (0, pad_n), constant_values=-1)
     npad = bins_fm.shape[1]
 
-    k = jnp.arange(128)
-    leafsel = jnp.where(k < 3 * num_slots,
-                        leaf_ids[jnp.minimum(k // 3, num_slots - 1)],
-                        -2).astype(jnp.int32)[None, :]
-
     fblocks = fp // f_blk
     rows = f_blk * max_bins
     grid = (fblocks, npad // row_chunk)
@@ -276,21 +296,15 @@ def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
         functools.partial(_multi_kernel_int8, f_blk=f_blk, group=group,
                           max_bins=max_bins),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((f_blk, row_chunk), lambda j, i: (j, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((row_chunk, 3), lambda j, i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((row_chunk, 1), lambda j, i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 128), lambda j, i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[pl.BlockSpec((f_blk, row_chunk), lambda j, i: (j, i),
+                               memory_space=pltpu.VMEM)]
+        + _row_operand_specs(row_chunk),
         out_specs=pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), jnp.int32),
         interpret=_resolve_interpret(interpret),
-    )(bins_fm, ghT_i8, row_leaf[:, None].astype(jnp.int32), leafsel)
+    )(bins_fm, ghT_i8.T, row_leaf[None, :].astype(jnp.int32),
+      _leafsel_col(leaf_ids, num_slots))
     out = out[:, :, :3 * num_slots]
     out = out.reshape(fp, max_bins, num_slots, 3)
     out = jnp.moveaxis(out, 2, 0)
@@ -302,23 +316,10 @@ def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
 # consumes every bit-section in it, so the dominant bin read shrinks by
 # the pack factor (bin_pack.PackedBins split-section layout: byte j of a
 # section-aligned block covers rows j, j+section, ...; the v-th section's
-# gh/row_leaf operands are the same arrays blocked at section-strided
-# offsets — no lane interleave anywhere, just vpb dots per feature group)
+# gh/row_leaf operands are the same [k, N] arrays blocked at
+# section-strided offsets — no lane interleave anywhere, just vpb dots
+# per feature group)
 # ---------------------------------------------------------------------------
-def _leaf_bop(gh, rl, leafsel_ref, int8: bool):
-    """The MXU's leaf-block-diagonal gh operand [R, 128] (lane k =
-    (leaf k//3, channel k%3)) — shared by every multi-kernel variant."""
-    r = rl.shape[0]
-    lanes = lax.broadcasted_iota(jnp.int32, (r, 128), 1)
-    csel = lanes % 3
-    gsel = jnp.where(csel == 0, gh[:, 0:1],
-                     jnp.where(csel == 1, gh[:, 1:2], gh[:, 2:3]))
-    if int8:
-        return jnp.where(rl == leafsel_ref[...], gsel,
-                         jnp.int8(0)).astype(jnp.int8)
-    return jnp.where(rl == leafsel_ref[...], gsel, 0.0)
-
-
 def _accum_section_dots(bins_ref, out_ref, bops, *, f_blk: int, group: int,
                         max_bins: int, vpb: int, int8: bool, precise):
     """Accumulate all bit-sections of a packed byte block: one one-hot
@@ -327,7 +328,7 @@ def _accum_section_dots(bins_ref, out_ref, bops, *, f_blk: int, group: int,
     bits = 8 // vpb
     bmask = (1 << bits) - 1
     rows = group * max_bins
-    cb = bops[0].shape[0]
+    cb = bops[0].shape[1]
     riota = lax.broadcasted_iota(jnp.int32, (rows, cb), 0)
     prec = None if int8 else resolve_precision(precise)
     for q in range(f_blk // group):
@@ -341,12 +342,12 @@ def _accum_section_dots(bins_ref, out_ref, bops, *, f_blk: int, group: int,
             if int8:
                 onehot_t = (b_eff == riota % max_bins).astype(jnp.int8)
                 out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
-                    onehot_t, bops[v], (((1,), (0,)), ((), ())),
+                    onehot_t, bops[v], _CONTRACT_ROWS,
                     preferred_element_type=jnp.int32)
             else:
                 onehot_t = (b_eff == riota % max_bins).astype(jnp.float32)
-                out_ref[0, q * rows:(q + 1) * rows, :] += jax.lax.dot(
-                    onehot_t, bops[v], precision=prec)
+                out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
+                    onehot_t, bops[v], _CONTRACT_ROWS, precision=prec)
 
 
 def _multi_kernel_packed(bins_ref, *refs, f_blk: int, group: int,
@@ -362,8 +363,8 @@ def _multi_kernel_packed(bins_ref, *refs, f_blk: int, group: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bops = [_leaf_bop(gh_refs[v][...], rl_refs[v][...], leafsel_ref, int8)
-            for v in range(vpb)]
+    bops = [_leaf_bop(*_gh_rows(gh_refs[v][...]), rl_refs[v][...],
+                      leafsel_ref, int8) for v in range(vpb)]
     _accum_section_dots(bins_ref, out_ref, bops, f_blk=f_blk, group=group,
                         max_bins=max_bins, vpb=vpb, int8=int8,
                         precise=precise)
@@ -399,9 +400,9 @@ def _multi_kernel_fused(bins_ref, *refs, f_blk: int, group: int,
         score, label = op(0, v), op(1, v)
         weight = op(2, v) if has_weight else None
         mask, rl = op(2 + iw, v), op(3 + iw, v)
-        g, h = grad_fn(score, label, weight)
-        gh = jnp.concatenate([g * mask, h * mask, mask], axis=1)  # [R, 3]
-        bops.append(_leaf_bop(gh, rl, leafsel_ref, False))
+        g, h = grad_fn(score, label, weight)  # [1, cb] rows
+        bops.append(_leaf_bop(g * mask, h * mask, mask, rl, leafsel_ref,
+                              False))
     _accum_section_dots(bins_ref, out_ref, bops, f_blk=f_blk, group=group,
                         max_bins=max_bins, vpb=vpb, int8=False,
                         precise=precise)
@@ -414,11 +415,14 @@ def _fb_geometry(num_features: int, max_bins: int):
     return group, f_blk
 
 
-def _leafsel_row(leaf_ids, num_slots: int):
+def _leafsel_col(leaf_ids, num_slots: int):
+    """[128, 1] leaf id of each MXU output column: sublane k holds
+    leaf_ids[k//3]; the ones beyond 3*num_slots get sentinel -2 (never
+    equals a row_leaf entry, which is >= 0 or -1 padding)."""
     k = jnp.arange(128)
     return jnp.where(k < 3 * num_slots,
                      leaf_ids[jnp.minimum(k // 3, num_slots - 1)],
-                     -2).astype(jnp.int32)[None, :]
+                     -2).astype(jnp.int32)[:, None]
 
 
 def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
@@ -426,13 +430,13 @@ def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
                        interpret):
     """Shared pallas_call plumbing of the packed multi kernels.
 
-    row_vecs: list of ([N] array, pad_value, block_width) triples; each
-    becomes vpb operands blocked at section-strided offsets so grid step
-    i sees the rows matching byte block i's bit-sections.
-    Returns (call_output [fblocks, f_blk*B, 128], kernel kwargs dict).
+    row_vecs: list of ([N] or [k, N] array, pad_value) pairs; each
+    becomes vpb lane-dense operands blocked at section-strided offsets
+    so grid step i sees the rows matching byte block i's bit-sections.
+    Returns the histograms [num_slots, F, B, 3].
     """
     num_features = pb.data.shape[0]
-    vpb, sec, n = pb.vpb, pb.section, pb.num_data
+    vpb, sec = pb.vpb, pb.section
     group, f_blk = _fb_geometry(num_features, max_bins)
     data = pb.data
     pad_f = (-num_features) % f_blk
@@ -444,29 +448,23 @@ def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
     nsb = sec // cb
     n_rows = vpb * sec
 
-    padded = []
-    for vec, pad_val, width in row_vecs:
-        v2 = vec.reshape(-1, width) if vec.ndim == 2 else vec[:, None]
-        pad_n = n_rows - v2.shape[0]
-        padded.append(jnp.pad(v2, ((0, pad_n), (0, 0)),
-                              constant_values=pad_val))
-    leafsel = _leafsel_row(leaf_ids, num_slots)
-
     in_specs = [pl.BlockSpec((f_blk, cb), lambda j, i: (j, i),
                              memory_space=pltpu.VMEM)]
     operands = [data]
     # operand-major layout (all of operand k's sections consecutively) —
     # the kernels index refs[k * vpb + v]
-    for arr in padded:
-        width = arr.shape[1]
+    for vec, pad_val in row_vecs:
+        arr = vec[None, :] if vec.ndim == 1 else vec
+        arr = jnp.pad(arr, ((0, 0), (0, n_rows - arr.shape[1])),
+                      constant_values=pad_val)
         for v in range(vpb):
             in_specs.append(pl.BlockSpec(
-                (cb, width), lambda j, i, v=v: (i + v * nsb, 0),
+                (arr.shape[0], cb), lambda j, i, v=v: (0, i + v * nsb),
                 memory_space=pltpu.VMEM))
             operands.append(arr)
-    in_specs.append(pl.BlockSpec((1, 128), lambda j, i: (0, 0),
+    in_specs.append(pl.BlockSpec((128, 1), lambda j, i: (0, 0),
                                  memory_space=pltpu.VMEM))
-    operands.append(leafsel)
+    operands.append(_leafsel_col(leaf_ids, num_slots))
 
     fblocks = fp // f_blk
     rows = f_blk * max_bins
@@ -490,11 +488,11 @@ def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
 def _hist_multi_packed_f32(pb, ghT, row_leaf, leaf_ids, *, max_bins: int,
                            num_slots: int, precise="highest",
                            interpret=None):
-    rl = row_leaf[:, None].astype(jnp.int32)
     kern = functools.partial(_multi_kernel_packed, int8=False,
                              precise=precise)
     return _packed_multi_call(
-        pb, [(ghT, 0.0, 3), (rl, -1, 1)], leaf_ids, kern,
+        pb, [(ghT.T, 0.0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
+        kern,
         max_bins=max_bins, num_slots=num_slots, out_dtype=jnp.float32,
         interpret=interpret)
 
@@ -503,10 +501,10 @@ def _hist_multi_packed_f32(pb, ghT, row_leaf, leaf_ids, *, max_bins: int,
                                              "interpret"))
 def _hist_multi_packed_int8(pb, ghT_i8, row_leaf, leaf_ids, *,
                             max_bins: int, num_slots: int, interpret=None):
-    rl = row_leaf[:, None].astype(jnp.int32)
     kern = functools.partial(_multi_kernel_packed, int8=True, precise=None)
     return _packed_multi_call(
-        pb, [(ghT_i8, 0, 3), (rl, -1, 1)], leaf_ids, kern,
+        pb, [(ghT_i8.T, 0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
+        kern,
         max_bins=max_bins, num_slots=num_slots, out_dtype=jnp.int32,
         interpret=interpret)
 
@@ -529,29 +527,21 @@ def hist_pallas_multi_fused(bins_fm, score, label, weight, mask, row_leaf,
     has_weight = weight is not None
     kern0 = functools.partial(_multi_kernel_fused, precise=precise,
                               grad_fn=grad_fn, has_weight=has_weight)
-    vecs = [(score.astype(jnp.float32), 0.0, 1),
-            (label.astype(jnp.float32), 0.0, 1)]
+    vecs = [(score.astype(jnp.float32), 0.0),
+            (label.astype(jnp.float32), 0.0)]
     if has_weight:
-        vecs.append((weight.astype(jnp.float32), 0.0, 1))
-    vecs.append((mask.astype(jnp.float32), 0.0, 1))
-    if isinstance(bins_fm, PackedBins):
-        rl = row_leaf[:, None].astype(jnp.int32)
-        return _packed_multi_call(
-            bins_fm, vecs + [(rl, -1, 1)], leaf_ids, kern0,
-            max_bins=max_bins, num_slots=num_slots, out_dtype=jnp.float32,
-            interpret=interpret)
-    # unpacked: wrap the raw matrix as a vpb=1 "packed" layout — the
-    # kernel's shift-0/mask-255 section loop is then the identity
-    n = bins_fm.shape[1]
-    cb = _PACKED_CHUNK_BYTES
-    sec = -(-n // cb) * cb
-    data = jnp.pad(bins_fm, ((0, 0), (0, sec - n)))
-    pb1 = PackedBins(data, n, 1)
-    rl = row_leaf[:, None].astype(jnp.int32)
+        vecs.append((weight.astype(jnp.float32), 0.0))
+    vecs.append((mask.astype(jnp.float32), 0.0))
+    vecs.append((row_leaf.astype(jnp.int32), -1))
+    if not isinstance(bins_fm, PackedBins):
+        # unpacked: wrap the raw matrix as a vpb=1 "packed" layout — the
+        # kernel's shift-0/mask-255 section loop is then the identity
+        n = bins_fm.shape[1]
+        sec = -(-n // _PACKED_CHUNK_BYTES) * _PACKED_CHUNK_BYTES
+        bins_fm = PackedBins(jnp.pad(bins_fm, ((0, 0), (0, sec - n))), n, 1)
     return _packed_multi_call(
-        pb1, vecs + [(rl, -1, 1)], leaf_ids, kern0,
-        max_bins=max_bins, num_slots=num_slots, out_dtype=jnp.float32,
-        interpret=interpret)
+        bins_fm, vecs, leaf_ids, kern0, max_bins=max_bins,
+        num_slots=num_slots, out_dtype=jnp.float32, interpret=interpret)
 
 
 def _hist_kernel_packed(bins_ref, *refs, f_blk: int, max_bins: int,

@@ -314,11 +314,26 @@ class EnsemblePacker:
         has_cat = bool(np.any(self._arrs["cat_nwords"]))
         self._cached = PackedEnsemble(
             **{f: jnp.asarray(self._arrs[f]) if f != "threshold"
-               else jnp.asarray(self._arrs[f], jnp.float32)
+               else jnp.asarray(_f32_floor(self._arrs[f]))
                for f in _ARRAY_FIELDS},
             max_depth=int(depth), num_trees_per_class=k, num_trees=t,
             has_categorical=has_cat)
         return self._cached
+
+
+def _f32_floor(threshold) -> np.ndarray:
+    """Largest float32 <= threshold, elementwise. The model defines a
+    numeric split as ``value <= threshold`` in float64; for a value that
+    is float32-representable that holds exactly when ``value <=
+    _f32_floor(threshold)``, so the device's f32 compare routes such
+    rows as the host tree walk does. Round-to-nearest flips the rows
+    that sit on a threshold which rounds UP (a midpoint of two adjacent
+    float32 values — found by chip_smoke.py: 1 training row in 200k)."""
+    t = np.asarray(threshold, np.float64)
+    with np.errstate(over="ignore"):  # beyond float32: inf, then stepped down
+        f = t.astype(np.float32)
+    return np.where(f.astype(np.float64) > t,
+                    np.nextafter(f, np.float32(-np.inf)), f)
 
 
 def pack_ensemble(trees: List, num_tree_per_iteration: int = 1
@@ -442,7 +457,7 @@ def _shap_merge_elements(tr, occs):
             el["oor_follows"] &= (not went_left)
         else:
             # f32 threshold compare, matching the device traversal pack
-            thr = float(np.float32(tr.threshold[node]))
+            thr = float(_f32_floor(tr.threshold[node]))
             if went_left:
                 el["hi"] = min(el["hi"], thr)
             else:

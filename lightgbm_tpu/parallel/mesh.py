@@ -30,21 +30,10 @@ _active_mesh: Optional[Mesh] = None
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """`shard_map` across jax versions: new jax exposes `jax.shard_map`
-    (replication check flag `check_vma`), older releases only
-    `jax.experimental.shard_map.shard_map` (`check_rep`). Every
+    """`jax.shard_map` with the replication check off — every
     shard_mapped program in this framework goes through here."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:  # pre-rename flag spelling
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def get_mesh(num_shards: int = 0, devices=None) -> Mesh:

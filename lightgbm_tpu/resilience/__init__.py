@@ -13,7 +13,7 @@
   (``tpu_watchdog_deadline_s``): a hung peer becomes a structured
   ``PeerLostError`` + checkpoint + ``EXIT_PREEMPTED`` instead of an
   infinite collective stall.
-- ``errors`` — the structured exception taxonomy
+- ``errors`` — the structured exception hierarchy
   (``CorruptModelError`` and friends).
 """
 

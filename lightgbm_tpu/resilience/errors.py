@@ -1,4 +1,4 @@
-"""Structured error taxonomy of the resilience layer.
+"""Structured error hierarchy of the resilience layer.
 
 Dependency-free on purpose: ``model_io`` (corrupt-model detection),
 ``serve/`` (degradation paths) and ``resilience/checkpoint.py`` all

@@ -51,7 +51,8 @@ import numpy as np
 from ..obs.metrics import global_metrics
 
 # on-disk format version: bump when the payload layout below changes
-ARTIFACT_VERSION = 1
+# (2: the payload records the ids of the devices it was compiled for)
+ARTIFACT_VERSION = 2
 
 
 def serialize_available() -> bool:
@@ -145,16 +146,22 @@ class ArtifactStore:
         The payload is VALIDATED by deserializing it back before it is
         written: some backend/executable combinations serialize without
         error but produce a blob that cannot load (e.g. an executable
-        that itself came out of the XLA disk cache re-serializes with
-        dangling fusion symbols on jaxlib<=0.4.36). A store must never
+        that itself came out of the XLA disk cache can re-serialize with
+        dangling fusion symbols). A store must never
         publish an artifact a restarted replica would trip over —
         counted under ``serve/aot_export_failures``."""
         try:
             from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = se.serialize(compiled)
-            se.deserialize_and_load(payload, in_tree, out_tree)
+            # deserialize_and_load places the executable on EVERY local
+            # device unless told otherwise; a single-device program then
+            # fails at call time on any host with more than one
+            devices = compiled.runtime_executable().local_devices()
+            se.deserialize_and_load(payload, in_tree, out_tree,
+                                    execution_devices=devices)
             blob = pickle.dumps({"key": key, "payload": payload,
-                                 "in_tree": in_tree, "out_tree": out_tree},
+                                 "in_tree": in_tree, "out_tree": out_tree,
+                                 "device_ids": [d.id for d in devices]},
                                 protocol=pickle.HIGHEST_PROTOCOL)
             os.makedirs(self.root, exist_ok=True)
             fd, tmp = tempfile.mkstemp(dir=self.root, suffix=".tmp")
@@ -192,8 +199,11 @@ class ArtifactStore:
             # foreign executable into this model
             if rec.get("key") != key:
                 raise ValueError("artifact fingerprint mismatch")
+            import jax
+            by_id = {d.id: d for d in jax.devices()}
             compiled = se.deserialize_and_load(
-                rec["payload"], rec["in_tree"], rec["out_tree"])
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=[by_id[i] for i in rec["device_ids"]])
         except Exception:
             global_metrics.inc_counter("serve/aot_load_failures")
             return None

@@ -41,7 +41,7 @@ The replica subprocess entry (``python -m lightgbm_tpu.serve.fleet
 (``registry_from_config`` + ``server_from_config``) and adds a
 ``POST /predict`` endpoint next to the stock /metrics, /healthz,
 /readyz — raw float64 bytes in, raw float64 bytes out, shape in
-headers, errors mapped back to the structured resilience taxonomy.
+headers, errors mapped back to the structured resilience errors.
 """
 
 from __future__ import annotations
@@ -65,7 +65,7 @@ from .server import ModelServer
 
 # replica-side error -> HTTP status + X-Error header; router-side the
 # same table maps the header back to the structured exception, so the
-# taxonomy survives the process boundary
+# hierarchy survives the process boundary
 _ERROR_STATUS = {"ServerOverloaded": 503, "CircuitOpenError": 503,
                  "DeadlineExceeded": 504, "TransientServeError": 500}
 _ERROR_CLASS = {"ServerOverloaded": ServerOverloaded,

@@ -1,0 +1,167 @@
+"""The v5e compiler's verdict on every Pallas histogram kernel, without
+a chip.
+
+Interpret mode (what every other test of ops/pallas_histogram.py runs)
+checks the arithmetic; it cannot see what Mosaic refuses: the int8
+kernels passed every interpret-mode test and could not compile (an i1
+mask layout change), the 2-bit fused kernel overran scoped VMEM. The TPU
+compiler is installed here and compiles for a chip that is described
+and not attached, so each entry point is AOT-compiled at the flagship
+widths (F=28, N=2^20) and must come back as a Mosaic custom call.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: describing it loads libtpu, which one process at a time may hold,
+so it must not happen at import/collection (every xdist worker imports
+every file) and these tests must stay in this one file. A compile that
+passes is not a chip run — chip_smoke.py is that.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from lightgbm_tpu.ops import pallas_histogram as ph
+from lightgbm_tpu.ops.bin_pack import PACK_ALIGN, PackedBins
+
+F, N, SLOTS = 28, 1 << 20, 42  # Higgs width, 2^20 rows, one full wave
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A program compiled for a described chip is written to the
+    persistent cache but cannot be read back without one: the next run
+    would warn and recompile. Keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _binary_grad(score, label, weight):
+    """Shape of objectives.Binary's pointwise gradient (sigmoid)."""
+    p = jax.nn.sigmoid(score)
+    g, h = p - label, p * (1.0 - p)
+    if weight is not None:
+        g, h = g * weight, h * weight
+    return g, h
+
+
+def _compiles_to_mosaic(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _shapes(one_chip):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return s
+
+
+def _bins(s, n, max_bins, vpb=1):
+    """[F, n] bin ids as a shape on the described chip: raw uint8/uint16
+    for vpb=1, else PackedBins with vpb values per byte."""
+    if vpb == 1:
+        return s((F, n), jnp.uint8 if max_bins <= 256 else jnp.uint16)
+    section = -(-n // vpb)
+    section = -(-section // PACK_ALIGN) * PACK_ALIGN
+    return PackedBins(s((F, section), jnp.uint8), n, vpb)
+
+
+def _kernel(kind, s, bins, n, max_bins, precise="default"):
+    """(function, operand shapes) of one Pallas entry point."""
+    vec, rl = s((n,), jnp.float32), s((n,), jnp.int32)
+    ids = s((SLOTS,), jnp.int32)
+    if kind == "single":
+        return (functools.partial(ph.hist_pallas, max_bins=max_bins,
+                                  precise=precise, interpret=False),
+                (bins, s((3, n), jnp.float32)))
+    if kind == "multi":
+        return (functools.partial(ph.hist_pallas_multi, max_bins=max_bins,
+                                  num_slots=SLOTS, precise=precise,
+                                  interpret=False),
+                (bins, s((n, 3), jnp.float32), rl, ids))
+    if kind == "fused":  # the default TPU path for objective=binary
+        return (functools.partial(ph.hist_pallas_multi_fused,
+                                  grad_fn=_binary_grad, max_bins=max_bins,
+                                  num_slots=SLOTS, precise=precise,
+                                  interpret=False),
+                (bins, vec, vec, None, vec, rl, ids))
+    assert kind == "int8"  # use_quantized_grad's kernel
+    return (functools.partial(ph.hist_pallas_multi_int8, max_bins=max_bins,
+                              num_slots=SLOTS, interpret=False),
+            (bins, s((n, 3), jnp.int8), rl, ids))
+
+
+# (static bin count B, values per byte). B is max(num_bins) over the
+# features, so max_bin=63/255/15/3 really run B=63/255/15/3 — odd tile
+# heights (2*63=126 rows per dot) the compiler must take — next to the
+# power-of-two widths above them. 15 and 3 are the widest counts that
+# bit-pack (bin_pack.pack_vpb): 4-bit and 2-bit PackedBins.
+WIDTHS = [pytest.param(b, vpb, id=f"B{b}" + (f"-{8 // vpb}bit" if vpb > 1
+                                               else ""))
+          for b, vpb in ((63, 1), (64, 1), (255, 1), (256, 1),
+                         (15, 2), (16, 2), (3, 4), (4, 4))]
+
+
+@pytest.mark.parametrize("max_bins,vpb", WIDTHS)
+@pytest.mark.parametrize("kind", ["single", "multi", "fused", "int8"])
+def test_kernel_is_accepted(one_chip, kind, max_bins, vpb):
+    """At the parent of PR 21 every int8 case was refused ("Non-singleton
+    logical dimension is replicated in destination but not in source for
+    'vector<2048x128xi1>'", until _leaf_bop compared and selected in
+    int32) and fused-B4-2bit overran the 16 MiB of scoped VMEM."""
+    s = _shapes(one_chip)
+    fn, args = _kernel(kind, s, _bins(s, N, max_bins, vpb), N, max_bins)
+    _compiles_to_mosaic(fn, *args)
+
+
+@pytest.mark.parametrize("kind", ["single", "multi", "int8"])
+def test_uint16_bins(one_chip, kind):
+    """max_bin > 256 stores uint16 bin ids (the fused kernel refuses
+    them by assertion and the learner keeps them off it)."""
+    s = _shapes(one_chip)
+    fn, args = _kernel(kind, s, _bins(s, N, 300), N, 300)
+    _compiles_to_mosaic(fn, *args)
+
+
+@pytest.mark.parametrize("kind", ["multi", "fused"])
+def test_highest_precision(one_chip, kind):
+    """tpu_hist_precision=highest (6 MXU passes) at the flagship width:
+    the f32-faithful setting chip_smoke.py's XLA reference uses."""
+    s = _shapes(one_chip)
+    fn, args = _kernel(kind, s, _bins(s, N, 63), N, 63, precise="highest")
+    _compiles_to_mosaic(fn, *args)
+
+
+@pytest.mark.parametrize("kind", ["multi", "fused", "int8"])
+def test_row_operands_stay_lane_dense_at_higgs_rows(one_chip, kind):
+    """Mosaic tiles a 2-D HBM operand (sublane, 128 lanes): a per-row
+    operand shaped [N, 1] or [N, 3] costs 512 bytes a row — 5 GB each
+    at the flagship's 10.5M rows, where the compiler refused the whole
+    iteration program at 30.5 GB (PR 21). Lane-dense [k, N] operands
+    keep one pass's buffers near the algorithm's own bytes: the padded
+    bin copy (336 MB) plus 42 MB per f32 row vector."""
+    n = 10_500_000
+    s = _shapes(one_chip)
+    fn, args = _kernel(kind, s, _bins(s, n, 63), n, 63)
+    mem = jax.jit(fn).lower(*args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem

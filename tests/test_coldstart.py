@@ -4,8 +4,8 @@ serialized AOT serving artifacts.
 Covers:
 - ``compile_cache`` policy semantics: auto respects an existing
   configuration (the conftest's), off never touches jax config, on
-  forces a directory; the version-gated donation guard
-  (``donation_allowed``) and the env force-off;
+  forces a directory, JAX_COMPILATION_CACHE_DIR outranks all of them;
+  the donation policy (``donation_allowed``: on unless env force-off);
 - cache hygiene: the LRU prune caps the directory, oldest entries
   first, env-tunable, unbounded = no-op;
 - serialized artifacts (serve/artifacts.py): export/restore round trip
@@ -91,33 +91,51 @@ class TestCompileCachePolicy:
     def test_cache_active_reports_jax_config(self):
         assert compile_cache.cache_active() is True  # conftest armed it
 
-    def test_donation_env_force_off(self, monkeypatch):
-        monkeypatch.setenv("LGBM_TPU_NO_DONATE", "1")
-        assert compile_cache.donation_allowed() is False
-
-    def test_donation_version_gate(self, monkeypatch):
-        monkeypatch.delenv("LGBM_TPU_NO_DONATE", raising=False)
-        # cache is active (conftest): affected jaxlib drops donation,
-        # a fixed one keeps it
-        monkeypatch.setattr(compile_cache, "_jaxlib_version",
-                            lambda: (0, 4, 36))
-        assert compile_cache.donation_allowed() is False
-        monkeypatch.setattr(compile_cache, "_jaxlib_version",
-                            lambda: (0, 4, 38))
-        assert compile_cache.donation_allowed() is True
-        # no cache => donation always allowed
-        monkeypatch.setattr(compile_cache, "cache_active", lambda: False)
-        monkeypatch.setattr(compile_cache, "_jaxlib_version",
-                            lambda: (0, 4, 30))
-        assert compile_cache.donation_allowed() is True
+    @pytest.mark.parametrize("cache_on", [True, False])
+    @pytest.mark.parametrize("env_off", [True, False])
+    def test_donation_policy(self, monkeypatch, cache_on, env_off):
+        # donation no longer depends on the cache (the jaxlib<=0.4.36
+        # crash it was gated on is gone with that jaxlib): only the env
+        # force-off drops it
+        monkeypatch.setattr(compile_cache, "cache_active",
+                            lambda: cache_on)
+        if env_off:
+            monkeypatch.setenv("LGBM_TPU_NO_DONATE", "1")
+        else:
+            monkeypatch.delenv("LGBM_TPU_NO_DONATE", raising=False)
+        assert compile_cache.donation_allowed() is (not env_off)
 
     def test_default_dir_resolution(self, monkeypatch):
-        monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE_DIR", "/tmp/xyz_cc")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/xyz_cc")
         assert compile_cache.default_cache_dir() == "/tmp/xyz_cc"
-        monkeypatch.delenv("LGBM_TPU_COMPILE_CACHE_DIR")
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert compile_cache.default_cache_dir() == \
             compile_cache.repo_cache_dir()
+
+    def test_env_dir_outranks_knob_and_mode(self, monkeypatch, tmp_path):
+        # where JAX_COMPILATION_CACHE_DIR is set, that directory is the
+        # cache: neither "on" + tpu_compile_cache_dir nor an earlier
+        # jax.config setting may place it elsewhere
+        import jax
+        before = jax.config.jax_compilation_cache_dir
+        env_dir = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        try:
+            for mode, knob in (("on", str(tmp_path / "knob")),
+                               ("auto", None)):
+                assert compile_cache.configure(mode, knob) is True
+                assert jax.config.jax_compilation_cache_dir == env_dir
+            assert not (tmp_path / "knob").exists()
+        finally:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            compile_cache.configure("on", before)
+
+    def test_conftest_cache_is_not_a_temp_path(self):
+        # the test cache is the env's directory or the checkout-local
+        # one — never derived from a temp name, pid or time
+        import jax
+        assert jax.config.jax_compilation_cache_dir == \
+            compile_cache.default_cache_dir()
 
     def test_knob_aliases(self):
         cfg = Config.from_params({"compile_cache": "off",
@@ -318,9 +336,29 @@ class TestSecondProcessWarmStart:
 
     def test_bench_mode_registered(self):
         import bench as bench_mod
-        assert bench_mod.parse_bench_mode(["--coldstart"], {}) == \
-            "coldstart"
+        assert bench_mod.parse_bench_mode(["--coldstart"]) == "coldstart"
         assert "coldstart" in bench_mod._MODE_MEASURE
+
+    def test_parent_imports_initialise_no_backend(self):
+        """A chip belongs to one process at a time, so the --coldstart
+        parent must reach its two children without having initialised a
+        backend: everything _measure_coldstart imports first is
+        import-only (bench.py says so in a comment; this holds it)."""
+        import subprocess
+        from lightgbm_tpu.hostenv import cpu_child_env
+        code = (
+            "import sys; sys.path.insert(0, sys.argv[1])\n"
+            "import jax, bench\n"
+            "from lightgbm_tpu.model_io import LoadedModel\n"
+            "from lightgbm_tpu.obs.metrics import global_metrics\n"
+            "from lightgbm_tpu.serve import (ModelRegistry, ModelServer,"
+            " SERVE_LOWLAT_TAG, serialize_available)\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n")
+        out = subprocess.run([sys.executable, "-c", code, REPO],
+                             env=cpu_child_env(), capture_output=True,
+                             text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
 
 
 class TestGateCheck10:

@@ -268,9 +268,9 @@ def test_check_metrics_endpoint_smoke():
 
 # ---------------------------------------------------------------------------
 def test_bench_partial_obs_line_on_failed_attempt(monkeypatch, capsys):
-    """bench.py satellite: a failed child attempt emits its partial obs
-    phase summary + compile attribution as one stderr comment line the
-    parent's spam filter forwards (the old path dropped it)."""
+    """bench.py satellite: a failed measurement emits its partial obs
+    phase summary + compile attribution as one stderr comment line
+    before the failure propagates (bench.main re-raises: exit != 0)."""
     import json
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
@@ -283,7 +283,7 @@ def test_bench_partial_obs_line_on_failed_attempt(monkeypatch, capsys):
     try:
         with global_tracer.span("train/doomed"):
             pass
-        bench._emit_partial_obs("train", RuntimeError("relay died"))
+        bench._emit_partial_obs("train", RuntimeError("chip lost"))
     finally:
         if not was:
             global_tracer.disable()
@@ -295,8 +295,14 @@ def test_bench_partial_obs_line_on_failed_attempt(monkeypatch, capsys):
     assert len(lines) == 1
     rec = json.loads(lines[0][len("# obs-partial: "):])
     assert rec["partial"] is True
-    assert "relay died" in rec["error"]
+    assert "chip lost" in rec["error"]
     assert rec["metric"] == "boosting_iters_per_sec_higgs_shape"
     assert "train/doomed" in rec["phases"]
-    # the line survives the parent's stderr spam filter
-    assert not bench._STDERR_SPAM.match(lines[0])
+    # ... and main() lets the failure out instead of printing a result
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    monkeypatch.setitem(bench._MODE_MEASURE, "train",
+                        lambda: (_ for _ in ()).throw(
+                            RuntimeError("chip lost")))
+    with pytest.raises(RuntimeError, match="chip lost"):
+        bench.main()
+    assert capsys.readouterr().out == ""

@@ -214,15 +214,21 @@ class TestProfileWindow:
 
 # ---------------------------------------------------------------------------
 class TestRoofline:
-    def test_platform_peaks_table_and_env_override(self, monkeypatch):
-        from lightgbm_tpu.hostenv import platform_peaks
-        cpu, tpu = platform_peaks("cpu"), platform_peaks("tpu")
-        assert 0 < cpu["flops_per_s"] < tpu["flops_per_s"]
-        assert 0 < cpu["bytes_per_s"] < tpu["bytes_per_s"]
-        assert platform_peaks("unknown") == tpu  # conservative default
+    def test_device_peaks_table_and_env_override(self, monkeypatch):
+        from lightgbm_tpu.hostenv import device_peaks
+        cpu, v5e = device_peaks("cpu"), device_peaks("TPU v5 lite")
+        assert 0 < cpu["flops_per_s"] < v5e["flops_per_s"]
+        assert 0 < cpu["bytes_per_s"] < v5e["bytes_per_s"]
+        # the published v5e peaks (Google Cloud documentation, "TPU v5e")
+        assert v5e == {"flops_per_s": 1.97e14, "int8_ops_per_s": 3.93e14,
+                       "bytes_per_s": 8.19e11}
+        # a device that is not in the table is an error, not a default
+        for kind in ("tpu", "TPU v4", "unknown"):
+            with pytest.raises(KeyError, match="no roofline peaks"):
+                device_peaks(kind)
         monkeypatch.setenv("LGBM_TPU_PEAK_FLOPS", "1e9")
         monkeypatch.setenv("LGBM_TPU_PEAK_BYTES_PER_S", "2e9")
-        over = platform_peaks("cpu")
+        over = device_peaks("cpu")
         assert over["flops_per_s"] == pytest.approx(1e9)
         assert over["bytes_per_s"] == pytest.approx(2e9)
 
@@ -240,7 +246,7 @@ class TestRoofline:
         fn(a)
         global_profile.stop_window()
         rl = global_profile.roofline(
-            platform="cpu",
+            device_kind="cpu",
             peaks={"bytes_per_s": 1e10, "flops_per_s": 1e11})
         row = rl["by_tag"]["test/roofline_mm"]
         assert row["device_s"] > 0 and row["calls"] == 1
@@ -257,8 +263,8 @@ class TestRoofline:
         global_profile.reset()
         global_profile.start_window()
         rl_empty = global_profile.roofline(
-            platform="cpu", peaks={"bytes_per_s": 1.0,
-                                   "flops_per_s": 1.0})
+            device_kind="cpu", peaks={"bytes_per_s": 1.0,
+                                      "flops_per_s": 1.0})
         global_profile.stop_window()
         assert rl_empty["by_tag"] == {}
 

@@ -17,7 +17,7 @@ device capture feeds:
 2. **Roofline** — the measured-vs-peak join must carry a valid
    memory-bound/compute-bound verdict per attributed tag, and (CPU
    exposes cost analysis) at least one tag must join achieved bytes/s
-   + utilization against the hostenv.platform_peaks row.
+   + utilization against the hostenv.device_peaks row.
 3. **OpenMetrics egress** — render_openmetrics() must surface every
    ``lgbmtpu_profile_*`` family, lint clean line-by-line
    (check_metrics_endpoint.validate_exposition), and stay
@@ -145,7 +145,7 @@ def main() -> int:
             and peaks.get("flops_per_s", 0) > 0):
         return _fail(f"roofline peaks row is degenerate: {peaks}")
     print(f"# roofline: {len(joined)}/{len(rl['by_tag'])} tag(s) "
-          f"joined vs {rl['platform']} peaks: OK")
+          f"joined vs {rl['device_kind']} peaks: OK")
 
     # --- 3. OpenMetrics families -------------------------------------
     text = render_openmetrics()
