@@ -73,6 +73,7 @@ def _nonfinite_counts(grad, hess, scores):
     return jnp.stack([cnt(grad), cnt(hess), cnt(scores)])
 
 
+@jax.named_scope("lgbm/records")
 def _stack_class_records(recs):
     """[K] per-class TreeArrays -> one TreeArrays with a leading class
     axis (traced; used inside the fused programs)."""
@@ -826,6 +827,7 @@ class GBDT:
                          and self.num_data < (1 << 24))
         return not renews or renews_traced
 
+    @jax.named_scope("lgbm/gradient")
     def _grad_fn(self, scores):
         """Traced gradient computation [K, N] (ref: GBDT::Boosting)."""
         obj = self.objective
@@ -850,6 +852,7 @@ class GBDT:
         """Bool [n] marking real rows (False on the padded tail)."""
         return jnp.arange(n) < self.num_data
 
+    @jax.named_scope("lgbm/sample")
     def _sampling_in_jit(self, key, it, prev_mask):
         """Bagging mask (traced; ref: bagging.hpp Bagging)."""
         cfg = self.config
@@ -872,6 +875,7 @@ class GBDT:
         resample = (it % cfg.bagging_freq) == 0
         return jnp.where(resample, fresh, prev_mask)
 
+    @jax.named_scope("lgbm/sample")
     def _goss_in_jit(self, key, grad, hess):
         """(ref: goss.hpp:60-131)"""
         cfg = self.config
@@ -892,6 +896,7 @@ class GBDT:
         scale = jnp.where(is_other, amplify, 1.0)
         return mask, scale
 
+    @jax.named_scope("lgbm/gradient/quantize")
     def _discretize_in_jit(self, key, grad, hess):
         """Gradient quantization with stochastic rounding (traced;
         ref: gradient_discretizer.cpp DiscretizeGradients — g_scale =
@@ -934,6 +939,7 @@ class GBDT:
                  h_scale.astype(jnp.float32))
         return g_int * g_scale, h_int * h_scale, quant
 
+    @jax.named_scope("lgbm/renew")
     def _renew_leaves_in_jit(self, rec, row_leaf, true_grad, true_hess,
                              mask):
         """Recompute leaf outputs from the un-quantized gradients
@@ -947,6 +953,7 @@ class GBDT:
         new_vals = jnp.where(rec.leaf_count > 0, renewed, rec.leaf_value)
         return rec._replace(leaf_value=new_vals)
 
+    @jax.named_scope("lgbm/sample")
     def _feature_mask_in_jit(self, key):
         cfg = self.config
         f = self.train_set.num_features
@@ -1026,11 +1033,12 @@ class GBDT:
                 rec, row_leaf, true_grad, true_hess, mask)
         obj = self.objective
         if obj is not None:
-            renewed_lv = obj.renew_leaves_traced(
-                rec.leaf_value, row_leaf, scores_k, mask)
-            if renewed_lv is not None:
-                rec = rec._replace(leaf_value=jnp.where(
-                    rec.num_leaves > 1, renewed_lv, rec.leaf_value))
+            with jax.named_scope("lgbm/renew"):
+                renewed_lv = obj.renew_leaves_traced(
+                    rec.leaf_value, row_leaf, scores_k, mask)
+                if renewed_lv is not None:
+                    rec = rec._replace(leaf_value=jnp.where(
+                        rec.num_leaves > 1, renewed_lv, rec.leaf_value))
         return rec, row_leaf
 
     def _make_fused(self):
@@ -1061,8 +1069,9 @@ class GBDT:
                         # NaN/Inf sentinel operands: the same pointwise
                         # formula the grower evaluates — XLA CSEs the
                         # two, so the fused path stays fused
-                        sen_g, sen_h = self._fused_grad_fn(
-                            scores[0], obj.label, obj.weight)
+                        with jax.named_scope("lgbm/gradient"):
+                            sen_g, sen_h = self._fused_grad_fn(
+                                scores[0], obj.label, obj.weight)
                 else:
                     grad_all, hess_all = self._grad_fn(scores)
                     if sentinel:
@@ -1073,18 +1082,21 @@ class GBDT:
                     rec, row_leaf = self._grow_class_traced(
                         grow, bins_fm, k, key, grad_all[k], hess_all[k],
                         sample_mask, scores[k], it)
-                    # 1-leaf trees contribute nothing (the reference stops
-                    # training instead, gbdt.cpp should_continue)
-                    leaf_vals = jnp.where(rec.num_leaves > 1,
-                                          rec.leaf_value * lr, 0.0)
-                    scores = scores.at[k].add(leaf_vals[row_leaf])
+                    with jax.named_scope("lgbm/score"):
+                        # 1-leaf trees contribute nothing (the reference
+                        # stops training instead, gbdt.cpp
+                        # should_continue)
+                        leaf_vals = jnp.where(rec.num_leaves > 1,
+                                              rec.leaf_value * lr, 0.0)
+                        scores = scores.at[k].add(leaf_vals[row_leaf])
                     for vi in range(len(valid_bins)):
-                        vleaf = replay_tree(
-                            rec, valid_bins[vi], self.feature_meta,
-                            self._bundle,
-                            num_data=self._valid_sets[vi][0].num_data)
-                        new_valid[vi] = new_valid[vi].at[k].add(
-                            leaf_vals[vleaf])
+                        with jax.named_scope("lgbm/valid"):
+                            vleaf = replay_tree(
+                                rec, valid_bins[vi], self.feature_meta,
+                                self._bundle,
+                                num_data=self._valid_sets[vi][0].num_data)
+                            new_valid[vi] = new_valid[vi].at[k].add(
+                                leaf_vals[vleaf])
                     recs.append(rec)
                 stacked = _stack_class_records(recs)
                 # updated objective state: objectives that evolve device
@@ -1197,20 +1209,23 @@ class GBDT:
             fmask = self._feature_mask_in_jit(
                 jax.random.fold_in(key, 200 + k))
             f32 = jnp.float32
-            root_g = jnp.sum(grad * mask, dtype=f32)
-            root_h = jnp.sum(hess * mask, dtype=f32)
-            root_c = jnp.sum(mask, dtype=f32)
-            if use_int8:
-                g_int, h_int, g_scale, h_scale = quant
-                m8 = mask.astype(jnp.int8)
-                ghT = jnp.stack([g_int.astype(jnp.int8) * m8,
-                                 h_int.astype(jnp.int8) * m8, m8], axis=1)
-                hscale = jnp.stack([g_scale, h_scale,
-                                    jnp.float32(1.0)]).astype(f32)
-            else:
-                ghT = jnp.stack([grad * mask, hess * mask, mask],
-                                axis=1).astype(f32)
-                hscale = jnp.ones((3,), f32)
+            with jax.named_scope("lgbm/split"):
+                root_g = jnp.sum(grad * mask, dtype=f32)
+                root_h = jnp.sum(hess * mask, dtype=f32)
+                root_c = jnp.sum(mask, dtype=f32)
+            with jax.named_scope("lgbm/gradient"):
+                if use_int8:
+                    g_int, h_int, g_scale, h_scale = quant
+                    m8 = mask.astype(jnp.int8)
+                    ghT = jnp.stack([g_int.astype(jnp.int8) * m8,
+                                     h_int.astype(jnp.int8) * m8, m8],
+                                    axis=1)
+                    hscale = jnp.stack([g_scale, h_scale,
+                                        jnp.float32(1.0)]).astype(f32)
+                else:
+                    ghT = jnp.stack([grad * mask, hess * mask, mask],
+                                    axis=1).astype(f32)
+                    hscale = jnp.ones((3,), f32)
             return (ghT, hscale, root_g, root_h, root_c, fmask,
                     true_grad, true_hess, mask)
         return class_prep
@@ -1230,23 +1245,26 @@ class GBDT:
                     rec = self._renew_leaves_in_jit(
                         rec, row_leaf, true_grad, true_hess, mask)
                 if obj is not None:
-                    renewed_lv = obj.renew_leaves_traced(
-                        rec.leaf_value, row_leaf, scores[k], mask)
-                    if renewed_lv is not None:
-                        rec = rec._replace(leaf_value=jnp.where(
-                            rec.num_leaves > 1, renewed_lv,
-                            rec.leaf_value))
-                leaf_vals = jnp.where(rec.num_leaves > 1,
-                                      rec.leaf_value * lr, 0.0)
-                scores = scores.at[k].add(leaf_vals[row_leaf])
+                    with jax.named_scope("lgbm/renew"):
+                        renewed_lv = obj.renew_leaves_traced(
+                            rec.leaf_value, row_leaf, scores[k], mask)
+                        if renewed_lv is not None:
+                            rec = rec._replace(leaf_value=jnp.where(
+                                rec.num_leaves > 1, renewed_lv,
+                                rec.leaf_value))
+                with jax.named_scope("lgbm/score"):
+                    leaf_vals = jnp.where(rec.num_leaves > 1,
+                                          rec.leaf_value * lr, 0.0)
+                    scores = scores.at[k].add(leaf_vals[row_leaf])
                 new_valid = list(valid_scores)
                 for vi in range(len(valid_bins)):
-                    vleaf = replay_tree(
-                        rec, valid_bins[vi], self.feature_meta,
-                        self._bundle,
-                        num_data=self._valid_sets[vi][0].num_data)
-                    new_valid[vi] = new_valid[vi].at[k].add(
-                        leaf_vals[vleaf])
+                    with jax.named_scope("lgbm/valid"):
+                        vleaf = replay_tree(
+                            rec, valid_bins[vi], self.feature_meta,
+                            self._bundle,
+                            num_data=self._valid_sets[vi][0].num_data)
+                        new_valid[vi] = new_valid[vi].at[k].add(
+                            leaf_vals[vleaf])
                 return rec, scores, tuple(new_valid)
             finally:
                 if obj is not None:
@@ -1280,11 +1298,13 @@ class GBDT:
 
         def basic_prep(grad_, hess_, mask_):
             f32 = jnp.float32
-            ghT = jnp.stack([grad_ * mask_, hess_ * mask_, mask_],
-                            axis=1).astype(f32)
-            return (ghT, jnp.sum(grad_ * mask_, dtype=f32),
-                    jnp.sum(hess_ * mask_, dtype=f32),
-                    jnp.sum(mask_, dtype=f32))
+            with jax.named_scope("lgbm/gradient"):
+                ghT = jnp.stack([grad_ * mask_, hess_ * mask_, mask_],
+                                axis=1).astype(f32)
+            with jax.named_scope("lgbm/split"):
+                return (ghT, jnp.sum(grad_ * mask_, dtype=f32),
+                        jnp.sum(hess_ * mask_, dtype=f32),
+                        jnp.sum(mask_, dtype=f32))
 
         prep = self._stream_prog("slow_prep", lambda: basic_prep)
         ghT, root_g, root_h, root_c = prep(grad, hess, mask)
@@ -1342,8 +1362,7 @@ class GBDT:
         from .io.streaming import global_stream_stats as _stats
         self._boost_from_average()
         if self._fused is None:
-            with global_tracer.span("train/compile_fused"):
-                self._fused = self._make_fused()
+            self._fused = self._make_fused()
         bins = self._stream_take_bins()
         with global_tracer.span("train/iteration",
                                 block=lambda: self.scores):
@@ -1418,8 +1437,7 @@ class GBDT:
             return self._train_one_iter_stream()
         self._boost_from_average()
         if self._fused is None:
-            with global_tracer.span("train/compile_fused"):
-                self._fused = self._make_fused()
+            self._fused = self._make_fused()
         with global_tracer.span("train/iteration",
                                 block=lambda: self.scores):
             out = self._fused(
@@ -2453,10 +2471,12 @@ class DART(GBDT):
                         fac_d[:, None, None]
                     return jnp.take_along_axis(v, h, axis=2).sum(axis=0)
 
-                delta = drop_delta(leaf_hist, leaf_vals)      # [K, N]
-                deltas_v = [drop_delta(vhists[vi], leaf_vals)
-                            for vi in range(len(valid_bins))]
-                scores_adj = scores - delta
+                with jax.named_scope("lgbm/score/drop"):
+                    delta = drop_delta(leaf_hist, leaf_vals)  # [K, N]
+                    scores_adj = scores - delta
+                with jax.named_scope("lgbm/valid/drop"):
+                    deltas_v = [drop_delta(vhists[vi], leaf_vals)
+                                for vi in range(len(valid_bins))]
                 grad_all, hess_all = self._grad_fn(scores_adj)
 
                 kd = n_drop.astype(jnp.float32)
@@ -2475,38 +2495,45 @@ class DART(GBDT):
                     rec, row_leaf = self._grow_class_traced(
                         grow, bins_fm, k, key, grad_all[k], hess_all[k],
                         sample_mask, scores_adj[k], it)
-                    lv = jnp.where(rec.num_leaves > 1, rec.leaf_value, 0.0)
-                    scores = scores.at[k].set(
-                        scores_adj[k] + old_factor * delta[k]
-                        + new_factor * lv[row_leaf])
-                    leaf_hist = leaf_hist.at[t_cur, k].set(
-                        row_leaf.astype(hd))
-                    lv_store = lv
-                    if with_bias:
-                        # bias applies to 1-LEAF first-iteration trees
-                        # too: the reference's constant tree carries
-                        # leaf_value == init (AsConstantTree), and a
-                        # drop must subtract it — a class with (near-)
-                        # empty data keeps a 1-leaf tree whose bias the
-                        # history would otherwise lose (multiclass DART
-                        # parity, tests/test_engine.py)
-                        lv_store = lv + jnp.where(
-                            t_cur == 0, init_vec[k] / new_factor, 0.0)
-                    leaf_vals = leaf_vals.at[t_cur, k].set(lv_store)
+                    with jax.named_scope("lgbm/score"):
+                        lv = jnp.where(rec.num_leaves > 1,
+                                       rec.leaf_value, 0.0)
+                        scores = scores.at[k].set(
+                            scores_adj[k] + old_factor * delta[k]
+                            + new_factor * lv[row_leaf])
+                        leaf_hist = leaf_hist.at[t_cur, k].set(
+                            row_leaf.astype(hd))
+                        lv_store = lv
+                        if with_bias:
+                            # bias applies to 1-LEAF first-iteration
+                            # trees too: the reference's constant tree
+                            # carries leaf_value == init
+                            # (AsConstantTree), and a drop must
+                            # subtract it — a class with (near-) empty
+                            # data keeps a 1-leaf tree whose bias the
+                            # history would otherwise lose (multiclass
+                            # DART parity, tests/test_engine.py)
+                            lv_store = lv + jnp.where(
+                                t_cur == 0, init_vec[k] / new_factor,
+                                0.0)
+                        leaf_vals = leaf_vals.at[t_cur, k].set(lv_store)
                     for vi in range(len(valid_bins)):
-                        vleaf = replay_tree(
-                            rec, valid_bins[vi], self.feature_meta,
-                            self._bundle,
-                            num_data=self._valid_sets[vi][0].num_data)
-                        new_valid[vi] = new_valid[vi].at[k].set(
-                            new_valid[vi][k]
-                            - (1.0 - old_factor) * deltas_v[vi][k]
-                            + new_factor * lv[vleaf])
-                        new_vhists[vi] = new_vhists[vi].at[t_cur, k].set(
-                            vleaf.astype(hd))
+                        with jax.named_scope("lgbm/valid"):
+                            vleaf = replay_tree(
+                                rec, valid_bins[vi], self.feature_meta,
+                                self._bundle,
+                                num_data=self._valid_sets[vi][0].num_data)
+                            new_valid[vi] = new_valid[vi].at[k].set(
+                                new_valid[vi][k]
+                                - (1.0 - old_factor) * deltas_v[vi][k]
+                                + new_factor * lv[vleaf])
+                            new_vhists[vi] = \
+                                new_vhists[vi].at[t_cur, k].set(
+                                    vleaf.astype(hd))
                     recs.append(rec)
-                factors = factors.at[d_scatter].multiply(old_factor)
-                factors = factors.at[t_cur].set(new_factor)
+                with jax.named_scope("lgbm/score"):
+                    factors = factors.at[d_scatter].multiply(old_factor)
+                    factors = factors.at[t_cur].set(new_factor)
                 stacked = _stack_class_records(recs)
                 out_state = obj.device_state(evolving_only=True)
                 outs = (scores, sample_mask, tuple(new_valid), stacked,
@@ -2547,10 +2574,12 @@ class DART(GBDT):
                         fac_d[:, None, None]
                     return jnp.take_along_axis(v, h, axis=2).sum(axis=0)
 
-                delta = drop_delta(leaf_hist, leaf_vals)
-                deltas_v = tuple(drop_delta(vhists[vi], leaf_vals)
-                                 for vi in range(n_valid))
-                scores_adj = scores - delta
+                with jax.named_scope("lgbm/score/drop"):
+                    delta = drop_delta(leaf_hist, leaf_vals)
+                    scores_adj = scores - delta
+                with jax.named_scope("lgbm/valid/drop"):
+                    deltas_v = tuple(drop_delta(vhists[vi], leaf_vals)
+                                     for vi in range(n_valid))
                 grad_all, hess_all = self._grad_fn(scores_adj)
                 kd = n_drop.astype(jnp.float32)
                 if xgb_mode:
@@ -2586,38 +2615,44 @@ class DART(GBDT):
                     rec = self._renew_leaves_in_jit(
                         rec, row_leaf, true_grad, true_hess, mask)
                 if obj is not None:
-                    renewed_lv = obj.renew_leaves_traced(
-                        rec.leaf_value, row_leaf, scores_adj[k], mask)
-                    if renewed_lv is not None:
-                        rec = rec._replace(leaf_value=jnp.where(
-                            rec.num_leaves > 1, renewed_lv,
-                            rec.leaf_value))
-                lv = jnp.where(rec.num_leaves > 1, rec.leaf_value, 0.0)
-                scores = scores.at[k].set(
-                    scores_adj[k] + old_factor * delta[k]
-                    + new_factor * lv[row_leaf])
-                leaf_hist = leaf_hist.at[t_cur, k].set(
-                    row_leaf.astype(hd))
-                lv_store = lv
-                if with_bias:
-                    # see _make_fused_dart: first-iteration trees carry
-                    # bias/creation_factor in the history buffer
-                    lv_store = lv + jnp.where(
-                        t_cur == 0, init_vec[k] / new_factor, 0.0)
-                leaf_vals = leaf_vals.at[t_cur, k].set(lv_store)
+                    with jax.named_scope("lgbm/renew"):
+                        renewed_lv = obj.renew_leaves_traced(
+                            rec.leaf_value, row_leaf, scores_adj[k], mask)
+                        if renewed_lv is not None:
+                            rec = rec._replace(leaf_value=jnp.where(
+                                rec.num_leaves > 1, renewed_lv,
+                                rec.leaf_value))
+                with jax.named_scope("lgbm/score"):
+                    lv = jnp.where(rec.num_leaves > 1, rec.leaf_value,
+                                   0.0)
+                    scores = scores.at[k].set(
+                        scores_adj[k] + old_factor * delta[k]
+                        + new_factor * lv[row_leaf])
+                    leaf_hist = leaf_hist.at[t_cur, k].set(
+                        row_leaf.astype(hd))
+                    lv_store = lv
+                    if with_bias:
+                        # see _make_fused_dart: first-iteration trees
+                        # carry bias/creation_factor in the history
+                        # buffer
+                        lv_store = lv + jnp.where(
+                            t_cur == 0, init_vec[k] / new_factor, 0.0)
+                    leaf_vals = leaf_vals.at[t_cur, k].set(lv_store)
                 new_valid = list(valid_scores)
                 new_vhists = list(vhists)
                 for vi in range(len(valid_bins)):
-                    vleaf = replay_tree(
-                        rec, valid_bins[vi], self.feature_meta,
-                        self._bundle,
-                        num_data=self._valid_sets[vi][0].num_data)
-                    new_valid[vi] = new_valid[vi].at[k].set(
-                        new_valid[vi][k]
-                        - (1.0 - old_factor) * deltas_v[vi][k]
-                        + new_factor * lv[vleaf])
-                    new_vhists[vi] = new_vhists[vi].at[t_cur, k].set(
-                        vleaf.astype(hd))
+                    with jax.named_scope("lgbm/valid"):
+                        vleaf = replay_tree(
+                            rec, valid_bins[vi], self.feature_meta,
+                            self._bundle,
+                            num_data=self._valid_sets[vi][0].num_data)
+                        new_valid[vi] = new_valid[vi].at[k].set(
+                            new_valid[vi][k]
+                            - (1.0 - old_factor) * deltas_v[vi][k]
+                            + new_factor * lv[vleaf])
+                        new_vhists[vi] = \
+                            new_vhists[vi].at[t_cur, k].set(
+                                vleaf.astype(hd))
                 return (rec, scores, tuple(new_valid),
                         tuple(new_vhists), leaf_hist, leaf_vals)
             finally:
@@ -2626,6 +2661,7 @@ class DART(GBDT):
         return post
 
     def _make_stream_dart_factors(self):
+        @jax.named_scope("lgbm/score")
         def upd(factors, dropped, t_cur, new_factor, old_factor):
             t_max = factors.shape[0]
             live = dropped >= 0
@@ -2648,8 +2684,7 @@ class DART(GBDT):
         dropped = np.full(d_cap, -1, np.int32)
         dropped[:n_drop] = drop_slots
         if self._dart_fused is None:
-            with global_tracer.span("train/compile_fused"):
-                self._dart_fused = self._make_fused_dart()
+            self._dart_fused = self._make_fused_dart()
         st = self._dart
         bins = self._stream_take_bins()
         with global_tracer.span("train/iteration",
@@ -2770,8 +2805,7 @@ class DART(GBDT):
         dropped = np.full(d_cap, -1, np.int32)
         dropped[:n_drop] = drop_slots
         if self._dart_fused is None:
-            with global_tracer.span("train/compile_fused"):
-                self._dart_fused = self._make_fused_dart()
+            self._dart_fused = self._make_fused_dart()
         st = self._dart
         with global_tracer.span("train/iteration",
                                 block=lambda: self.scores):

@@ -653,7 +653,8 @@ class Config:
     # obs.profile.global_profile.summary()/roofline(), the
     # lgbmtpu_profile_* OpenMetrics families, bench JSON
     # device_seconds_by_tag/roofline, and a device lane in the Chrome
-    # trace export.
+    # trace export. With LGBM_TPU_PROFILE_DIR set the window's profiler
+    # trace also gives device_seconds_by_layer (the lgbm/<layer> scopes).
     tpu_profile: str = "off"
     tpu_profile_window: int = 5
     # persistent XLA compile cache (compile_cache.py; ROADMAP item 2 —

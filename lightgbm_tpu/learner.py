@@ -445,6 +445,10 @@ def grow_tree(bins_fm: jax.Array,
             totals = jnp.sum(hg[0], axis=0)  # every row hits group 0 once
             return expand_bundle_hist(hg, group_of, offset_of, nb_arr,
                                       max_bins, totals)
+    # every build (root and per-split smaller child) is the hist layer,
+    # pads and bundle expansion included; innermost scope wins, so the
+    # enclosing lgbm/split of the scan does not claim it
+    build = jax.named_scope("lgbm/hist")(build)
 
     if interaction_groups is not None:
         interaction_groups = jnp.asarray(interaction_groups, bool)
@@ -454,10 +458,11 @@ def grow_tree(bins_fm: jax.Array,
 
     # --- root (ref: serial_tree_learner.cpp BeforeTrain root LeafSplits init)
     root_hist = build(bins_fm, grad, hess, sample_mask)
-    root_g = jnp.sum(grad * sample_mask, dtype=f32)
-    root_h = jnp.sum(hess * sample_mask, dtype=f32)
-    root_c = jnp.sum(sample_mask, dtype=f32)
-    root_out = leaf_output(root_g, root_h, hp)
+    with jax.named_scope("lgbm/split"):
+        root_g = jnp.sum(grad * sample_mask, dtype=f32)
+        root_h = jnp.sum(hess * sample_mask, dtype=f32)
+        root_c = jnp.sum(sample_mask, dtype=f32)
+        root_out = leaf_output(root_g, root_h, hp)
     root_fmask = feature_mask if root_allowed is None else \
         feature_mask & root_allowed
     neg_inf, pos_inf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
@@ -482,11 +487,12 @@ def grow_tree(bins_fm: jax.Array,
                                    has_categorical, rand_bins)
         split_root_fn = split_step_fn = _split_plain
 
-    rb_root, fm_root = _node_randomness(node_key, 0, meta, root_fmask,
-                                        extra_trees, ff_bynode)
-    root_split = split_root_fn(root_hist, root_g, root_h, root_c,
-                               meta, hp, fm_root, root_out,
-                               neg_inf, pos_inf, jnp.int32(0), rb_root)
+    with jax.named_scope("lgbm/split"):
+        rb_root, fm_root = _node_randomness(node_key, 0, meta, root_fmask,
+                                            extra_trees, ff_bynode)
+        root_split = split_root_fn(root_hist, root_g, root_h, root_c,
+                                   meta, hp, fm_root, root_out,
+                                   neg_inf, pos_inf, jnp.int32(0), rb_root)
 
     zero_l = jnp.zeros((L,), f32)
     leaves = _LeafSplits(
@@ -596,10 +602,11 @@ def grow_tree(bins_fm: jax.Array,
         n_applied = state.n_applied + valid.astype(jnp.int32)
 
         # --- partition rows (left keeps best_leaf id, right -> new_leaf)
-        row_leaf = part_ops.apply_split(
-            state.row_leaf, bins_fm, best_leaf, new_leaf, feat, thr, dleft,
-            cat_mask, meta.num_bins, meta.missing_type, meta.is_categorical,
-            valid, bundle)
+        with jax.named_scope("lgbm/partition"):
+            row_leaf = part_ops.apply_split(
+                state.row_leaf, bins_fm, best_leaf, new_leaf, feat, thr,
+                dleft, cat_mask, meta.num_bins, meta.missing_type,
+                meta.is_categorical, valid, bundle)
 
         # --- histograms: build smaller child, subtract for the sibling
         # (ref: serial_tree_learner.cpp:373-386,582)
@@ -715,15 +722,20 @@ def grow_tree(bins_fm: jax.Array,
                 dict(record=record, valid=valid))
 
     # unroll=2: a single-step scan body wrapping pallas_call lowers to a
-    # pathologically slow while-loop on TPU (~1000x); any unrolling avoids it
-    state, ys = lax.scan(step, state, jnp.arange(L - 1, dtype=jnp.int32),
-                         unroll=2 if L > 2 else 1)
-    records = ys["record"]
-    # compact valid records first (a forced split can revive growth after
-    # an invalid step; split s must create leaf s+1 gap-free)
-    steps = jnp.arange(L - 1, dtype=jnp.int32)
-    order = jnp.argsort(jnp.where(ys["valid"], steps, steps + L))
-    records = jax.tree_util.tree_map(lambda a: a[order], records)
+    # pathologically slow while-loop on TPU (~1000x); any unrolling avoids it.
+    # The step is split search and bookkeeping except where an inner
+    # scope (hist, partition, collective) says otherwise.
+    with jax.named_scope("lgbm/split"):
+        state, ys = lax.scan(step, state,
+                             jnp.arange(L - 1, dtype=jnp.int32),
+                             unroll=2 if L > 2 else 1)
+    with jax.named_scope("lgbm/records"):
+        records = ys["record"]
+        # compact valid records first (a forced split can revive growth
+        # after an invalid step; split s must create leaf s+1 gap-free)
+        steps = jnp.arange(L - 1, dtype=jnp.int32)
+        order = jnp.argsort(jnp.where(ys["valid"], steps, steps + L))
+        records = jax.tree_util.tree_map(lambda a: a[order], records)
 
     leaves = state.leaves
     leaf_values = leaves.output
@@ -1288,7 +1300,8 @@ def grow_tree_waved(bins_fm: jax.Array,
         # same values get_gradients would have produced, but XLA can now
         # fuse the element-wise math straight into its consumers instead
         # of round-tripping materialized [N] buffers through HBM
-        grad, hess = fg_fn(fg_score, fg_label, fg_weight)
+        with jax.named_scope("lgbm/gradient"):
+            grad, hess = fg_fn(fg_score, fg_label, fg_weight)
         # build_bins <= 256 keeps bin ids byte-representable — the fused
         # kernel reads bins through the byte-sectioned layout, so uint16
         # storage (max_bin > 256) must stay on the materialized-ghT path
@@ -1303,11 +1316,12 @@ def grow_tree_waved(bins_fm: jax.Array,
                 max_bins=max_bins, num_slots=ids.shape[0])
     elif quant is not None:
         g_int, h_int, g_scale, h_scale = quant
-        m8 = sample_mask.astype(jnp.int8)
-        ghT_i8 = jnp.stack([g_int.astype(jnp.int8) * m8,
-                            h_int.astype(jnp.int8) * m8, m8], axis=1)
-        hscale_vec = jnp.stack([g_scale, h_scale,
-                                jnp.float32(1.0)]).astype(f32)
+        with jax.named_scope("lgbm/gradient"):
+            m8 = sample_mask.astype(jnp.int8)
+            ghT_i8 = jnp.stack([g_int.astype(jnp.int8) * m8,
+                                h_int.astype(jnp.int8) * m8, m8], axis=1)
+            hscale_vec = jnp.stack([g_scale, h_scale,
+                                    jnp.float32(1.0)]).astype(f32)
         if use_shard_hist:
             # per-shard int8 kernel + INT32 psum: the cross-mesh reduce
             # moves exact integer histograms and dequantizes after —
@@ -1369,9 +1383,10 @@ def grow_tree_waved(bins_fm: jax.Array,
                                       max_bins, totals)
     # the gradient/bagging element-wise product: skipped entirely when
     # the kernel computes gh in-place (fused_grad on the pallas path)
-    ghT = None if use_kernel_fused else jnp.stack(
-        [grad * sample_mask, hess * sample_mask, sample_mask],
-        axis=1).astype(jnp.float32)
+    with jax.named_scope("lgbm/gradient"):
+        ghT = None if use_kernel_fused else jnp.stack(
+            [grad * sample_mask, hess * sample_mask, sample_mask],
+            axis=1).astype(jnp.float32)
 
     if interaction_groups is not None:
         interaction_groups = jnp.asarray(interaction_groups, bool)
@@ -1383,12 +1398,14 @@ def grow_tree_waved(bins_fm: jax.Array,
     # The single-leaf kernel's [3, C] x [C, B] dots leave the MXU 97% idle
     # (M=3 rows); the multi kernel's [f_blk*B, C] x [C, 128] shape is the
     # efficient one, so the root rides it too.
-    root_ids = jnp.zeros((1,), jnp.int32)
-    root_hist = multi(bins_fm, ghT, jnp.zeros((num_data,), jnp.int32),
-                      root_ids)[0].astype(f32)
-    root_g = jnp.sum(grad * sample_mask, dtype=f32)
-    root_h = jnp.sum(hess * sample_mask, dtype=f32)
-    root_c = jnp.sum(sample_mask, dtype=f32)
+    with jax.named_scope("lgbm/hist/root"):
+        root_ids = jnp.zeros((1,), jnp.int32)
+        root_hist = multi(bins_fm, ghT, jnp.zeros((num_data,), jnp.int32),
+                          root_ids)[0].astype(f32)
+    with jax.named_scope("lgbm/split"):
+        root_g = jnp.sum(grad * sample_mask, dtype=f32)
+        root_h = jnp.sum(hess * sample_mask, dtype=f32)
+        root_c = jnp.sum(sample_mask, dtype=f32)
     root_fmask = feature_mask if root_allowed is None else \
         feature_mask & root_allowed
     if hist_reduce == "scatter":
@@ -1404,12 +1421,13 @@ def grow_tree_waved(bins_fm: jax.Array,
                                            **_scat_kw)
     else:
         split_root_fn = split_wave_fn = None
-    leaves, pool, used_features = _init_wave_state(
-        root_hist, root_g, root_h, root_c, meta, hp, root_fmask, node_key,
-        L=L, max_bins=max_bins, num_features=num_features, f32=f32,
-        has_categorical=has_categorical, extra_trees=extra_trees,
-        ff_bynode=ff_bynode, interaction_groups=interaction_groups,
-        split_fn=split_root_fn)
+    with jax.named_scope("lgbm/split"):
+        leaves, pool, used_features = _init_wave_state(
+            root_hist, root_g, root_h, root_c, meta, hp, root_fmask,
+            node_key, L=L, max_bins=max_bins, num_features=num_features,
+            f32=f32, has_categorical=has_categorical,
+            extra_trees=extra_trees, ff_bynode=ff_bynode,
+            interaction_groups=interaction_groups, split_fn=split_root_fn)
     row_leaf = jnp.zeros((num_data,), jnp.int32)
 
     unknown = _unknown_split(max_bins)
@@ -1430,6 +1448,7 @@ def grow_tree_waved(bins_fm: jax.Array,
             # slower than W sequential masked passes (measured: bench
             # fallback 3.6 -> 2.8 s/iter) — the batched pass is an HBM
             # bandwidth optimization for accelerator backends
+            @jax.named_scope("lgbm/partition")
             def partition_fn(row_leaf, best_leaf, new_leaf, feat, thr,
                              dleft, cmask, valid):
                 return part_ops.apply_split(
@@ -1456,8 +1475,9 @@ def grow_tree_waved(bins_fm: jax.Array,
     schedule = _wave_schedule(L, wave_max, SLOTS,
                               1 if subtract_siblings else 2)
     for wi, W in enumerate(schedule):
-        (row_leaf, leaves, used_features, n_applied, wbox_lo, wbox_hi), \
-            ys = lax.scan(
+        with jax.named_scope(f"lgbm/split/apply/w{wi:02d}"):
+            (row_leaf, leaves, used_features, n_applied, wbox_lo,
+             wbox_hi), ys = lax.scan(
                 wave_step,
                 (row_leaf, leaves, used_features, n_applied,
                  wbox_lo, wbox_hi),
@@ -1471,14 +1491,15 @@ def grow_tree_waved(bins_fm: jax.Array,
             # layouts on accelerator backends; each row moves at most
             # once per wave — see partition.apply_wave_splits). The COO
             # and CPU paths partitioned inside wave_step instead.
-            row_leaf = part_ops.apply_wave_splits(
-                row_leaf, bins_fm, ys["left_id"], ys["right_id"],
-                ys["record"]["split_feature"],
-                ys["record"]["split_bin_threshold"],
-                ys["record"]["split_default_left"],
-                ys["record"]["split_cat_mask"], ys["valid"],
-                meta.num_bins, meta.missing_type, meta.is_categorical,
-                L, bundle)
+            with jax.named_scope(f"lgbm/partition/w{wi:02d}"):
+                row_leaf = part_ops.apply_wave_splits(
+                    row_leaf, bins_fm, ys["left_id"], ys["right_id"],
+                    ys["record"]["split_feature"],
+                    ys["record"]["split_bin_threshold"],
+                    ys["record"]["split_default_left"],
+                    ys["record"]["split_cat_mask"], ys["valid"],
+                    meta.num_bins, meta.missing_type,
+                    meta.is_categorical, L, bundle)
 
         if wi == len(schedule) - 1:
             # the tree is full: the children of the final wave can never
@@ -1495,40 +1516,45 @@ def grow_tree_waved(bins_fm: jax.Array,
         # (a split leaf's candidate becomes `unknown` within the wave),
         # and invalid steps write to the out-of-bounds row L, which jit
         # scatters drop — so the batch has no index collisions.
-        if subtract_siblings:
-            small_ids = jnp.where(ys["valid"], ys["small_id"], -2)
-            wave_hists = multi(bins_fm, ghT, row_leaf,
-                               small_ids)              # [W, F, B, 3]
-        else:
-            # no-subtraction ORACLE (tpu_wave_subtract=False): build BOTH
-            # children directly. Two slots per split — the schedule above
-            # already halved the wave width — and the pass accumulates
-            # the rows of the full frontier instead of only the smaller
-            # siblings. Kept as the parity/traffic baseline.
-            lids = jnp.where(ys["valid"], ys["left_id"], -2)
-            rids = jnp.where(ys["valid"], ys["right_id"], -2)
-            wave_hists = multi(bins_fm, ghT, row_leaf,
-                               jnp.concatenate([lids, rids]))
-        pool, leaves = _wave_boundary_core(
-            pool, leaves, used_features, ys, wave_hists,
-            feature_mask, max_depth, node_key, s0,
-            subtract_siblings=subtract_siblings, L=L,
-            num_features=num_features, f32=f32, meta=meta, hp=hp,
-            interaction_groups=interaction_groups,
-            has_categorical=has_categorical, extra_trees=extra_trees,
-            ff_bynode=ff_bynode, split_fn=split_wave_fn)
+        with jax.named_scope(f"lgbm/hist/w{wi:02d}"):
+            if subtract_siblings:
+                small_ids = jnp.where(ys["valid"], ys["small_id"], -2)
+                wave_hists = multi(bins_fm, ghT, row_leaf,
+                                   small_ids)          # [W, F, B, 3]
+            else:
+                # no-subtraction ORACLE (tpu_wave_subtract=False): build
+                # BOTH children directly. Two slots per split — the
+                # schedule above already halved the wave width — and the
+                # pass accumulates the rows of the full frontier instead
+                # of only the smaller siblings. Kept as the
+                # parity/traffic baseline.
+                lids = jnp.where(ys["valid"], ys["left_id"], -2)
+                rids = jnp.where(ys["valid"], ys["right_id"], -2)
+                wave_hists = multi(bins_fm, ghT, row_leaf,
+                                   jnp.concatenate([lids, rids]))
+        with jax.named_scope(f"lgbm/split/w{wi:02d}"):
+            pool, leaves = _wave_boundary_core(
+                pool, leaves, used_features, ys, wave_hists,
+                feature_mask, max_depth, node_key, s0,
+                subtract_siblings=subtract_siblings, L=L,
+                num_features=num_features, f32=f32, meta=meta, hp=hp,
+                interaction_groups=interaction_groups,
+                has_categorical=has_categorical, extra_trees=extra_trees,
+                ff_bynode=ff_bynode, split_fn=split_wave_fn)
 
-    records = jax.tree_util.tree_map(
-        lambda *xs: jnp.concatenate(xs, axis=0), *all_records)
-    # compact: valid splits first, in application order. A stale-candidate
-    # step can be invalid while later waves keep splitting, so raw scan
-    # order may interleave -1 records among real ones; Tree.from_arrays
-    # and replay_tree index split s -> new leaf s+1, which requires the
-    # gap-free prefix this permutation restores.
-    valid_all = jnp.concatenate(all_valid)
-    steps = jnp.arange(L - 1, dtype=jnp.int32)
-    order = jnp.argsort(jnp.where(valid_all, steps, steps + L))
-    records = jax.tree_util.tree_map(lambda a: a[order], records)
+    with jax.named_scope("lgbm/records"):
+        records = jax.tree_util.tree_map(
+            lambda *xs: jnp.concatenate(xs, axis=0), *all_records)
+        # compact: valid splits first, in application order. A
+        # stale-candidate step can be invalid while later waves keep
+        # splitting, so raw scan order may interleave -1 records among
+        # real ones; Tree.from_arrays and replay_tree index split s ->
+        # new leaf s+1, which requires the gap-free prefix this
+        # permutation restores.
+        valid_all = jnp.concatenate(all_valid)
+        steps = jnp.arange(L - 1, dtype=jnp.int32)
+        order = jnp.argsort(jnp.where(valid_all, steps, steps + L))
+        records = jax.tree_util.tree_map(lambda a: a[order], records)
     num_leaves_out = 1 + n_applied
 
     tree_arrays = TreeArrays(
@@ -1615,6 +1641,7 @@ class StreamTreeGrower:
         return slab.num_data if isinstance(slab, PackedBins) \
             else int(slab.shape[1])
 
+    @jax.named_scope("lgbm/hist")
     def _multi(self, slab, gh_slab, rl_slab, ids):
         from .ops.pallas_histogram import hist_multi, hist_multi_int8
         if gh_slab.dtype == jnp.int8:
@@ -1661,6 +1688,7 @@ class StreamTreeGrower:
         """One slab's wave work: batched partition, then (except for
         the final wave, whose children can never split) the boundary
         histogram contribution — one upload serves both."""
+        @jax.named_scope("lgbm/partition")
         def part(slab_, rl_, wave_, meta_):
             return part_ops.apply_wave_splits(
                 rl_, slab_, wave_["left_id"], wave_["right_id"],
@@ -1692,6 +1720,7 @@ class StreamTreeGrower:
     def _run_wave_apply(self, leaves, n_applied, steps, meta, hp):
         unknown = _unknown_split(self.max_bins)
 
+        @jax.named_scope("lgbm/split/apply")
         def wave_apply(leaves_, n_applied_, steps_, meta_, hp_):
             def step(carry, s):
                 return _wave_step_stored(carry, s, L=self.L, meta=meta_,
@@ -1708,6 +1737,7 @@ class StreamTreeGrower:
 
     def _run_root_finish(self, acc, hscale, root_g, root_h, root_c,
                          fmask, node_key, meta, hp):
+        @jax.named_scope("lgbm/split")
         def root_finish(acc_, hscale_, rg, rh, rc, fmask_, node_key_,
                         meta_, hp_):
             root_hist = self._scaled(acc_, hscale_)[0].astype(jnp.float32)
@@ -1726,6 +1756,7 @@ class StreamTreeGrower:
 
     def _run_boundary(self, acc, hscale, pool, leaves, ys, fmask,
                       max_depth, node_key, s0, meta, hp):
+        @jax.named_scope("lgbm/split")
         def boundary(acc_, hscale_, pool_, leaves_, ys_, fmask_,
                      max_depth_, node_key_, s0_, meta_, hp_):
             wave_hists = self._scaled(acc_, hscale_)

@@ -16,10 +16,12 @@ common.h:980,1044; global_timer dump at src/boosting/gbdt.cpp:29):
   per-phase watermarks sampled at span boundaries
   (``global_watermarks``), and the ``preflight`` capacity planner that
   fails fast (with knob recommendations) instead of OOMing mid-run.
-- ``obs.xla``    — XLA program introspection: per-executable
-  ``cost_analysis()`` / ``memory_analysis()`` capture, compile
-  wall-time, and per-phase/shape-bucket recompile attribution
-  (``instrumented_jit`` at the program boundaries).
+- ``obs.xla``    — XLA program introspection: always-on
+  first-dispatch counters of every program a boundary acquires
+  (trace+lower seconds, compile-or-load seconds, cache hit), and with
+  telemetry on per-executable ``cost_analysis()`` /
+  ``memory_analysis()`` capture and per-phase/shape-bucket recompile
+  attribution (``instrumented_jit`` at the program boundaries).
 - ``obs.health`` — training-health: runtime-attributed collective
   byte/call counters with a timed mesh microprobe, host straggler-skew
   attribution, cross-shard drift sentinels over replicated state
@@ -45,8 +47,9 @@ common.h:980,1044; global_timer dump at src/boosting/gbdt.cpp:29):
   ``LGBM_TPU_METRICS_FILE`` textfile flusher.
 
 All are disabled by default and their hot-path guards are single
-attribute checks — training with telemetry off records nothing and
-allocates nothing per span/observation.
+attribute checks — training with telemetry off records nothing beyond
+``obs.xla``'s one record per acquired program, and allocates nothing
+per span/observation.
 """
 
 from .trace import Tracer, global_tracer  # noqa: F401
@@ -61,7 +64,7 @@ from .xla import (XlaIntrospector, aot_cost_summary,  # noqa: F401
 from .health import (DriftError, HealthError,  # noqa: F401
                      HealthRegistry, NonFiniteError, global_health)
 from .profile import (ProfileRegistry, global_profile,  # noqa: F401
-                      parse_trace_events)
+                      layer_table)
 from .flightrec import (FlightRecorder, global_flightrec,  # noqa: F401
                         validate_dump)
 from .export import (MetricsHTTPEndpoint,  # noqa: F401
@@ -77,7 +80,7 @@ __all__ = ["Tracer", "global_tracer", "LatencyReservoir",
            "XlaIntrospector", "global_xla", "instrumented_jit",
            "aot_cost_summary", "HealthError", "DriftError",
            "NonFiniteError", "HealthRegistry", "global_health",
-           "ProfileRegistry", "global_profile", "parse_trace_events",
+           "ProfileRegistry", "global_profile", "layer_table",
            "FlightRecorder", "global_flightrec", "validate_dump",
            "MetricsHTTPEndpoint",
            "MetricsTextfileFlusher", "global_flusher",

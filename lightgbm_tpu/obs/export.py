@@ -369,6 +369,12 @@ def render_openmetrics(registry=None,
         for tag in sorted(ps.get("calls_by_tag", {})):
             doc.sample("lgbmtpu_profile_calls_total", "counter",
                        ps["calls_by_tag"][tag], labels={"tag": tag})
+        for layer, secs in ps.get("device_seconds_by_layer", {}).items():
+            doc.sample("lgbmtpu_profile_layer_device_seconds_total",
+                       "counter", secs, labels={"layer": layer},
+                       help_text="device self seconds per lgbm/<layer> "
+                                 "scope (profiler windows; the layer "
+                                 "table of obs/profile.py)")
         rl = global_profile.last_roofline
         if rl is None:
             try:

@@ -657,8 +657,10 @@ def psum(x, axis_name: str, *, tag: str, loop_factor: int = 1):
     """``lax.psum`` with health accounting: keeps the PR-1 trace-time
     counters and registers the site (tag, bytes, scan trip count) into
     the enclosing program's manifest for runtime attribution."""
+    import jax
     from jax import lax
-    out = lax.psum(x, axis_name)
+    with jax.named_scope("lgbm/collective"):
+        out = lax.psum(x, axis_name)
     nbytes = _tree_bytes(out)
     global_metrics.note_collective("psum", nbytes)
     global_health.register_site(tag, "psum", nbytes, loop_factor)
@@ -670,8 +672,9 @@ def all_gather(x, axis_name: str, *, tag: str, loop_factor: int = 1):
     counts are of the GATHERED result (W x the local payload)."""
     import jax
     from jax import lax
-    out = jax.tree_util.tree_map(
-        lambda a: lax.all_gather(a, axis_name), x)
+    with jax.named_scope("lgbm/collective"):
+        out = jax.tree_util.tree_map(
+            lambda a: lax.all_gather(a, axis_name), x)
     nbytes = _tree_bytes(out)
     global_metrics.note_collective("all_gather", nbytes)
     global_health.register_site(tag, "all_gather", nbytes, loop_factor)
@@ -686,9 +689,12 @@ def psum_scatter(x, axis_name: str, *, tag: str, loop_factor: int = 1,
     are of the per-shard RESULT slice (the wrapper convention), which
     is what makes the psum->psum_scatter reduction visible as a W-fold
     drop in the runtime counters."""
+    import jax
     from jax import lax
-    out = lax.psum_scatter(x, axis_name,
-                           scatter_dimension=scatter_dimension, tiled=True)
+    with jax.named_scope("lgbm/collective"):
+        out = lax.psum_scatter(x, axis_name,
+                               scatter_dimension=scatter_dimension,
+                               tiled=True)
     nbytes = _tree_bytes(out)
     global_metrics.note_collective("psum_scatter", nbytes)
     global_health.register_site(tag, "psum_scatter", nbytes, loop_factor)

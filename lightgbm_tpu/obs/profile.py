@@ -10,13 +10,30 @@ keyed back to the existing obs program tags (``boosting/fused_iter``,
   ``stop_trace`` around a bounded window of training iterations or
   serve requests (armed by the ``tpu_profile=off/window/bench`` knob;
   ``LGBM_TPU_PROFILE_DIR`` selects the trace directory and turns the
-  real profiler on). The emitted trace-events JSON is parsed into
-  per-program device-busy seconds via the jitted function names
-  ``instrumented_jit`` registers at wrap time.
+  real profiler on). The window's ``.xplane.pb`` is read back through
+  ``jax.profiler.ProfileData``: each device plane's "XLA Modules" line
+  gives per-program device seconds (by the jitted function names
+  ``instrumented_jit`` registers at wrap time), its "XLA Ops" line the
+  self seconds of every instruction, which the **layer table** turns
+  into ``device_seconds_by_layer``. The host planes hold the program's
+  own spans (``obs/trace.py`` enters a ``TraceAnnotation("lgbm/<span>")``
+  while a profiler session is live), on the clock of the device ops, so
+  each long idle gap is put down to the innermost ``lgbm/`` span that
+  covers it.
+* **Layer table** — ``layer_table(tag)``: ``{instruction: layer}`` read
+  from the optimised HLO of the executable a train-phase tag last
+  acquired (``instrumented_jit`` takes its ``Compiled`` from jit's
+  caches right after the first dispatch). The layers are the ``lgbm/<layer>``
+  ``jax.named_scope``s of boosting.py / learner.py (``sample``,
+  ``gradient``, ``hist``, ``split``, ``partition``, ``score``, ``renew``,
+  ``valid``, ``records``, ``collective``); an instruction's layer is the
+  path component after the LAST ``lgbm`` in its ``op_name``. Parsed on
+  first request (one ``as_text()`` and one pass over it), never on the
+  training path.
 * **Profiler-free fallback** — while a window is open, every
   ``instrumented_jit`` dispatch is re-timed with a
-  ``jax.block_until_ready`` sync (``timed_call``), and the AOT
-  executables obs/xla.py caches are re-run at window close
+  ``jax.block_until_ready`` sync (``timed_call``), and with telemetry
+  on the programs obs/xla.py registered are re-run at window close
   (``block_until_ready`` micro-reruns, best-of-N) — so CPU CI
   exercises the identical attribution plumbing with no profiler.
 * **Roofline layer** — ``roofline()`` joins measured device seconds
@@ -27,7 +44,10 @@ keyed back to the existing obs program tags (``boosting/fused_iter``,
   bytes/s + utilization-vs-peak + a memory-bound/compute-bound verdict
   per tag. Surfaced in bench JSON (``device_seconds_by_tag``,
   ``roofline``), OpenMetrics (``lgbmtpu_profile_*``), the Chrome trace
-  (a separate device-lane pid, obs/trace.py) and perf-gate check 11.
+  (the sync-timed slices on a separate device-lane pid, obs/trace.py:
+  they are on the spans' clock by construction; profiler-measured ops
+  stay in the ``.xplane.pb``, beside the ``lgbm/`` spans) and perf-gate
+  check 11.
 
 Windows never nest; ``start_window``/``stop_window`` accumulate across
 repeated windows. Capture changes no computed values (a sync is
@@ -37,13 +57,13 @@ The disabled path is a single attribute check (``capturing``).
 
 from __future__ import annotations
 
+import bisect
 import glob
-import gzip
-import json
 import os
+import re
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 from .metrics import global_metrics
 
@@ -52,6 +72,10 @@ _ENV_DIR = "LGBM_TPU_PROFILE_DIR"
 _ENV_MODE = "LGBM_TPU_PROFILE"
 
 DEVICE_LANE_NAME = "lightgbm_tpu device"
+SPAN_PREFIX = "lgbm/"         # obs/trace.py's spans in a profiler trace
+_DEVICE_PLANE = "/device:"    # "/device:TPU:0", "/device:GPU:0"
+_OPS_LINE = "XLA Ops"         # one event per executed HLO instruction
+_MODULES_LINE = "XLA Modules"  # one event per executed program
 
 
 def _default_device_kind() -> str:
@@ -63,76 +87,187 @@ def _default_device_kind() -> str:
     return "cpu" if dev.platform == "cpu" else str(dev.device_kind)
 
 
-def parse_trace_events(events: List[Dict[str, Any]],
-                       name_to_tag: Dict[str, str]
-                       ) -> Tuple[Dict[str, float],
-                                  List[Tuple[str, float, float]]]:
-    """Attribute profiler trace events to obs program tags.
+# ---------------------------------------------------------------------------
+# the layer table: optimised HLO text -> {instruction: layer}
+_INSTR_RE = re.compile(r"\s*(?:ROOT\s+)?(%?[A-Za-z_][\w.\-]*) = ")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 
-    -> ({tag: device_busy_seconds}, [(tag, ts_us, dur_us), ...]).
 
-    Pure function (importable for tests). Device pids are identified by
-    ``process_name`` metadata (``/device:``, ``TPU``, ``GPU`` — the
-    names the XLA profiler plugin emits); when no pid is identifiably a
-    device (single-process CPU traces) every pid counts. A complete
-    event is attributed to the tag whose registered jitted-function
-    name appears in the event name, longest name first so e.g.
-    ``_fused_iter_impl`` wins over ``_iter``."""
-    dev_pids = set()
-    for ev in events:
-        if ev.get("ph") == "M" and ev.get("name") == "process_name":
-            nm = str((ev.get("args") or {}).get("name", ""))
-            if "/device:" in nm or nm.startswith(("TPU", "GPU", "Device")):
-                dev_pids.add(ev.get("pid"))
+def instruction_head(text: str) -> Optional[str]:
+    """``%name = shape`` of one HLO instruction: the part before the
+    opcode, which the compiled text and a device trace's event name
+    print alike (the trace adds operand shapes after it). None for a
+    line that is no instruction."""
+    m = _INSTR_RE.match(text)
+    if m is None:
+        return None
+    rest = text[m.end():]
+    if rest.startswith("("):            # tuple shape: to the matching ")"
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                return f"{m.group(1)} = {rest[:i + 1]}"
+        return None
+    return f"{m.group(1)} = {rest.split(' ', 1)[0]}"
+
+
+def layer_of(op_name: str) -> Optional[str]:
+    """The path component right after the last ``lgbm`` component of an
+    ``op_name`` (innermost scope wins), or None without one."""
+    parts = op_name.split("/")
+    for i in range(len(parts) - 2, -1, -1):
+        if parts[i] == "lgbm":
+            return parts[i + 1]
+    return None
+
+
+def parse_layer_table(hlo_text: str) -> Dict[str, str]:
+    """{``instruction_head``: layer} for every instruction of every
+    computation of an optimised HLO module (fusion roots, ``while``
+    bodies, custom calls, what sits inside a fused computation) whose
+    ``op_name`` lies under an ``lgbm/<layer>`` scope. Instructions
+    without ``op_name`` or without a scope are absent."""
+    table: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        op = _OP_NAME_RE.search(line)
+        layer = layer_of(op.group(1)) if op else None
+        if layer is not None:
+            head = instruction_head(line)
+            if head is not None:
+                table[head] = layer
+    return table
+
+
+# ---------------------------------------------------------------------------
+# the window's .xplane.pb -> seconds by tag and by layer, idle gaps
+def find_xplane(log_dir: str) -> Optional[str]:
+    """Newest ``.xplane.pb`` a ``jax.profiler`` session left under
+    `log_dir`, or None."""
+    paths = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return max(paths, key=os.path.getmtime) if paths else None
+
+
+def self_times(events: Iterable[Tuple[str, float, float]]
+               ) -> List[Tuple[str, float, float]]:
+    """(name, start_ns, self_ns) of each ``(name, start_ns, dur_ns)``:
+    its duration less what its children on the same line cover (a
+    ``while`` spans the instructions of its body). Events nest properly
+    on one line."""
+    out: List[Tuple[str, float, float]] = []
+    stack: List[list] = []   # [end, name, start, dur, covered]
+
+    def close():
+        end, name, start, dur, covered = stack.pop()
+        out.append((name, start, max(0.0, dur - covered)))
+
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and start >= stack[-1][0]:
+            close()
+        if stack:
+            stack[-1][4] += min(dur, stack[-1][0] - start)
+        stack.append([start + dur, name, start, dur, 0.0])
+    while stack:
+        close()
+    return out
+
+
+def _idle_gaps(busy: List[Tuple[float, float]], t0: float, t1: float
+               ) -> List[Tuple[float, float]]:
+    """(start_ns, dur_ns) of every stretch of [t0, t1] outside the
+    (start, end) intervals of `busy`, longest first."""
+    gaps = []
+    at = t0
+    for start, end in sorted(busy):
+        if start > at:
+            gaps.append((at, min(start, t1) - at))
+        at = max(at, end)
+        if at >= t1:
+            break
+    if t1 > at:
+        gaps.append((at, t1 - at))
+    return sorted(gaps, key=lambda g: -g[1])
+
+
+def _span_at(spans: List[Tuple[str, float, float]], t: float) -> str:
+    """The innermost (shortest) host span that covers `t`."""
+    best = None
+    for name, start, dur in spans:
+        if start <= t <= start + dur and (best is None or dur < best[1]):
+            best = (name, dur)
+    return best[0] if best else ""
+
+
+def attribute_xspace(data, name_to_tag: Dict[str, str],
+                     tables: Dict[str, Dict[str, str]],
+                     n_gaps: int = 10) -> Dict[str, Any]:
+    """Reduce a ``jax.profiler.ProfileData`` to what the registry
+    reports (pure: tests feed it an XSpace built from text).
+
+    -> ``by_tag`` {tag: device seconds} from the "XLA Modules" lines,
+    ``by_layer`` {layer: self seconds} from the "XLA Ops" lines through
+    `tables` ({tag: layer table}; an op takes the table of the program
+    that was running, else any table that knows it), ``unattributed_s``
+    (ops no table places) and ``idle_gaps``: the `n_gaps` longest
+    stretches without a device op, each with the innermost ``lgbm/``
+    host span over its midpoint. Seconds are summed over the device
+    planes."""
     names = sorted(((n, t) for n, t in name_to_tag.items() if n),
                    key=lambda kv: -len(kv[0]))
-    secs: Dict[str, float] = {}
-    slices: List[Tuple[str, float, float]] = []
-    for ev in events:
-        if ev.get("ph") != "X":
+    merged: Dict[str, str] = {}
+    for table in tables.values():
+        merged.update(table)
+    by_tag: Dict[str, float] = {}
+    by_layer: Dict[str, float] = {}
+    unattributed = 0.0
+    busy: List[Tuple[float, float]] = []
+    spans: List[Tuple[str, float, float]] = []
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans.extend((ev.name, float(ev.start_ns),
+                              float(ev.duration_ns)) for ev in line.events
+                             if ev.name.startswith(SPAN_PREFIX))
             continue
-        if dev_pids and ev.get("pid") not in dev_pids:
+        if not plane.name.startswith(_DEVICE_PLANE):
             continue
-        dur = ev.get("dur")
-        if not isinstance(dur, (int, float)) or dur <= 0:
-            continue
-        ev_name = str(ev.get("name", ""))
-        for fname, tag in names:
-            if fname in ev_name:
-                secs[tag] = secs.get(tag, 0.0) + float(dur) / 1e6
-                if len(slices) < MAX_SLICES:
-                    ts = ev.get("ts")
-                    slices.append((tag,
-                                   float(ts) if isinstance(
-                                       ts, (int, float)) else 0.0,
-                                   float(dur)))
-                break
-    return secs, slices
-
-
-def load_profiler_trace(log_dir: str) -> Optional[List[Dict[str, Any]]]:
-    """Newest ``*.trace.json(.gz)`` under a ``jax.profiler`` log dir,
-    parsed to its event list — or None when the profiler emitted no
-    chrome-format trace (xplane-only versions)."""
-    paths = []
-    for pat in ("**/*.trace.json.gz", "**/*.trace.json"):
-        paths.extend(glob.glob(os.path.join(log_dir, pat), recursive=True))
-    if not paths:
-        return None
-    path = max(paths, key=os.path.getmtime)
-    try:
-        if path.endswith(".gz"):
-            with gzip.open(path, "rt") as fh:
-                doc = json.load(fh)
-        else:
-            with open(path) as fh:
-                doc = json.load(fh)
-    except Exception:
-        return None
-    if isinstance(doc, dict):
-        events = doc.get("traceEvents")
-        return events if isinstance(events, list) else None
-    return doc if isinstance(doc, list) else None
+        modules: List[Tuple[float, float, str]] = []  # (start, end, tag)
+        ops: List[Tuple[str, float, float]] = []
+        for line in plane.lines:
+            if line.name == _MODULES_LINE:
+                for ev in line.events:
+                    tag = next((t for n, t in names if n in ev.name), None)
+                    if tag is not None:
+                        start = float(ev.start_ns)
+                        modules.append((start, start + ev.duration_ns,
+                                        tag))
+                        by_tag[tag] = by_tag.get(tag, 0.0) \
+                            + ev.duration_ns / 1e9
+            elif line.name == _OPS_LINE:
+                ops.extend((ev.name, float(ev.start_ns),
+                            float(ev.duration_ns)) for ev in line.events)
+        modules.sort()
+        starts = [m[0] for m in modules]
+        for name, start, self_ns in self_times(ops):
+            i = bisect.bisect_right(starts, start) - 1
+            table = tables.get(modules[i][2]) \
+                if i >= 0 and start < modules[i][1] else None
+            head = instruction_head(name)
+            layer = (table or merged).get(head) if head else None
+            if layer is None:
+                unattributed += self_ns / 1e9
+            else:
+                by_layer[layer] = by_layer.get(layer, 0.0) + self_ns / 1e9
+        busy.extend((start, start + dur) for _, start, dur in ops)
+    gaps: List[Tuple[str, float]] = []
+    if busy:
+        t0 = min([b[0] for b in busy] + [s[1] for s in spans])
+        t1 = max([b[1] for b in busy] + [s[1] + s[2] for s in spans])
+        gaps = [(_span_at(spans, start + dur / 2), dur / 1e9)
+                for start, dur in _idle_gaps(busy, t0, t1)[:n_gaps]]
+    return {"by_tag": by_tag, "by_layer": by_layer,
+            "unattributed_s": unattributed, "idle_gaps": gaps}
 
 
 class ProfileRegistry:
@@ -154,9 +289,13 @@ class ProfileRegistry:
         self._dropped_slices = 0
         self._entries: Dict[str, Tuple[Any, tuple, dict]] = {}
         self._name_to_tag: Dict[str, str] = {}
+        # tag -> [latest executable dispatched, its layer table or None]
+        self._programs: Dict[str, list] = {}
+        self._layer_s: Dict[str, float] = {}
+        self._unattributed_s = 0.0
+        self._idle_gaps: List[Tuple[str, float]] = []
         self._wall_s = 0.0
         self._t0: Optional[float] = None
-        self._t0_ns = 0
         self._n_windows = 0
         self._trace_dir: Optional[str] = None
         self._tracing = False
@@ -173,6 +312,26 @@ class ProfileRegistry:
             if phase:
                 self._phase.setdefault(tag, phase)
 
+    def note_program(self, tag: str, compiled) -> None:
+        """Called when a train-phase tag has acquired a program: keep
+        the executable that ran (one reference; it holds no closure, so
+        it may outlive its Booster) for ``layer_table``."""
+        self._programs[tag] = [compiled, None]
+
+    def layer_table(self, tag: str) -> Optional[Dict[str, str]]:
+        """{``instruction_head``: layer} of the executable last
+        acquired under `tag` (see ``parse_layer_table``); None when
+        the tag has run no program or its text cannot be had."""
+        held = self._programs.get(tag)
+        if held is None:
+            return None
+        if held[1] is None:
+            try:
+                held[1] = parse_layer_table(held[0].as_text())
+            except Exception:
+                return None
+        return held[1]
+
     # -- window lifecycle ----------------------------------------------
     def start_window(self, source: str = "window",
                      profile_dir: Optional[str] = None) -> None:
@@ -184,7 +343,6 @@ class ProfileRegistry:
             if self.capturing:
                 return
             self._t0 = time.perf_counter()
-            self._t0_ns = time.perf_counter_ns()
             self._n_windows += 1
             self.capturing = True
         if self.mode == "off":
@@ -201,7 +359,7 @@ class ProfileRegistry:
 
     def stop_window(self) -> Dict[str, Any]:
         """Close the window: stop/parse the profiler trace if one ran,
-        micro-rerun the registered AOT executables, drop the retained
+        micro-rerun the registered programs, drop the retained
         call args, cache the roofline. Returns ``summary()``.
         Idempotent — safe to call with no window open."""
         with self._lock:
@@ -244,6 +402,9 @@ class ProfileRegistry:
             self._slices.clear()
             self._dropped_slices = 0
             self._entries.clear()
+            self._layer_s.clear()
+            self._unattributed_s = 0.0
+            self._idle_gaps = []
             self._wall_s = 0.0
             self._t0 = None
             self._n_windows = 0
@@ -290,7 +451,7 @@ class ProfileRegistry:
                 self._phase.setdefault(tag, phase)
 
     def _micro_rerun(self, reps: int = 2) -> None:
-        """Re-time each retained AOT executable best-of-`reps` with
+        """Re-time each retained program best-of-`reps` with
         block_until_ready — the pure device+runtime cost of one call,
         free of the Python dispatch the inline timing includes. Skips
         entries whose buffers were donated/freed (best-effort)."""
@@ -313,41 +474,41 @@ class ProfileRegistry:
 
     # -- profiler ingestion --------------------------------------------
     def _ingest_profiler_dir(self, log_dir: Optional[str]) -> None:
-        if not log_dir:
+        path = find_xplane(log_dir) if log_dir else None
+        if path is None:
             return
-        events = load_profiler_trace(log_dir)
-        if not events:
-            return
+        from jax.profiler import ProfileData
         with self._lock:
             mapping = dict(self._name_to_tag)
-        secs, slices = parse_trace_events(events, mapping)
-        if not secs:
-            return
-        base_us = min(ts for _, ts, _ in slices) if slices else 0.0
+            tags = list(self._programs)
+        tables = {t: self.layer_table(t) for t in tags}
+        got = attribute_xspace(ProfileData.from_file(path), mapping,
+                               {t: tb for t, tb in tables.items() if tb})
         with self._lock:
-            for tag, s in secs.items():
-                self._profiler_s[tag] = self._profiler_s.get(tag, 0.0) + s
-            for tag, ts_us, dur_us in slices:
-                if len(self._slices) >= MAX_SLICES:
-                    self._dropped_slices += 1
-                    continue
-                # rebase the profiler clock onto the window's
-                # perf_counter_ns origin so host+device lanes align
-                t0_ns = self._t0_ns + (ts_us - base_us) * 1e3
-                self._slices.append((tag, t0_ns, dur_us * 1e3,
-                                     "profiler"))
+            for tag, secs in got["by_tag"].items():
+                self._profiler_s[tag] = self._profiler_s.get(tag, 0.0) + secs
+            for layer, secs in got["by_layer"].items():
+                self._layer_s[layer] = self._layer_s.get(layer, 0.0) + secs
+            self._unattributed_s += got["unattributed_s"]
+            self._idle_gaps = sorted(self._idle_gaps + got["idle_gaps"],
+                                     key=lambda g: -g[1])[:10]
 
     # -- reporting ------------------------------------------------------
     def summary(self) -> Dict[str, Any]:
         """Attribution snapshot; live-readable while capturing.
         ``device_seconds_by_tag`` prefers profiler-measured seconds per
-        tag, falling back to the sync-timed dispatches."""
+        tag, falling back to the sync-timed dispatches;
+        ``device_seconds_by_layer`` exists only after a profiler window
+        whose trace held device ops."""
         with self._lock:
             fallback = dict(self._fallback_s)
             profiler = dict(self._profiler_s)
             calls = dict(self._calls)
             phase = dict(self._phase)
             rerun = dict(self._rerun_s)
+            layers = dict(self._layer_s)
+            unattributed = self._unattributed_s
+            gaps = list(self._idle_gaps)
             wall = self._wall_s
             if self.capturing and self._t0 is not None:
                 wall += time.perf_counter() - self._t0
@@ -373,6 +534,15 @@ class ProfileRegistry:
         if rerun:
             out["rerun_seconds_by_tag"] = {t: round(s, 6)
                                            for t, s in rerun.items()}
+        if layers or unattributed:
+            # profiler windows only: self seconds of the device's ops by
+            # lgbm/<layer> scope, what no scope claims, and the longest
+            # stretches with no device op beside the host span over them
+            out["device_seconds_by_layer"] = {
+                name: round(s, 6) for name, s in sorted(layers.items())}
+            out["unattributed_s"] = round(unattributed, 6)
+            out["idle_gaps"] = [{"span": span, "seconds": round(s, 6)}
+                                for span, s in gaps]
         return out
 
     def roofline(self, device_kind: Optional[str] = None,
@@ -452,8 +622,8 @@ class ProfileRegistry:
 
     # -- Chrome trace device lane (obs/trace.py merges these) ----------
     def device_lane_events(self, pid: int) -> List[Dict[str, Any]]:
-        """Captured device slices as Chrome trace events on their own
-        pid — metadata first (check_trace.py requires a process_name
+        """The sync-timed dispatches (on the spans' own clock) as Chrome
+        trace events on their own pid — metadata first (check_trace.py requires a process_name
         per pid and a thread_name per track), then the spans sorted by
         start so per-track ts stays monotonic."""
         with self._lock:
@@ -477,3 +647,9 @@ class ProfileRegistry:
 
 
 global_profile = ProfileRegistry()
+
+
+def layer_table(tag: str) -> Optional[Dict[str, str]]:
+    """``global_profile.layer_table``: what the benchmark's per-layer
+    readers join a device trace with."""
+    return global_profile.layer_table(tag)

@@ -23,8 +23,15 @@ Enabling:
 - ``LGBM_TPU_TIMETAG=1`` (or ``enable()``) prints the aggregated
   summary at exit, exactly like the reference's atexit dump.
 
-When disabled, ``span()`` returns a shared no-op context manager —
-no allocation, one attribute check.
+While a ``jax.profiler`` session is live (an operator's, the
+``tpu_profile`` window's, a benchmark harness's), every span also enters
+a ``jax.profiler.TraceAnnotation("lgbm/" + name)``, tracer enabled or
+not: the profiler's ``.xplane.pb`` then holds the program's host spans,
+nested as here, on the clock of the device ops.
+
+When disabled and no profiler session is live, ``span()`` returns a
+shared no-op context manager — no allocation, one attribute check and
+one static call.
 """
 
 from __future__ import annotations
@@ -50,20 +57,44 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+ANNOTATION_PREFIX = "lgbm/"  # the spans' names in a profiler trace
+_annotation_cls = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use (this
+    module stays importable without touching jax)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
 
 class _SpanFrame:
     """One live span (context manager); exists only while enabled."""
-    __slots__ = ("tracer", "name", "block", "args", "t0", "child_ns")
+    __slots__ = ("tracer", "name", "block", "args", "t0", "child_ns",
+                 "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, block,
-                 args=None) -> None:
+                 args=None, annotation=None) -> None:
         self.tracer = tracer
         self.name = name
         self.block = block
         self.args = args
         self.child_ns = 0
+        self.annotation = annotation
+
+    def set_metadata(self, **kw) -> None:
+        """Attach facts known only inside the span (``TraceAnnotation``'s
+        own method name, so a caller need not know which it holds)."""
+        self.args = dict(self.args or {}, **kw)
+        if self.annotation is not None:
+            self.annotation.set_metadata(**kw)
 
     def __enter__(self) -> "_SpanFrame":
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.tracer._stack.append(self)
         self.t0 = time.perf_counter_ns()
         return self
@@ -93,6 +124,8 @@ class _SpanFrame:
             stack[-1].child_ns += dur
         tracer._record(self.name, self.t0, dur, dur - self.child_ns,
                        len(stack), self.args)
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc_val, exc_tb)
         return False
 
 
@@ -168,13 +201,20 @@ class Tracer:
     # ------------------------------------------------------------------
     def span(self, name: str, block: Optional[Any] = None,
              args: Optional[Dict[str, Any]] = None):
-        """Time a nested phase. Disabled mode returns a shared no-op
-        context manager (no allocation). `args` (a small dict) rides
-        into the Chrome event's ``args`` — the request-tracing link
-        fields (trace_id, batch_id, ...) travel this way."""
+        """Time a nested phase. `args` (a small dict) rides into the
+        Chrome event's ``args`` — the request-tracing link fields
+        (trace_id, batch_id, ...) travel this way. While a profiler
+        session is live the span is also a ``TraceAnnotation`` in the
+        profiler's own trace; with the tracer disabled it is only that
+        (and ``block`` is not waited on: the annotation then shows what
+        the host really did). Disabled and no session: the shared no-op
+        context manager, no allocation."""
+        cls = _annotation()
+        annotation = (cls(ANNOTATION_PREFIX + name) if cls.is_enabled()
+                      else None)
         if not self.enabled:
-            return _NULL_SPAN
-        return _SpanFrame(self, name, block, args)
+            return _NULL_SPAN if annotation is None else annotation
+        return _SpanFrame(self, name, block, args, annotation)
 
     def add_complete_span(self, name: str, start_ns: int, dur_ns: int,
                           args: Optional[Dict[str, Any]] = None,
@@ -295,8 +335,8 @@ class Tracer:
                 "args": args,
             })
         try:
-            # device lane (same perf_counter_ns clock as the spans; the
-            # profile registry rebases profiler-sourced slices onto it)
+            # device lane: the sync-timed dispatches, on the spans' own
+            # perf_counter_ns clock
             from .profile import global_profile
             events.extend(global_profile.device_lane_events(pid + 1))
         except Exception:

@@ -211,6 +211,7 @@ def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="lgbm_hist_multi",
     )(bins_fm, ghT.T, row_leaf[None, :].astype(jnp.int32),
       _leafsel_col(leaf_ids, num_slots))
     # [fblocks, f_blk*B, 128] -> [F, B, J, 3] -> [J, F, B, 3]
@@ -303,6 +304,7 @@ def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), jnp.int32),
         interpret=_resolve_interpret(interpret),
+        name="lgbm_hist_multi_int8",
     )(bins_fm, ghT_i8.T, row_leaf[None, :].astype(jnp.int32),
       _leafsel_col(leaf_ids, num_slots))
     out = out[:, :, :3 * num_slots]
@@ -477,6 +479,7 @@ def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), out_dtype),
         interpret=_resolve_interpret(interpret),
+        name="lgbm_hist_multi_packed",
     )(*operands)
     out = out[:, :, :3 * num_slots]
     out = out.reshape(fp, max_bins, num_slots, 3)
@@ -606,6 +609,7 @@ def _hist_pallas_packed(pb, gh3, *, max_bins: int, f_blk: int = 8,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((fp, 3, max_bins), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="lgbm_hist_packed",
     )(*operands)
     return jnp.swapaxes(out[:num_features], 1, 2)
 
@@ -798,6 +802,7 @@ def hist_pallas(bins_fm: jax.Array, gh3: jax.Array, *, max_bins: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((fp, 3, max_bins), jnp.float32),
         interpret=_resolve_interpret(interpret),
+        name="lgbm_hist",
     )(bins_fm, gh3)
     # [F, 3, B] -> [F, B, 3] to match the XLA path's layout
     return jnp.swapaxes(out[:num_features], 1, 2)

@@ -165,3 +165,75 @@ def test_row_operands_stay_lane_dense_at_higgs_rows(one_chip, kind):
     fn, args = _kernel(kind, s, _bins(s, n, 63), n, 63)
     mem = jax.jit(fn).lower(*args).compile().memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30, mem
+
+
+# ---------------------------------------------------------------------------
+# the whole iteration program, for its layer table (ISSUE 26): what the
+# chip's compiler leaves of the lgbm/<layer> scopes is what the benchmark's
+# per-layer seconds are read through
+@pytest.fixture(scope="module")
+def fused_iter_table(one_chip):
+    """{instruction_head: layer} of ``boosting/fused_iter`` compiled for
+    the described chip: the benchmark cell's path (int8 gradients, F=28,
+    63 bins) at 16k rows and 31 leaves, a 15 s compile."""
+    import numpy as np
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs.profile import parse_layer_table
+    from lightgbm_tpu.ops import histogram as hist_ops
+    mp = pytest.MonkeyPatch()
+    # the Booster is built on the CPU; the program takes its TPU branches
+    # (Mosaic kernels, batched partition) because the test says so
+    mp.setattr(hist_ops, "cpu_backend", lambda: False)
+    try:
+        r = np.random.RandomState(0)
+        x = r.randn(1 << 14, F) + 0.26
+        y = (x[:, 0] + x[:, 1] > 0.5).astype(np.float64)
+        g = lgb.Booster({"objective": "binary", "num_leaves": 31,
+                         "max_bin": 63, "verbosity": -1,
+                         "use_quantized_grad": True,
+                         "num_grad_quant_bins": 126,
+                         "tpu_hist_impl": "pallas"},
+                        lgb.Dataset(x, label=y))._gbdt
+        g._boost_from_average()
+        args = (g.bins_fm, tuple(g._valid_bins), g._obj_state(), g.scores,
+                g._sample_mask, tuple(g._valid_scores), jnp.int32(0),
+                jnp.float32(0.1))
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), args)
+        text = g._make_fused().lower(*shapes).compile().as_text()
+    finally:
+        mp.undo()
+    return parse_layer_table(text)
+
+
+@pytest.mark.parametrize("layer", ["gradient", "hist", "split",
+                                   "partition", "score"])
+def test_compiled_iteration_keeps_each_layer(fused_iter_table, layer):
+    assert layer in fused_iter_table.values()
+
+
+def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table):
+    """``name=`` on the pallas_call names the custom call's instruction,
+    and keeps ``hist`` in it: benchmarks/metrics/hist_kernels.py finds the
+    kernels by that substring."""
+    kernels = {head: layer for head, layer in fused_iter_table.items()
+               if head.startswith("%lgbm_hist_multi_int8")}
+    assert kernels and set(kernels.values()) == {"hist"}
+
+
+@pytest.mark.parametrize("shape,layer", [("u8[16384]", "partition"),
+                                         ("s32[16384]", "partition"),
+                                         ("f32[16384]", "score")])
+def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
+    """The fusions that carry the benchmark cell's seconds (PERF.md
+    section 5: ``u8[N]`` the bin gather, ``s32[N]`` the row-to-leaf
+    update, ``f32[N]`` the score update) each fall under one layer. The
+    ``layer_*_s`` metrics are defined by the scopes in the program: a
+    change that moves a ``named_scope`` moves seconds between them, and
+    shows here first."""
+    got = {lay for head, lay in fused_iter_table.items()
+           if "fusion" in head.split(" = ")[0]
+           and head.split(" = ")[1].startswith(shape + "{")}
+    assert got == {layer}

@@ -493,22 +493,34 @@ class TestRegisterLogger:
 
 # ---------------------------------------------------------------------------
 # disabled-path cost of the introspection layer (exporter, xla, request
-# tracing): telemetry off must mean guard checks only — nothing routed,
-# nothing recorded, nothing allocated
+# tracing): telemetry off means the first-dispatch counters and nothing
+# else — no cost analysis, no published meta, nothing allocated per call
 class TestDisabledIntrospectionLayer:
-    def test_xla_introspector_disabled_is_passthrough(self):
+    def test_xla_introspector_disabled_keeps_counters_only(self,
+                                                          monkeypatch):
+        """One dispatch path on both settings, jit's own: with telemetry
+        off a program outside training leaves its first-dispatch
+        counters and nothing else — no Compiled is taken (a broken
+        ``lower`` is never reached), no cost analysis."""
         from lightgbm_tpu.obs.xla import XlaIntrospector, instrumented_jit
         reg = XlaIntrospector()
         assert not reg.enabled  # env-gated, off under the test env
-        compiles = []
-        g = instrumented_jit("off/prog", lambda x: x * 3, registry=reg)
-        # break AOT entry points: if the disabled path ever touched
-        # them the call would explode
-        g.__wrapped_jit__.lower = lambda *a, **k: compiles.append(1)
-        out = g(np.ones(4, np.float32))
-        np.testing.assert_array_equal(np.asarray(out), [3.0] * 4)
-        assert reg.n_programs == 0 and compiles == []
-        assert reg.summary()["compile_s_total"] == 0.0
+        g = instrumented_jit("off/prog", lambda x: x * 3, phase="predict",
+                             registry=reg)
+
+        def boom(*a, **k):
+            raise AssertionError("lower() reached with telemetry off")
+
+        monkeypatch.setattr(g.__wrapped_jit__, "lower", boom)
+        a = np.ones(4, np.float32)
+        np.testing.assert_array_equal(np.asarray(g(a)), [3.0] * 4)
+        np.testing.assert_array_equal(np.asarray(g(a)), [3.0] * 4)
+        assert reg.n_programs == 1  # the second call acquired nothing
+        rec = reg.records()[0]
+        assert rec["trace_lower_s"] > 0 and rec["compile_or_load_s"] > 0
+        assert rec["cache_hit"] in (True, False)
+        assert "flops" not in rec and "argument_bytes" not in rec
+        assert "aot_fallbacks" not in reg.summary()
 
     def test_flusher_unarmed_is_attribute_check(self, monkeypatch,
                                                 tmp_path):

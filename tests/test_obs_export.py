@@ -148,13 +148,18 @@ class TestTextfileFlusher:
 class TestXlaIntrospector:
     def test_enabled_routes_aot_and_records_cost(self):
         import jax
+        from jax.experimental.compilation_cache import \
+            compilation_cache as cc
         # A persistent-cache-served compile is attributed to
         # cache_load_s_total, NOT compile_s_total — so if a prior run
         # already wrote this tiny program to the disk cache (conftest
         # arms it), the compile_s_total assertions below would see 0.
-        # Pin the test to real compiles by detaching the disk cache.
-        prev_cache = jax.config.jax_compilation_cache_dir
-        jax.config.update("jax_compilation_cache_dir", None)
+        # Pin the test to real compiles by detaching the disk cache:
+        # the flag AND the already-initialised cache object (resetting
+        # the directory alone leaves the live cache serving hits).
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
         try:
             reg = XlaIntrospector()
             reg.enable()
@@ -174,18 +179,22 @@ class TestXlaIntrospector:
             recs = reg.records()
             assert recs[0]["tag"] == "test/prog"
             assert recs[0]["phase"] == "testing"
-            assert recs[0]["compile_s"] > 0
+            assert recs[0]["compile_or_load_s"] > 0
+            assert recs[0]["trace_lower_s"] > 0
+            assert recs[0]["cache_hit"] is False
             assert "64x4" in recs[0]["shapes"]
             g(np.ones((128, 4), np.float32))  # new bucket: +1 program
             assert reg.n_programs == 2
             s = reg.summary()
             assert s["n_recompiles_by_phase"] == {"testing": 2}
             assert s["compile_s_total"] > 0
+            assert s["n_cache_hits"] == 0
             assert s["by_tag"]["test/prog"]["programs"] == 2
-            # the AOT result equals the jit path bit-for-bit
+            # the boundary's result equals bare jit's bit-for-bit
             assert float(g(a)) == float(jax.jit(f)(a))
         finally:
-            jax.config.update("jax_compilation_cache_dir", prev_cache)
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()
 
     def test_cost_analysis_fields_when_backend_exposes_them(self):
         reg = XlaIntrospector()
@@ -199,9 +208,11 @@ class TestXlaIntrospector:
         assert rec.get("bytes_accessed", 0) > 0
         assert rec.get("argument_bytes", 0) >= 32 * 8 * 4
 
-    def test_fallback_on_uncompilable_keeps_results(self, monkeypatch):
-        """lower/compile failure must fall back to the plain jit path
-        (and stay there) without changing results."""
+    def test_compiled_unavailable_keeps_results_and_counters(
+            self, monkeypatch):
+        """Failing to take the Compiled after the call costs the cost
+        analysis only: the call itself went through jit, its result and
+        its first-dispatch counters stand."""
         reg = XlaIntrospector()
         reg.enable()
         g = instrumented_jit("test/fb", lambda x: x + 1, registry=reg)
@@ -211,15 +222,47 @@ class TestXlaIntrospector:
             raise RuntimeError("no AOT here")
 
         monkeypatch.setattr(jitted, "lower", boom)
-        out = g(np.arange(4.0, dtype=np.float32))
-        np.testing.assert_array_equal(np.asarray(out),
-                                      [1.0, 2.0, 3.0, 4.0])
-        assert reg.n_programs == 0
+        for _ in range(2):
+            out = g(np.arange(4.0, dtype=np.float32))
+            np.testing.assert_array_equal(np.asarray(out),
+                                          [1.0, 2.0, 3.0, 4.0])
+        assert reg.n_programs == 1
+        assert reg.records()[0]["compile_or_load_s"] > 0
+        assert "flops" not in reg.records()[0]
         assert "test/fb" in reg.summary()["aot_fallbacks"]
-        # subsequent calls stay on the fallback path, still correct
-        out = g(np.arange(4.0, dtype=np.float32))
-        np.testing.assert_array_equal(np.asarray(out),
-                                      [1.0, 2.0, 3.0, 4.0])
+
+    @pytest.mark.parametrize("when", ["trace", "run"])
+    def test_errors_reach_the_caller_as_jit_raised_them(self, when):
+        """The boundary catches nothing around the call: a failing
+        program is not retried on another route (its donated arguments
+        may be gone), and nothing is recorded as a fallback."""
+        import jax
+        import jax.numpy as jnp
+        reg = XlaIntrospector()
+        reg.enable()
+        runs = []
+
+        def host_side(x):
+            runs.append(1)
+            raise FloatingPointError("device said no")
+
+        def f(x, buf):
+            if when == "trace":
+                raise KeyError("bad program")
+            return jax.pure_callback(
+                host_side, jax.ShapeDtypeStruct(x.shape, x.dtype), x) + buf
+
+        g = instrumented_jit("test/raises", f, phase="train", registry=reg,
+                             donate_argnums=(1,))
+        x, buf = jnp.ones(4), jnp.zeros(4)
+        with pytest.raises(KeyError if when == "trace" else Exception) as e:
+            jax.block_until_ready(g(x, buf))
+        if when == "trace":
+            assert reg.n_programs == 0 and not buf.is_deleted()
+        else:
+            assert "device said no" in str(e.value)
+            assert runs == [1]  # ran once: no second route was tried
+        assert "aot_fallbacks" not in reg.summary()
 
     def test_aot_cost_summary_shape(self):
         cost = aot_cost_summary(lambda x: (x * x).sum(),
@@ -252,7 +295,7 @@ class TestXlaIntrospector:
         recs = [r for r in global_xla.records()[n0:]
                 if r["tag"] == SERVE_LOWLAT_TAG]
         assert recs and recs[0]["phase"] == "serve"
-        assert recs[0]["compile_s"] > 0
+        assert recs[0]["compile_or_load_s"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -20,8 +20,7 @@ from lightgbm_tpu.obs.export import (MetricsHTTPEndpoint,
 from lightgbm_tpu.obs.flightrec import (FORMAT, FlightRecorder,
                                         global_flightrec, validate_dump)
 from lightgbm_tpu.obs.metrics import global_metrics
-from lightgbm_tpu.obs.profile import (DEVICE_LANE_NAME, global_profile,
-                                      parse_trace_events)
+from lightgbm_tpu.obs.profile import DEVICE_LANE_NAME, global_profile
 from lightgbm_tpu.obs.xla import global_xla, instrumented_jit
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -122,41 +121,6 @@ class TestFlightRecorder:
         kinds = [e["kind"] for e in global_flightrec.events()]
         assert kinds.count("iteration") == 4
         assert "checkpoint" in kinds
-
-
-# ---------------------------------------------------------------------------
-class TestParseTraceEvents:
-    def test_device_pid_filter_and_name_attribution(self):
-        events = [
-            {"ph": "M", "name": "process_name", "pid": 7,
-             "args": {"name": "/device:TPU:0"}},
-            {"ph": "M", "name": "process_name", "pid": 1,
-             "args": {"name": "python host"}},
-            {"ph": "X", "name": "jit__fused_iter_impl.33", "pid": 7,
-             "ts": 100.0, "dur": 2000.0},
-            {"ph": "X", "name": "jit__fused_iter_impl.33", "pid": 1,
-             "ts": 100.0, "dur": 9000.0},  # host copy: ignored
-            {"ph": "X", "name": "unrelated_kernel", "pid": 7,
-             "ts": 200.0, "dur": 500.0},
-        ]
-        secs, slices = parse_trace_events(
-            events, {"_fused_iter_impl": "boosting/fused_iter"})
-        assert secs == {"boosting/fused_iter": pytest.approx(0.002)}
-        assert slices == [("boosting/fused_iter", 100.0, 2000.0)]
-
-    def test_no_device_pid_counts_every_pid(self):
-        events = [{"ph": "X", "name": "jit_foo", "pid": 1,
-                   "ts": 0.0, "dur": 1000.0}]
-        secs, _ = parse_trace_events(events, {"foo": "t/foo"})
-        assert secs == {"t/foo": pytest.approx(0.001)}
-
-    def test_longest_registered_name_wins(self):
-        events = [{"ph": "X", "name": "jit__grow_wave_impl", "pid": 1,
-                   "ts": 0.0, "dur": 1000.0}]
-        secs, _ = parse_trace_events(
-            events, {"_grow": "short/tag",
-                     "_grow_wave_impl": "long/tag"})
-        assert list(secs) == ["long/tag"]
 
 
 # ---------------------------------------------------------------------------
