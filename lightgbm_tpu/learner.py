@@ -1234,11 +1234,12 @@ def grow_tree_waved(bins_fm: jax.Array,
     hist_deterministic: Kahan-compensated fixed-chunk accumulation in
     the XLA histogram paths (`deterministic_hist` knob).
 
-    batched_partition: apply each wave's splits in one gathered pass
-    (partition.apply_wave_splits) instead of per-split passes. None =
-    auto: on for accelerator backends (the gather is an HBM-bandwidth
-    win), off on CPU (the gather loses to sequential masked passes) and
-    always off for COO sparse storage.
+    batched_partition: apply each wave's splits in one pass over the
+    rows (partition.apply_wave_splits: compare-and-select, no per-row
+    gather) instead of one apply_split pass per split. None = auto: on
+    for accelerator backends, off on the CPU backend; always off for
+    COO sparse storage, which has no [F, N] matrix to select from.
+    PERF.md sections 5 and 6 hold what each costs on the chip.
 
     Identical split mathematics to `grow_tree`, but histogram builds are
     batched: splits are applied in waves; at each wave boundary ONE
@@ -1443,11 +1444,10 @@ def grow_tree_waved(bins_fm: jax.Array,
         if use_batched_partition:
             partition_fn = None
         else:
-            # per-split partition: COO storage can't serve the batched
-            # pass's per-row feature gathers, and on CPU the gather is
-            # slower than W sequential masked passes (measured: bench
-            # fallback 3.6 -> 2.8 s/iter) — the batched pass is an HBM
-            # bandwidth optimization for accelerator backends
+            # per-split partition (apply_split once a step): COO storage
+            # has no [F, N] matrix for the wave pass to select from, and
+            # the CPU backend keeps it by batched_partition's resolution
+            # below (PERF.md section 7 says what is not measured there)
             @jax.named_scope("lgbm/partition")
             def partition_fn(row_leaf, best_leaf, new_leaf, feat, thr,
                              dleft, cmask, valid):
@@ -1487,10 +1487,10 @@ def grow_tree_waved(bins_fm: jax.Array,
         s0 += W
 
         if use_batched_partition:
-            # ONE batched partition pass for the whole wave (dense/EFB
-            # layouts on accelerator backends; each row moves at most
-            # once per wave — see partition.apply_wave_splits). The COO
-            # and CPU paths partitioned inside wave_step instead.
+            # ONE partition pass for the whole wave (dense, EFB and
+            # packed layouts on accelerator backends; each row moves at
+            # most once per wave — see partition.apply_wave_splits). The
+            # COO and CPU paths partitioned inside wave_step instead.
             with jax.named_scope(f"lgbm/partition/w{wi:02d}"):
                 row_leaf = part_ops.apply_wave_splits(
                     row_leaf, bins_fm, ys["left_id"], ys["right_id"],
@@ -1499,7 +1499,7 @@ def grow_tree_waved(bins_fm: jax.Array,
                     ys["record"]["split_default_left"],
                     ys["record"]["split_cat_mask"], ys["valid"],
                     meta.num_bins, meta.missing_type,
-                    meta.is_categorical, L, bundle)
+                    meta.is_categorical, L, bundle, has_categorical)
 
         if wi == len(schedule) - 1:
             # the tree is full: the children of the final wave can never
@@ -1694,7 +1694,8 @@ class StreamTreeGrower:
                 rl_, slab_, wave_["left_id"], wave_["right_id"],
                 wave_["feat"], wave_["thr"], wave_["dleft"],
                 wave_["cmask"], wave_["valid"], meta_.num_bins,
-                meta_.missing_type, meta_.is_categorical, self.L, None)
+                meta_.missing_type, meta_.is_categorical, self.L, None,
+                self._has_cat)
 
         if not with_hist:
             return self._prog("wave_last", part)(slab, rl_slab, wave,

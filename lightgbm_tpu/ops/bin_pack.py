@@ -170,13 +170,19 @@ def unpack_feature(pb: PackedBins, feature):
     return jnp.concatenate(parts)[:pb.num_data]
 
 
-def unpack_rows(pb: PackedBins, feat, rows):
-    """Per-row gathered unpack: bins of feature ``feat[i]`` at row
-    ``rows[i]`` (the packed analog of a ``bins[feat, rows]`` gather).
-    Row r lives in byte ``r % section`` at bit position
-    ``bits * (r // section)``."""
+def unpack_rows(pb: PackedBins, feat):
+    """Per-row unpack: bin of feature ``feat[r]`` at every row ``r``
+    (the packed analog of ``bins[feat, arange(N)]``), as a select over
+    the F packed rows and not as a gather. Row r lives in byte
+    ``r % section`` at bit position ``bits * (r // section)``, so the
+    rows of section v compare against the same bytes and shift by
+    ``bits * v``."""
     bits = pb.bits
-    bmask = (1 << bits) - 1
-    sec = pb.section
-    byte = pb.data[feat, rows % sec].astype(jnp.int32)
-    return (byte >> (bits * (rows // sec))) & bmask
+    f, sec = pb.data.shape
+    feat = jnp.pad(feat, (0, pb.vpb * sec - pb.num_data))
+    ids = jnp.arange(f, dtype=jnp.int32)[:, None, None]
+    byte = jnp.sum(jnp.where(feat.reshape(1, pb.vpb, sec) == ids,
+                             pb.data[:, None, :], 0),
+                   axis=0, dtype=jnp.int32)                # [vpb, section]
+    shift = bits * jnp.arange(pb.vpb, dtype=jnp.int32)[:, None]
+    return ((byte >> shift) & ((1 << bits) - 1)).reshape(-1)[:pb.num_data]

@@ -81,19 +81,67 @@ def _decode_bundled(col: jax.Array, off: jax.Array,
     return jnp.where(in_range, col - off + 1, 0)
 
 
-def _per_row_feature_bins(bins_fm: jax.Array, feat: jax.Array,
-                          bundle=None) -> jax.Array:
-    """bins of feature feat[i] for every row i — the gathered analog of
-    feature_bins for per-row feature indices (feat: [N] int32)."""
-    n = feat.shape[0]
-    rows = jnp.arange(n)
+def _bits(n: int) -> int:
+    """Bits that hold every value of [0, n]."""
+    return max(int(n), 1).bit_length()
+
+
+def _per_row(m: jax.Array, rec: jax.Array) -> jax.Array:
+    """Row i's value of a per-split record (rec: [W]) under the match
+    matrix m[w, i] (at most one w true per row; 0 where none is)."""
+    return jnp.sum(jnp.where(m, rec[:, None], 0), axis=0, dtype=rec.dtype)
+
+
+def _per_row_fields(m: jax.Array, fields: dict) -> dict:
+    """Per-row values of the per-split `fields` ({name: (values [W],
+    bits)}, every value in [0, 2^bits) where it matters): the fields
+    are packed, first fit, into as few 31-bit int32 records as hold
+    them, each record is brought to the rows once (`_per_row`) and cut
+    up again there. The widths come from static shapes only."""
+    words, used, place = [], [], {}
+    for name, (val, bits) in fields.items():
+        val = val.astype(jnp.int32) & ((1 << bits) - 1)
+        k = next((i for i, u in enumerate(used) if u + bits <= 31), None)
+        if k is None:
+            k = len(words)
+            words.append(jnp.zeros_like(val))
+            used.append(0)
+        words[k] = words[k] | (val << used[k])
+        place[name] = (k, used[k], bits)
+        used[k] += bits
+    rows = [_per_row(m, w) for w in words]
+    return {name: (rows[k] >> shift) & ((1 << bits) - 1)
+            for name, (k, shift, bits) in place.items()}
+
+
+def _per_row_feature_bins(bins_fm, feat: jax.Array) -> jax.Array:
+    """Stored bin of row i on the stored row feat[i] of `bins_fm`
+    (feat: [N] int32), as a select over the F rows and not as a
+    gather: every bin is read once, in the layout it is stored in."""
     if isinstance(bins_fm, PackedBins):
-        return unpack_rows(bins_fm, feat, rows)
-    if bundle is None:
-        return bins_fm[feat, rows].astype(jnp.int32)
-    group_of, offset_of, nb = bundle
-    col = bins_fm[group_of[feat], rows].astype(jnp.int32)
-    return _decode_bundled(col, offset_of[feat], nb[feat])
+        return unpack_rows(bins_fm, feat)
+    ids = jnp.arange(bins_fm.shape[0], dtype=jnp.int32)[:, None]
+    return jnp.sum(jnp.where(feat[None, :] == ids, bins_fm, 0), axis=0,
+                   dtype=jnp.int32)
+
+
+def _per_row_cat_bit(row_leaf: jax.Array, lids: jax.Array,
+                     cat_masks: jax.Array, fbins: jax.Array) -> jax.Array:
+    """cat_masks[w, fbins[i]] for the step w whose leaf lids[w] row i is
+    in (False where it is in none): each step's [B] mask is packed into
+    K = ceil(B / 32) words, and a row finds its word by comparing the
+    key (leaf, word index) with the W * K keys of the wave."""
+    w_count, b = cat_masks.shape
+    k = -(-b // 32)
+    bit = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
+    words = jnp.sum(
+        jnp.where(jnp.pad(cat_masks, ((0, 0), (0, k * 32 - b)))
+                  .reshape(w_count, k, 32), bit, jnp.uint32(0)),
+        axis=2, dtype=jnp.uint32)                       # [W, K]
+    keys = lids[:, None] * k + jnp.arange(k, dtype=jnp.int32)
+    mine = (row_leaf * k + (fbins >> 5))[None, :] == keys.reshape(-1, 1)
+    word = _per_row(mine, words.reshape(-1))
+    return ((word >> (fbins & 31).astype(jnp.uint32)) & 1) == 1
 
 
 def apply_wave_splits(row_leaf: jax.Array, bins_fm: jax.Array,
@@ -102,35 +150,59 @@ def apply_wave_splits(row_leaf: jax.Array, bins_fm: jax.Array,
                       default_lefts: jax.Array, cat_masks: jax.Array,
                       valid: jax.Array, num_bins: jax.Array,
                       missing_type: jax.Array, is_categorical: jax.Array,
-                      num_leaves: int, bundle=None) -> jax.Array:
+                      num_leaves: int, bundle=None,
+                      has_categorical: bool = True) -> jax.Array:
     """Apply a whole wave's W splits in ONE pass over the rows.
 
     A wave's split leaves are pairwise distinct and a leaf created
     within the wave is never split in the same wave (its candidates are
     unknown until the boundary), so each row moves AT MOST once per
-    wave — the W sequential apply_split passes (each reading a bin row
-    + row_leaf, ~9 bytes/row/split of HBM traffic) collapse into one
-    gathered decision (~40 bytes/row/WAVE). This is the partition
-    analog of the multi-leaf histogram kernel and the main HBM saving
-    of waved growth beyond the histogram batching itself.
+    wave and the W sequential apply_split passes collapse into one
+    decision per row, bit-equal to that chain.
+
+    Nothing row-sized is gathered. Each step's facts (feature,
+    threshold, NaN bin, default side, right child; for EFB storage the
+    group, offset and width) are looked up at [W] and packed into one
+    or two int32 records; a row finds its step by comparing its leaf
+    with the W split leaves, and its bin on that step's feature by
+    comparing that feature with the F stored rows, so the pass reads
+    row_leaf and every bin once. has_categorical (static) False traces
+    nothing categorical. PERF.md sections 5 and 6 (PR 27) hold what
+    the pass costs on the chip.
     """
     global_metrics.note_trace("ops/partition_wave")
-    w_count = leaf_ids.shape[0]
     L = num_leaves
+    B = cat_masks.shape[1]
     lids = jnp.where(valid, leaf_ids, L)
-    table = jnp.full((L + 1,), -1, jnp.int32).at[lids].set(
-        jnp.arange(w_count, dtype=jnp.int32))
-    widx = table[row_leaf]
-    hit = widx >= 0
-    w = jnp.maximum(widx, 0)
-    feat = features[w]                              # [N]
-    fbins = _per_row_feature_bins(bins_fm, feat, bundle)
-    nan_bin = num_bins[feat] - 1
-    is_nan = (missing_type[feat] == MISSING_NAN) & (fbins == nan_bin)
-    go_num = jnp.where(is_nan, default_lefts[w], fbins <= thresholds[w])
-    go_left = jnp.where(is_categorical[feat], cat_masks[w, fbins], go_num)
-    move = hit & ~go_left
-    return jnp.where(move, right_ids[w], row_leaf)
+    m = row_leaf[None, :] == lids[:, None]              # [W, N], fused
+    nan_code = jnp.where(missing_type[features] == MISSING_NAN,
+                         num_bins[features], 0)         # NaN bin + 1
+    fields = {
+        "hit": (jnp.ones_like(features), 1),
+        "row": (features if bundle is None else bundle[0][features],
+                _bits(bins_fm.shape[0] - 1)),      # stored row of bins_fm
+        "thr": (thresholds, _bits(B - 1)),
+        "nan_code": (nan_code, _bits(B)),
+        "dleft": (default_lefts, 1),
+        "right": (right_ids, _bits(L - 1))}
+    if has_categorical:
+        fields["is_cat"] = (is_categorical[features], 1)
+    if bundle is not None:
+        _, offset_of, nb = bundle
+        stored = bins_fm.data if isinstance(bins_fm, PackedBins) else bins_fm
+        fields["off"] = (offset_of[features], 8 * stored.dtype.itemsize + 1)
+        fields["nb"] = (nb[features], _bits(B))
+    r = _per_row_fields(m, fields)
+    fbins = _per_row_feature_bins(bins_fm, r["row"])
+    if bundle is not None:
+        fbins = _decode_bundled(fbins, r["off"], r["nb"])
+    go_left = jnp.where(fbins + 1 == r["nan_code"], r["dleft"] == 1,
+                        fbins <= r["thr"])
+    if has_categorical:
+        cat_left = _per_row_cat_bit(row_leaf, lids, cat_masks, fbins)
+        go_left = jnp.where(r["is_cat"] == 1, cat_left, go_left)
+    move = (r["hit"] == 1) & ~go_left
+    return jnp.where(move, r["right"], row_leaf)
 
 
 def apply_split(row_leaf: jax.Array, bins_fm: jax.Array,

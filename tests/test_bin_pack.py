@@ -74,12 +74,11 @@ def test_pack_roundtrip(max_bins, n):
     assert pb.nbytes <= f * (pb.section)
     dev = to_device(pb)
     np.testing.assert_array_equal(np.asarray(unpack_bins(dev)), bins)
-    # gathered per-row unpack (the partition path)
+    # per-row unpack (the partition path)
     feat = r.randint(0, f, n).astype(np.int32)
-    rows = np.arange(n)
     np.testing.assert_array_equal(
-        np.asarray(unpack_rows(dev, jnp.asarray(feat), jnp.asarray(rows))),
-        bins[feat, rows])
+        np.asarray(unpack_rows(dev, jnp.asarray(feat))),
+        bins[feat, np.arange(n)])
     np.testing.assert_array_equal(np.asarray(unpack_feature(dev, 0)),
                                   bins[0])
 

@@ -28,6 +28,7 @@ from lightgbm_tpu.ops import pallas_histogram as ph
 from lightgbm_tpu.ops.bin_pack import PACK_ALIGN, PackedBins
 
 F, N, SLOTS = 28, 1 << 20, 42  # Higgs width, 2^20 rows, one full wave
+ITER_ROWS = 1 << 14            # rows of the whole iteration program below
 
 
 @pytest.fixture(scope="module")
@@ -172,14 +173,13 @@ def test_row_operands_stay_lane_dense_at_higgs_rows(one_chip, kind):
 # chip's compiler leaves of the lgbm/<layer> scopes is what the benchmark's
 # per-layer seconds are read through
 @pytest.fixture(scope="module")
-def fused_iter_table(one_chip):
-    """{instruction_head: layer} of ``boosting/fused_iter`` compiled for
-    the described chip: the benchmark cell's path (int8 gradients, F=28,
-    63 bins) at 16k rows and 31 leaves, a 15 s compile."""
+def fused_iter_text(one_chip):
+    """``boosting/fused_iter`` compiled for the described chip, as text:
+    the benchmark cell's path (int8 gradients, F=28, 63 bins) at 16k rows
+    and 31 leaves, a 15 s compile."""
     import numpy as np
 
     import lightgbm_tpu as lgb
-    from lightgbm_tpu.obs.profile import parse_layer_table
     from lightgbm_tpu.ops import histogram as hist_ops
     mp = pytest.MonkeyPatch()
     # the Booster is built on the CPU; the program takes its TPU branches
@@ -187,7 +187,7 @@ def fused_iter_table(one_chip):
     mp.setattr(hist_ops, "cpu_backend", lambda: False)
     try:
         r = np.random.RandomState(0)
-        x = r.randn(1 << 14, F) + 0.26
+        x = r.randn(ITER_ROWS, F) + 0.26
         y = (x[:, 0] + x[:, 1] > 0.5).astype(np.float64)
         g = lgb.Booster({"objective": "binary", "num_leaves": 31,
                          "max_bin": 63, "verbosity": -1,
@@ -202,10 +202,16 @@ def fused_iter_table(one_chip):
         shapes = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), args)
-        text = g._make_fused().lower(*shapes).compile().as_text()
+        return g._make_fused().lower(*shapes).compile().as_text()
     finally:
         mp.undo()
-    return parse_layer_table(text)
+
+
+@pytest.fixture(scope="module")
+def fused_iter_table(fused_iter_text):
+    """{instruction_head: layer} of that program."""
+    from lightgbm_tpu.obs.profile import parse_layer_table
+    return parse_layer_table(fused_iter_text)
 
 
 @pytest.mark.parametrize("layer", ["gradient", "hist", "split",
@@ -223,13 +229,14 @@ def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table):
     assert kernels and set(kernels.values()) == {"hist"}
 
 
-@pytest.mark.parametrize("shape,layer", [("u8[16384]", "partition"),
-                                         ("s32[16384]", "partition"),
+@pytest.mark.parametrize("shape,layer", [("s32[16384]", "partition"),
+                                         ("s8[3,16384]", "hist"),
                                          ("f32[16384]", "score")])
 def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
-    """The fusions that carry the benchmark cell's seconds (PERF.md
-    section 5: ``u8[N]`` the bin gather, ``s32[N]`` the row-to-leaf
-    update, ``f32[N]`` the score update) each fall under one layer. The
+    """The row-sized fusions each fall under one layer: ``s32[N]`` the
+    wave partition's compare-and-select passes (PR 27; the ``u8[N]`` bin
+    gather it replaced is gone), ``s8[3, N]`` the int8 kernel's
+    operand, ``f32[N]`` the score update (PERF.md section 5). The
     ``layer_*_s`` metrics are defined by the scopes in the program: a
     change that moves a ``named_scope`` moves seconds between them, and
     shows here first."""
@@ -237,3 +244,66 @@ def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
            if "fusion" in head.split(" = ")[0]
            and head.split(" = ")[1].startswith(shape + "{")}
     assert got == {layer}
+
+
+def _row_sized_gathers(text, rows, scope):
+    """Instructions of a compiled program traced under `scope` that
+    gather a result with a `rows`-long dimension: a ``gather``, or a
+    fusion whose computation holds one."""
+    import re
+    row_sized = re.compile(rf"[\[,]{rows}[\],]")
+    gathering, comp = set(), None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            comp = line.removeprefix("ENTRY ").split(" ", 1)[0]
+        elif (" gather(" in line
+              and row_sized.search(line.split(" gather(")[0])):
+            gathering.add(comp)
+    bad = []
+    for line in text.splitlines():
+        head = line.split(", metadata=")[0]
+        calls = re.search(r"calls=(%[\w.\-]+)", line)
+        if scope in line and (
+                (" gather(" in head
+                 and row_sized.search(head.split(" gather(")[0]))
+                or (calls and calls.group(1) in gathering)):
+            bad.append(line.strip()[:200])
+    return bad
+
+
+def test_partition_gathers_nothing_row_sized(fused_iter_text):
+    """PR 27: ``apply_wave_splits`` decides every row's move by
+    compare-and-select. The gathers it keeps are [W]-sized (a step's
+    facts by its feature); a row-sized one read 475 GB a wave at the
+    benchmark cell's size (PERF.md section 6)."""
+    assert "lgbm/partition" in fused_iter_text
+    assert not _row_sized_gathers(fused_iter_text, ITER_ROWS,
+                                  "lgbm/partition")
+    # the detector sees a row-sized gather where there is one: the score
+    # update's leaf_vals[row_leaf] at a width XLA does not rewrite
+    gathered = jax.jit(lambda t, i: t[i]).lower(
+        jax.ShapeDtypeStruct((1 << 12,), jnp.float32),
+        jax.ShapeDtypeStruct((ITER_ROWS,), jnp.int32)).compile().as_text()
+    assert _row_sized_gathers(gathered, ITER_ROWS, "")
+
+
+@pytest.mark.parametrize("rows,features", [(1 << 20, 28), (1 << 17, 2000)])
+def test_wave_partition_keeps_row_sized_temporaries(one_chip, rows,
+                                                    features):
+    """``apply_wave_splits`` alone, one full wave, at the narrow and the
+    wide shape: what the program holds beside its operands stays under
+    four row-sized int32 vectors, so no [W, N] match matrix and no
+    [F, N] select is ever written out."""
+    from lightgbm_tpu.ops import partition as part_ops
+    s = _shapes(one_chip)
+    w, b, leaves = SLOTS, 63, 255
+    step = [s((w,), jnp.int32)] * 4 + [s((w,), jnp.bool_),
+                                       s((w, b), jnp.bool_),
+                                       s((w,), jnp.bool_)]
+    meta = [s((features,), jnp.int32)] * 2 + [s((features,), jnp.bool_)]
+    fn = functools.partial(part_ops.apply_wave_splits, num_leaves=leaves,
+                           has_categorical=False)
+    mem = jax.jit(fn).lower(s((rows,), jnp.int32),
+                            s((features, rows), jnp.uint8), *step,
+                            *meta).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * 4 * rows, mem

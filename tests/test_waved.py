@@ -233,77 +233,176 @@ def test_hist_pallas_single_matches_xla():
                                rtol=1e-6, atol=1e-6)
 
 
-def test_apply_wave_splits_matches_sequential():
-    """The batched wave partition must be BIT-equivalent to the
-    sequential apply_split chain it replaced (dense + EFB-bundled,
-    categorical, NaN default-left routing, invalid steps)."""
-    import jax.numpy as jnp
+def _wave_case(rng, N, F, B, L, W, *, live=8, all_invalid=False,
+               has_categorical=True, bins_dtype=np.uint8):
+    """One random wave over `live` current leaves: W distinct split
+    leaves (the last step invalid, or all of them), right ids counting
+    down from L - 1."""
+    assert W <= live and live + W <= L + 1
+    c = dict(
+        bins=rng.randint(0, B, (F, N)).astype(bins_dtype),
+        row_leaf=rng.randint(0, live, N).astype(np.int32),
+        leaves=rng.permutation(live)[:W].astype(np.int32),
+        rights=(L - 1 - np.arange(W)).astype(np.int32),
+        feats=rng.randint(0, F, W).astype(np.int32),
+        thrs=rng.randint(0, B - 1, W).astype(np.int32),
+        dlefts=rng.rand(W) > 0.5,
+        cmasks=rng.rand(W, B) > 0.5,
+        valid=np.ones(W, bool),
+        num_bins=rng.randint(max(B // 2, 2), B + 1, F).astype(np.int32),
+        missing=rng.randint(0, 3, F).astype(np.int32),
+        is_cat=(rng.rand(F) > 0.7) & has_categorical)
+    c["num_bins"][0] = B
+    c["valid"][-1] = False
+    if all_invalid:
+        c["valid"][:] = False
+    return c
+
+
+def _sequential(part_ops, c, bins, bundle=None):
+    seq = jnp.asarray(c["row_leaf"])
+    for w in range(len(c["leaves"])):
+        seq = part_ops.apply_split(
+            seq, bins, jnp.int32(c["leaves"][w]), jnp.int32(c["rights"][w]),
+            jnp.int32(c["feats"][w]), jnp.int32(c["thrs"][w]),
+            jnp.bool_(c["dlefts"][w]), jnp.asarray(c["cmasks"][w]),
+            jnp.asarray(c["num_bins"]), jnp.asarray(c["missing"]),
+            jnp.asarray(c["is_cat"]), jnp.bool_(c["valid"][w]), bundle)
+    return np.asarray(seq)
+
+
+def _batched(part_ops, c, bins, L, bundle=None, has_categorical=True):
+    return np.asarray(part_ops.apply_wave_splits(
+        jnp.asarray(c["row_leaf"]), bins, jnp.asarray(c["leaves"]),
+        jnp.asarray(c["rights"]), jnp.asarray(c["feats"]),
+        jnp.asarray(c["thrs"]), jnp.asarray(c["dlefts"]),
+        jnp.asarray(c["cmasks"]), jnp.asarray(c["valid"]),
+        jnp.asarray(c["num_bins"]), jnp.asarray(c["missing"]),
+        jnp.asarray(c["is_cat"]), L, bundle, has_categorical))
+
+
+# the cases apply_wave_splits branches on (static shapes and storage
+# class): N, F, B, L, W and what differs from the dense mix
+WAVE_CASES = {
+    "dense-mix": dict(N=500, F=6, B=16, L=15, W=5),
+    "no-categorical": dict(N=500, F=6, B=16, L=15, W=5,
+                           has_categorical=False),
+    # a full wave of the flagship tree: right ids up to 254, two records
+    "W42-L255": dict(N=3000, F=28, B=63, L=255, W=42, live=213),
+    # 8 mask words; a categorical split on the last bin
+    "B255-cat-bin254": dict(N=2000, F=6, B=255, L=31, W=5, cat_last=True),
+    "F33": dict(N=700, F=33, B=16, L=15, W=5),
+    "all-invalid": dict(N=500, F=6, B=16, L=15, W=5, all_invalid=True),
+    # uint16 bins: thresholds and NaN codes wider than a byte
+    "B300-uint16": dict(N=1500, F=5, B=300, L=15, W=4,
+                        bins_dtype=np.uint16),
+    # records wider than one int32: feature ids above 2^15, leaf ids
+    # above 2^16
+    "wide-ids": dict(N=300, F=40000, B=16, L=70000, W=3),
+    "efb-bundle": dict(N=900, F=7, B=16, L=15, W=5, bundle=True),
+    "packed-4bit": dict(N=2500, F=6, B=15, L=15, W=5, packed=True),
+    "packed-2bit": dict(N=4500, F=6, B=3, L=15, W=5, packed=True),
+}
+
+
+@pytest.mark.parametrize("case", list(WAVE_CASES))
+def test_apply_wave_splits_matches_sequential(case):
+    """The wave partition must be BIT-equal to the sequential
+    apply_split chain it stands for: NaN default routing, invalid
+    steps, categorical masks, every storage class and every record
+    width it packs."""
     from lightgbm_tpu.ops import partition as part_ops
+    from lightgbm_tpu.ops.bin_pack import pack_bins_host, to_device
 
-    rng = np.random.RandomState(0)
-    N, F, B, L, W = 500, 6, 16, 15, 5
-    for trial in range(8):
-        bins = rng.randint(0, B, (F, N)).astype(np.uint8)
-        row_leaf = rng.randint(0, 8, N).astype(np.int32)
-        # distinct split leaves; last one invalid
-        leaves = rng.permutation(8)[:W].astype(np.int32)
-        rights = (8 + np.arange(W)).astype(np.int32)
-        feats = rng.randint(0, F, W).astype(np.int32)
-        thrs = rng.randint(0, B - 1, W).astype(np.int32)
-        dlefts = rng.rand(W) > 0.5
-        cmasks = rng.rand(W, B) > 0.5
-        valid = np.ones(W, bool)
-        valid[-1] = False
-        num_bins = np.full(F, B, np.int32)
-        missing = rng.randint(0, 3, F).astype(np.int32)
-        is_cat = rng.rand(F) > 0.7
+    kw = dict(WAVE_CASES[case])
+    bundle_case = kw.pop("bundle", False)
+    packed = kw.pop("packed", False)
+    cat_last = kw.pop("cat_last", False)
+    L, B = kw["L"], kw["B"]
+    has_cat = kw.get("has_categorical", True)
+    rng = np.random.RandomState(len(case))
+    for trial in range(2 if kw["F"] > 1000 else 6):
+        c = _wave_case(rng, **kw)
+        if cat_last:
+            f = c["feats"][0]
+            c["is_cat"][f] = True
+            c["num_bins"][f] = B
+            c["cmasks"][0, B - 1] = trial % 2 == 0
+            rows = np.flatnonzero(c["row_leaf"] == c["leaves"][0])[::2]
+            c["bins"][f, rows] = B - 1
+        bins, bundle = jnp.asarray(c["bins"]), None
+        if packed:
+            bins = to_device(pack_bins_host(c["bins"], B))
+        if bundle_case:
+            # 7 logical features in 3 stored columns: logical bin b >= 1
+            # of feature f is stored as offset_of[f] + b - 1, 0 is shared
+            group_of = np.array([0, 0, 0, 1, 1, 2, 2], np.int32)
+            nb = np.array([5, 4, 6, 7, 3, 9, 8], np.int32)
+            offset_of = np.array([1, 5, 8, 1, 7, 1, 9], np.int32)
+            owner = rng.randint(0, 7, kw["N"])
+            stored = np.zeros((3, kw["N"]), np.uint8)
+            for f in range(7):
+                mine = owner == f
+                logical = rng.randint(0, nb[f], kw["N"])
+                stored[group_of[f], mine] = np.where(
+                    logical[mine] > 0, offset_of[f] + logical[mine] - 1, 0)
+            c["num_bins"] = nb
+            c["thrs"] = np.minimum(c["thrs"], nb[c["feats"]] - 1)
+            bins = jnp.asarray(stored)
+            bundle = (jnp.asarray(group_of), jnp.asarray(offset_of),
+                      jnp.asarray(nb))
+        seq = _sequential(part_ops, c, bins, bundle)
+        got = _batched(part_ops, c, bins, L, bundle, has_cat)
+        np.testing.assert_array_equal(seq, got)
+        if not c["valid"].any():
+            np.testing.assert_array_equal(got, c["row_leaf"])
+        else:
+            assert (got != c["row_leaf"]).any()
 
-        seq = jnp.asarray(row_leaf)
-        for w in range(W):
-            seq = part_ops.apply_split(
-                seq, jnp.asarray(bins), jnp.int32(leaves[w]),
-                jnp.int32(rights[w]), jnp.int32(feats[w]),
-                jnp.int32(thrs[w]), jnp.bool_(dlefts[w]),
-                jnp.asarray(cmasks[w]), jnp.asarray(num_bins),
-                jnp.asarray(missing), jnp.asarray(is_cat),
-                jnp.bool_(valid[w]))
-        batched = part_ops.apply_wave_splits(
-            jnp.asarray(row_leaf), jnp.asarray(bins),
-            jnp.asarray(leaves), jnp.asarray(rights), jnp.asarray(feats),
-            jnp.asarray(thrs), jnp.asarray(dlefts), jnp.asarray(cmasks),
-            jnp.asarray(valid), jnp.asarray(num_bins),
-            jnp.asarray(missing), jnp.asarray(is_cat), L)
-        np.testing.assert_array_equal(np.asarray(seq),
-                                      np.asarray(batched))
+
+def _grower_data(case, rng, n):
+    """(X, y, dataset params, categorical columns) of one storage class."""
+    if case == "bundle":
+        # one-hot-ish mutually exclusive features so EFB actually bundles
+        hot = rng.randint(0, 6, n)
+        X = np.zeros((n, 6))
+        X[np.arange(n), hot] = rng.rand(n) * 3 + 0.5
+        return X, np.isin(hot, [1, 4]).astype(np.float32), {"max_bin": 15}, []
+    X = rng.randn(n, 6)
+    X[rng.rand(n) < 0.1, 2] = np.nan            # a NaN bin to route
+    y = (X[:, 0] + 0.5 * X[:, 1] ** 2 > 0.4).astype(np.float32)
+    if case == "categorical":
+        X[:, 3] = rng.randint(0, 12, n)
+        y = np.where(np.isin(X[:, 3], [2, 5, 7]), 1.0, y).astype(np.float32)
+        return X, y, {"max_bin": 63}, [3]
+    return X, y, {"max_bin": 15 if case == "packed" else 63}, []
 
 
-@pytest.mark.slow
-def test_batched_partition_through_grower_with_bundle():
-    """Force the batched wave partition (the TPU default) through the
-    FULL waved grower on CPU, on EFB-bundled one-hot data, and require
-    agreement with the per-split partition (the CPU default) — covers
-    the call-site wiring and the bundle-decode path of
-    partition._per_row_feature_bins end-to-end."""
-    import functools
-    import jax.numpy as jnp
+@pytest.mark.parametrize("case", [
+    "dense", "categorical", "packed",
+    pytest.param("bundle", marks=pytest.mark.slow)])
+def test_batched_partition_through_grower(case):
+    """Force the wave partition (the TPU default) through the FULL waved
+    grower on CPU and require the tree and every row's leaf of the
+    per-split partition (the CPU default, apply_split): the call-site
+    wiring, has_categorical, and each storage class end to end (dense,
+    a categorical feature, PackedBins, an EFB bundle)."""
     from lightgbm_tpu import Dataset
-    from lightgbm_tpu.learner import grow_tree_waved
-
-    rng = np.random.RandomState(9)
-    n = 1500
-    # one-hot-ish mutually exclusive features so EFB actually bundles
-    hot = rng.randint(0, 6, n)
-    X = np.zeros((n, 6))
-    X[np.arange(n), hot] = rng.rand(n) * 3 + 0.5
-    y = np.isin(hot, [1, 4]).astype(np.float32)
-    ds = Dataset(X, label=y, params={"max_bin": 15,
-                                     "verbosity": -1}).construct()
-    binned = ds._binned
-    assert binned.bundle_info is not None, "EFB must engage for this test"
     from lightgbm_tpu.basic import Booster
+    from lightgbm_tpu.learner import grow_tree_waved
+    from lightgbm_tpu.ops.bin_pack import PackedBins
+
+    n = 1500
+    X, y, ds_params, cats = _grower_data(case, np.random.RandomState(9), n)
+    ds = Dataset(X, label=y, categorical_feature=cats or "auto",
+                 params={**ds_params, "verbosity": -1}).construct()
+    binned = ds._binned
     bst = Booster({"objective": "binary", "num_leaves": 15,
-                   "min_data_in_leaf": 5, "verbosity": -1}, ds)
+                   "min_data_in_leaf": 5, "verbosity": -1, **ds_params}, ds)
     g = bst._gbdt
+    assert (binned.bundle_info is not None) == (case == "bundle")
+    assert isinstance(g.bins_fm, PackedBins) == (case == "packed")
+    assert g._has_categorical == (case == "categorical")
     grad = jnp.asarray(y - 0.5, jnp.float32)
     hess = jnp.full(n, 0.25, jnp.float32)
     mask = jnp.ones(n, jnp.float32)
@@ -317,7 +416,10 @@ def test_batched_partition_through_grower_with_bundle():
             g.bins_fm, grad, hess, mask, fmask, g.feature_meta, g.hp,
             g.max_depth, None, None, batched_partition=batched, **kw)
         outs[batched] = (np.asarray(row_leaf), np.asarray(rec.leaf_count),
-                        np.asarray(rec.split_feature))
-    np.testing.assert_array_equal(outs[False][0], outs[True][0])
-    np.testing.assert_array_equal(outs[False][1], outs[True][1])
-    np.testing.assert_array_equal(outs[False][2], outs[True][2])
+                        np.asarray(rec.split_feature),
+                        np.asarray(rec.split_cat_mask))
+    assert int(np.asarray(rec.num_leaves)) >= (3 if case == "bundle" else 8)
+    if case == "categorical":
+        assert 3 in outs[True][2]
+    for a, b in zip(outs[False], outs[True]):
+        np.testing.assert_array_equal(a, b)
