@@ -1189,7 +1189,7 @@ class GBDT:
         """Per-class sampling/quantization + the grower's resident
         operands: the pre-masked ghT histogram operand (int8 when the
         int8 wave path applies, f32 otherwise), its dequantization
-        vector, the root sums, and the feature mask. Identical RNG
+        vector, and the feature mask. Identical RNG
         salts to _grow_class_traced."""
         use_int8 = (self._quant_enabled and
                     int(self.config.num_grad_quant_bins) <= 126)
@@ -1209,10 +1209,6 @@ class GBDT:
             fmask = self._feature_mask_in_jit(
                 jax.random.fold_in(key, 200 + k))
             f32 = jnp.float32
-            with jax.named_scope("lgbm/split"):
-                root_g = jnp.sum(grad * mask, dtype=f32)
-                root_h = jnp.sum(hess * mask, dtype=f32)
-                root_c = jnp.sum(mask, dtype=f32)
             with jax.named_scope("lgbm/gradient"):
                 if use_int8:
                     g_int, h_int, g_scale, h_scale = quant
@@ -1226,8 +1222,7 @@ class GBDT:
                     ghT = jnp.stack([grad * mask, hess * mask, mask],
                                     axis=1).astype(f32)
                     hscale = jnp.ones((3,), f32)
-            return (ghT, hscale, root_g, root_h, root_c, fmask,
-                    true_grad, true_hess, mask)
+            return (ghT, hscale, fmask, true_grad, true_hess, mask)
         return class_prep
 
     def _make_stream_class_post(self, k: int):
@@ -1276,15 +1271,15 @@ class GBDT:
         class prep program -> host-orchestrated slab grower."""
         cp = self._stream_prog(f"class_prep_{k}",
                                lambda: self._make_stream_class_prep(k))
-        (ghT, hscale, root_g, root_h, root_c, fmask,
-         true_grad, true_hess, mask) = cp(grad_k, hess_k, sample_mask, it)
+        ghT, hscale, fmask, true_grad, true_hess, mask = cp(
+            grad_k, hess_k, sample_mask, it)
         node_key = (jax.random.fold_in(
             self._extra_key,
             self.iter * self.num_tree_per_iteration + k)
             if self._use_node_rand else None)
         rec, row_leaf = self._stream_grower.grow(
-            ghT, hscale, (root_g, root_h, root_c), fmask,
-            self.feature_meta, self.hp, self.max_depth, node_key)
+            ghT, hscale, fmask, self.feature_meta, self.hp,
+            self.max_depth, node_key)
         return rec, row_leaf, mask, true_grad, true_hess
 
     def _stream_grow_slow(self, bins_fm, grad, hess, mask, feature_mask,
@@ -1296,20 +1291,14 @@ class GBDT:
         assert forced is None, \
             "forced splits are gated out of streaming at resolve time"
 
+        @jax.named_scope("lgbm/gradient")
         def basic_prep(grad_, hess_, mask_):
-            f32 = jnp.float32
-            with jax.named_scope("lgbm/gradient"):
-                ghT = jnp.stack([grad_ * mask_, hess_ * mask_, mask_],
-                                axis=1).astype(f32)
-            with jax.named_scope("lgbm/split"):
-                return (ghT, jnp.sum(grad_ * mask_, dtype=f32),
-                        jnp.sum(hess_ * mask_, dtype=f32),
-                        jnp.sum(mask_, dtype=f32))
+            return jnp.stack([grad_ * mask_, hess_ * mask_, mask_],
+                             axis=1).astype(jnp.float32)
 
         prep = self._stream_prog("slow_prep", lambda: basic_prep)
-        ghT, root_g, root_h, root_c = prep(grad, hess, mask)
         return self._stream_grower.grow(
-            ghT, jnp.ones((3,), jnp.float32), (root_g, root_h, root_c),
+            prep(grad, hess, mask), jnp.ones((3,), jnp.float32),
             feature_mask, meta, hp, max_depth, node_key)
 
     def _note_stream_meta(self) -> None:

@@ -246,9 +246,10 @@ def expand_bundle_hist(bundle_hist, group_of, offset_of, nb,
 
     nb: [F] logical bin counts; totals: [..., C] per-leaf channel totals
     (each feature's default-bin row = total - sum of its own non-default
-    bins). Rows b >= nb[f] contain neighboring features' bins — the
-    split finder masks them via FeatureMeta.num_bins, and the bin-0
-    subtraction here masks them explicitly.
+    bins). Rows b >= nb[f] would hold neighboring features' bins: they
+    are zeroed, so that every feature's bins sum to the totals as in an
+    unbundled histogram (the split scan and hist_ops.node_totals sum a
+    feature's whole row).
     """
     import jax.numpy as jnp
     b_tot = bundle_hist.shape[-2]
@@ -262,7 +263,6 @@ def expand_bundle_hist(bundle_hist, group_of, offset_of, nb,
         gathered.shape[:-2] + (max_bins, gathered.shape[-1]))
     hist = jnp.take_along_axis(gathered, idx, axis=-2)  # [..., F, B, C]
     own = (bidx[None, :] >= 1) & (bidx[None, :] < nb[:, None])  # [F, B]
-    nondefault = jnp.sum(hist * own[..., None], axis=-2)  # [..., F, C]
-    default_row = totals[..., None, :] - nondefault
-    hist = hist.at[..., 0, :].set(default_row)
-    return hist
+    hist = jnp.where(own[..., None], hist, 0.0)
+    default_row = totals[..., None, :] - jnp.sum(hist, axis=-2)
+    return hist.at[..., 0, :].set(default_row)

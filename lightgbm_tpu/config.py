@@ -504,13 +504,18 @@ class Config:
     # passes on large multiclass data.
     tpu_wave_max: int = -1
     # MXU precision of the histogram one-hot contraction: "default" =
-    # single bf16 pass with f32 accumulation (the one-hot operand is
-    # exact in bf16; the grad/hess operand is rounded to 8 mantissa
-    # bits — noise far below the gradient-quantization the reference
-    # itself ships with use_quantized_grad), "high" = 3-pass, "highest"
-    # = 6-pass f32 emulation. On CPU (tests) every mode is exact f32.
-    # Measured on the TPU chip: "default" matches "highest" AUC to
-    # ~1e-3 at Higgs shape while cutting iteration time ~2x.
+    # one bf16 pass with f32 accumulation: the one-hot operand is exact
+    # in bf16, each row's gradient and hessian are rounded to bf16 (8
+    # significant bits, up to 2^-9 relative, and the same way for every
+    # row that holds the same value: at a constant hessian the bins sum
+    # to a total up to 0.2% off the gradients' own sum); "high" = 3
+    # passes, "highest" = 6-pass f32 emulation. On the CPU (tests) every
+    # mode is exact f32. The bins are therefore not sums of the
+    # gradients, in any mode (the kernel also adds in its own order), and
+    # a node's totals are read from its bins, never from the gradients
+    # (ops/histogram.node_totals, ops/split._gain_tensors). What each
+    # mode reads against the reference on the chip: PERF.md sections 2
+    # and 7.1; no record compares their speed.
     tpu_hist_precision: str = "default"
     # histogram kernel implementation: "auto" = pallas on TPU backends /
     # one-hot XLA contraction elsewhere; "pallas" / "xla" force one

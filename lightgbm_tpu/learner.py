@@ -80,14 +80,41 @@ class _LeafSplits(NamedTuple):
     feature: jax.Array    # [L] int32
     threshold: jax.Array  # [L] int32
     default_left: jax.Array  # [L] bool
-    left_sum_grad: jax.Array
-    left_sum_hess: jax.Array
-    left_count: jax.Array
+    # [L, 2, 3] the candidate's (left, right) x (grad, hess, count) as
+    # the split scan summed them from the leaf's own bins: a child's
+    # sum_grad/sum_hess/count are copied from here, never formed as the
+    # leaf's totals less the other side. One array, so that a scan step
+    # reads and writes it once (a step costs by its count of small ops)
+    sides: jax.Array
     left_output: jax.Array   # [L] candidate left-child output
     right_output: jax.Array  # [L] candidate right-child output
     cat_mask: jax.Array      # [L, B] bool candidate categorical mask
     min_bound: jax.Array     # [L] monotone lower output bound
     max_bound: jax.Array     # [L] monotone upper output bound
+
+    @classmethod
+    def empty(cls, L: int, max_bins: int, f32) -> "_LeafSplits":
+        """L unused slots: no stats, no candidate, unbounded output."""
+        zero_l = jnp.zeros((L,), f32)
+        return cls(
+            sum_grad=zero_l, sum_hess=zero_l, count=zero_l,
+            depth=jnp.zeros((L,), jnp.int32),
+            output=zero_l,
+            gain=jnp.full((L,), K_MIN_SCORE, f32),
+            feature=jnp.zeros((L,), jnp.int32),
+            threshold=jnp.zeros((L,), jnp.int32),
+            default_left=jnp.zeros((L,), jnp.bool_),
+            sides=jnp.zeros((L, 2, 3), f32),
+            left_output=zero_l, right_output=zero_l,
+            cat_mask=jnp.zeros((L, max_bins), jnp.bool_),
+            min_bound=jnp.full((L,), -jnp.inf, f32),
+            max_bound=jnp.full((L,), jnp.inf, f32),
+        )
+
+    def candidate_sides(self, leaf):
+        """((lg, lh, lc), (rg, rh, rc)) of `leaf`'s stored candidate."""
+        left, right = self.sides[leaf]
+        return tuple(left), tuple(right)
 
 
 class _GrowState(NamedTuple):
@@ -99,6 +126,16 @@ class _GrowState(NamedTuple):
     # leaf feature-range boxes [L, F] int32 (pairwise monotone modes only)
     box_lo: Optional[jax.Array] = None
     box_hi: Optional[jax.Array] = None
+
+
+def _split_sides(info: SplitInfo) -> jax.Array:
+    """[..., 2, 3] (left, right) x (grad, hess, count) of a SplitInfo
+    (scalar fields, or [S]-leading from a vmapped search)."""
+    return jnp.stack(
+        [jnp.stack([info.left_sum_grad, info.left_sum_hess,
+                    info.left_count], axis=-1),
+         jnp.stack([info.right_sum_grad, info.right_sum_hess,
+                    info.right_count], axis=-1)], axis=-2)
 
 
 def _store_split(leaves: _LeafSplits, idx, info: SplitInfo, depth, output,
@@ -117,9 +154,7 @@ def _store_split(leaves: _LeafSplits, idx, info: SplitInfo, depth, output,
         feature=upd(leaves.feature, info.feature),
         threshold=upd(leaves.threshold, info.threshold),
         default_left=upd(leaves.default_left, info.default_left),
-        left_sum_grad=upd(leaves.left_sum_grad, info.left_sum_grad),
-        left_sum_hess=upd(leaves.left_sum_hess, info.left_sum_hess),
-        left_count=upd(leaves.left_count, info.left_count),
+        sides=upd(leaves.sides, _split_sides(info)),
         left_output=upd(leaves.left_output, info.left_output),
         right_output=upd(leaves.right_output, info.right_output),
         cat_mask=upd(leaves.cat_mask, info.cat_mask),
@@ -458,10 +493,8 @@ def grow_tree(bins_fm: jax.Array,
 
     # --- root (ref: serial_tree_learner.cpp BeforeTrain root LeafSplits init)
     root_hist = build(bins_fm, grad, hess, sample_mask)
-    with jax.named_scope("lgbm/split"):
-        root_g = jnp.sum(grad * sample_mask, dtype=f32)
-        root_h = jnp.sum(hess * sample_mask, dtype=f32)
-        root_c = jnp.sum(sample_mask, dtype=f32)
+    with jax.named_scope("lgbm/split/totals"):
+        root_g, root_h, root_c = hist_ops.node_totals(root_hist)
         root_out = leaf_output(root_g, root_h, hp)
     root_fmask = feature_mask if root_allowed is None else \
         feature_mask & root_allowed
@@ -494,21 +527,7 @@ def grow_tree(bins_fm: jax.Array,
                                    meta, hp, fm_root, root_out,
                                    neg_inf, pos_inf, jnp.int32(0), rb_root)
 
-    zero_l = jnp.zeros((L,), f32)
-    leaves = _LeafSplits(
-        sum_grad=zero_l, sum_hess=zero_l, count=zero_l,
-        depth=jnp.zeros((L,), jnp.int32),
-        output=zero_l,
-        gain=jnp.full((L,), K_MIN_SCORE, f32),
-        feature=jnp.zeros((L,), jnp.int32),
-        threshold=jnp.zeros((L,), jnp.int32),
-        default_left=jnp.zeros((L,), jnp.bool_),
-        left_sum_grad=zero_l, left_sum_hess=zero_l, left_count=zero_l,
-        left_output=zero_l, right_output=zero_l,
-        cat_mask=jnp.zeros((L, max_bins), jnp.bool_),
-        min_bound=jnp.full((L,), -jnp.inf, f32),
-        max_bound=jnp.full((L,), jnp.inf, f32),
-    )
+    leaves = _LeafSplits.empty(L, max_bins, f32)
     leaves = _store_split(leaves, 0, root_split, jnp.int32(1), root_out,
                           root_g, root_h, root_c, neg_inf, pos_inf, True)
 
@@ -557,10 +576,10 @@ def grow_tree(bins_fm: jax.Array,
         bin_sel = jnp.where(f_is_cat, bin_eq,
                             jnp.arange(f_hist.shape[1]) <= f_thr)
         f_left = jnp.sum(f_hist[f_feat] * bin_sel[:, None], axis=0)
-        f_pg, f_ph, f_pc = (leaves.sum_grad[f_leaf], leaves.sum_hess[f_leaf],
-                            leaves.count[f_leaf])
+        f_right = jnp.sum(f_hist[f_feat] * ~bin_sel[:, None], axis=0)
+        f_pg, f_ph = leaves.sum_grad[f_leaf], leaves.sum_hess[f_leaf]
         f_lg, f_lh, f_lc = f_left[GRAD], f_left[HESS], f_left[COUNT]
-        f_rg, f_rh, f_rc = f_pg - f_lg, f_ph - f_lh, f_pc - f_lc
+        f_rg, f_rh, f_rc = f_right[GRAD], f_right[HESS], f_right[COUNT]
         f_parent_out = leaves.output[f_leaf]
         f_out_l = leaf_output_smooth(f_lg, f_lh, f_lc, f_parent_out, hp)
         f_out_r = leaf_output_smooth(f_rg, f_rh, f_rc, f_parent_out, hp)
@@ -586,12 +605,11 @@ def grow_tree(bins_fm: jax.Array,
                              leaves.cat_mask[best_leaf])
 
         # --- children stats: stored candidate, or the forced gather
-        pg, ph, pc = (leaves.sum_grad[best_leaf], leaves.sum_hess[best_leaf],
-                      leaves.count[best_leaf])
-        lg = jnp.where(use_forced, f_lg, leaves.left_sum_grad[best_leaf])
-        lh = jnp.where(use_forced, f_lh, leaves.left_sum_hess[best_leaf])
-        lc = jnp.where(use_forced, f_lc, leaves.left_count[best_leaf])
-        rg, rh, rc = pg - lg, ph - lh, pc - lc
+        ph, pc = leaves.sum_hess[best_leaf], leaves.count[best_leaf]
+        (lg, lh, lc), (rg, rh, rc) = jax.tree_util.tree_map(
+            lambda forced_, stored: jnp.where(use_forced, forced_, stored),
+            ((f_lg, f_lh, f_lc), (f_rg, f_rh, f_rc)),
+            leaves.candidate_sides(best_leaf))
 
         valid = use_forced | (leaves.gain[best_leaf] > 0.0)
         # applied-split counter ids: a forced split can revive growth
@@ -950,12 +968,8 @@ def _wave_step_stored(carry, step_idx, *, L, meta, hp, unknown,
         row_leaf = partition_fn(row_leaf, best_leaf, new_leaf, feat, thr,
                                 dleft, cmask, valid)
 
-    pg, ph, pc = (leaves.sum_grad[best_leaf], leaves.sum_hess[best_leaf],
-                  leaves.count[best_leaf])
-    lg = leaves.left_sum_grad[best_leaf]
-    lh = leaves.left_sum_hess[best_leaf]
-    lc = leaves.left_count[best_leaf]
-    rg, rh, rc = pg - lg, ph - lh, pc - lc
+    ph, pc = leaves.sum_hess[best_leaf], leaves.count[best_leaf]
+    (lg, lh, lc), (rg, rh, rc) = leaves.candidate_sides(best_leaf)
     parent_out = leaves.output[best_leaf]
     p_minb = leaves.min_bound[best_leaf]
     p_maxb = leaves.max_bound[best_leaf]
@@ -1034,19 +1048,22 @@ def _unknown_split(max_bins: int) -> SplitInfo:
         cat_mask=jnp.zeros((max_bins,), jnp.bool_))
 
 
-def _init_wave_state(root_hist, root_g, root_h, root_c, meta, hp,
+def _init_wave_state(root_hist, meta, hp,
                      root_fmask, node_key, *, L, max_bins, num_features,
                      f32, has_categorical, extra_trees, ff_bynode,
                      interaction_groups, split_fn=None):
     """Root leaf state + histogram pool from a built root histogram —
     shared by the resident waved grower and the streamed grower (the
-    streamed root histogram arrives accumulated over slabs).
+    streamed root histogram arrives accumulated over slabs). The root's
+    totals are the histogram's own (hist_ops.node_totals).
 
     split_fn: optional find_best_split replacement (signature minus
     has_categorical) — the feature-sharded scatter search
     (parallel/scatter.py). The pool then inherits the (possibly
     feature-padded) built histogram's shape."""
     neg_inf, pos_inf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
+    with jax.named_scope("lgbm/split/totals"):
+        root_g, root_h, root_c = hist_ops.node_totals(root_hist)
     root_out = leaf_output(root_g, root_h, hp)
     rb_root, fm_root = _node_randomness(node_key, 0, meta, root_fmask,
                                         extra_trees, ff_bynode)
@@ -1060,21 +1077,7 @@ def _init_wave_state(root_hist, root_g, root_h, root_c, meta, hp,
                               meta, hp, fm_root, root_out,
                               neg_inf, pos_inf, jnp.int32(0), rb_root)
 
-    zero_l = jnp.zeros((L,), f32)
-    leaves = _LeafSplits(
-        sum_grad=zero_l, sum_hess=zero_l, count=zero_l,
-        depth=jnp.zeros((L,), jnp.int32),
-        output=zero_l,
-        gain=jnp.full((L,), K_MIN_SCORE, f32),
-        feature=jnp.zeros((L,), jnp.int32),
-        threshold=jnp.zeros((L,), jnp.int32),
-        default_left=jnp.zeros((L,), jnp.bool_),
-        left_sum_grad=zero_l, left_sum_hess=zero_l, left_count=zero_l,
-        left_output=zero_l, right_output=zero_l,
-        cat_mask=jnp.zeros((L, max_bins), jnp.bool_),
-        min_bound=jnp.full((L,), -jnp.inf, f32),
-        max_bound=jnp.full((L,), jnp.inf, f32),
-    )
+    leaves = _LeafSplits.empty(L, max_bins, f32)
     leaves = _store_split(leaves, 0, root_split, jnp.int32(1), root_out,
                           root_g, root_h, root_c, neg_inf, pos_inf, True)
     pool = jnp.zeros((L,) + tuple(root_hist.shape), f32)
@@ -1170,9 +1173,7 @@ def _wave_boundary_core(pool, leaves, used_features, ys, wave_hists,
         feature=upd(leaves.feature, infos.feature),
         threshold=upd(leaves.threshold, infos.threshold),
         default_left=upd(leaves.default_left, infos.default_left),
-        left_sum_grad=upd(leaves.left_sum_grad, infos.left_sum_grad),
-        left_sum_hess=upd(leaves.left_sum_hess, infos.left_sum_hess),
-        left_count=upd(leaves.left_count, infos.left_count),
+        sides=upd(leaves.sides, _split_sides(infos)),
         left_output=upd(leaves.left_output, infos.left_output),
         right_output=upd(leaves.right_output, infos.right_output),
         cat_mask=upd(leaves.cat_mask, infos.cat_mask),
@@ -1403,10 +1404,6 @@ def grow_tree_waved(bins_fm: jax.Array,
         root_ids = jnp.zeros((1,), jnp.int32)
         root_hist = multi(bins_fm, ghT, jnp.zeros((num_data,), jnp.int32),
                           root_ids)[0].astype(f32)
-    with jax.named_scope("lgbm/split"):
-        root_g = jnp.sum(grad * sample_mask, dtype=f32)
-        root_h = jnp.sum(hess * sample_mask, dtype=f32)
-        root_c = jnp.sum(sample_mask, dtype=f32)
     root_fmask = feature_mask if root_allowed is None else \
         feature_mask & root_allowed
     if hist_reduce == "scatter":
@@ -1424,8 +1421,8 @@ def grow_tree_waved(bins_fm: jax.Array,
         split_root_fn = split_wave_fn = None
     with jax.named_scope("lgbm/split"):
         leaves, pool, used_features = _init_wave_state(
-            root_hist, root_g, root_h, root_c, meta, hp, root_fmask,
-            node_key, L=L, max_bins=max_bins, num_features=num_features,
+            root_hist, meta, hp, root_fmask, node_key, L=L,
+            max_bins=max_bins, num_features=num_features,
             f32=f32, has_categorical=has_categorical,
             extra_trees=extra_trees, ff_bynode=ff_bynode,
             interaction_groups=interaction_groups, split_fn=split_root_fn)
@@ -1736,14 +1733,12 @@ class StreamTreeGrower:
         return self._prog("wave_apply", wave_apply)(leaves, n_applied,
                                                     steps, meta, hp)
 
-    def _run_root_finish(self, acc, hscale, root_g, root_h, root_c,
-                         fmask, node_key, meta, hp):
+    def _run_root_finish(self, acc, hscale, fmask, node_key, meta, hp):
         @jax.named_scope("lgbm/split")
-        def root_finish(acc_, hscale_, rg, rh, rc, fmask_, node_key_,
-                        meta_, hp_):
+        def root_finish(acc_, hscale_, fmask_, node_key_, meta_, hp_):
             root_hist = self._scaled(acc_, hscale_)[0].astype(jnp.float32)
             leaves, pool, _ = _init_wave_state(
-                root_hist, rg, rh, rc, meta_, hp_, fmask_, node_key_,
+                root_hist, meta_, hp_, fmask_, node_key_,
                 L=self.L, max_bins=self.max_bins,
                 num_features=self.num_features, f32=jnp.float32,
                 has_categorical=self._has_cat,
@@ -1752,8 +1747,7 @@ class StreamTreeGrower:
             return leaves, pool
 
         return self._prog("root_finish", root_finish)(
-            acc, hscale, root_g, root_h, root_c, fmask, node_key, meta,
-            hp)
+            acc, hscale, fmask, node_key, meta, hp)
 
     def _run_boundary(self, acc, hscale, pool, leaves, ys, fmask,
                       max_depth, node_key, s0, meta, hp):
@@ -1775,20 +1769,17 @@ class StreamTreeGrower:
             s0, meta, hp)
 
     # -- the grower -----------------------------------------------------
-    def grow(self, ghT, hscale, root_sums, feature_mask, meta, hp,
-             max_depth, node_key=None):
+    def grow(self, ghT, hscale, feature_mask, meta, hp, max_depth,
+             node_key=None):
         """Grow one tree over the host-resident slab plan.
 
         ghT: device ``[N, 3]`` pre-masked (g, h, m) operand — f32, or
         int8 with ``hscale`` the [3] dequantization vector (f32 passes
         ``hscale=ones``, applied only on int32 accumulators).
-        root_sums: (root_g, root_h, root_c) scalars, computed by the
-        caller's prep program from the SAME masked gradients.
         Returns (TreeArrays, row_leaf [N]) like the resident growers.
         """
         plan = self.plan
         stats = plan.stats
-        root_g, root_h, root_c = root_sums
         root_ids = jnp.zeros((1,), jnp.int32)
 
         # --- root histogram: one pass over the slabs
@@ -1799,8 +1790,7 @@ class StreamTreeGrower:
             acc = self._run_hist(slab, ghT, rl0, lo, root_ids, acc)
             stats.note_dispatch()
         leaves, pool = self._run_root_finish(
-            acc, hscale, root_g, root_h, root_c, feature_mask, node_key,
-            meta, hp)
+            acc, hscale, feature_mask, node_key, meta, hp)
 
         rl_slabs = None  # per-slab row->leaf pieces (lazily zeros)
         n_applied = jnp.int32(0)

@@ -197,6 +197,17 @@ def hist_multi_sparse(sb, ghT: jax.Array, row_leaf: jax.Array,
     return hist.at[:, jnp.arange(f), sb.zero_bins].add(resid)
 
 
+def node_totals(hist: jax.Array) -> jax.Array:
+    """``[3]`` (sum_grad, sum_hess, count) of the node a ``[F, B, 3]``
+    histogram was built over, read from the histogram itself: every row
+    of the node falls in exactly one bin of feature 0 (dense, packed,
+    expanded-bundle and COO histograms alike; a feature-sharded one is a
+    global value here). A node's totals are taken from here and never
+    from a separate reduction of the gradients, whose rounding would not
+    be the bins' (PERF.md section 7.1)."""
+    return jnp.sum(hist[0], axis=0)
+
+
 def subtract_histogram(parent: jax.Array, child: jax.Array) -> jax.Array:
     """Sibling histogram via subtraction (ref: serial_tree_learner.cpp:582,
     FeatureHistogram::Subtract). Hessians/counts clamped at 0 to absorb

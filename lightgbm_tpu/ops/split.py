@@ -307,19 +307,33 @@ def _gain_tensors(hist: jax.Array,
     split. Returns (gains [F, B, V], aux dict).
     """
     num_features, num_bin_slots, _ = hist.shape
-    prefix = jnp.cumsum(hist, axis=1)  # [F, B, 3]
     t_idx = jnp.arange(num_bin_slots, dtype=jnp.int32)[None, :]  # [1, B]
     nb = meta.num_bins[:, None]  # [F, 1]
+    has_nan = meta.missing_type[:, None] == MISSING_NAN
+
+    # Both sides of every candidate come from THIS histogram's bins: the
+    # left as a sum of bins, the right as the feature's own total (the
+    # sum of all its bins) less the left, in every variant. The parent's stored totals enter
+    # only the gain shift below. A float histogram's bins hold rounded
+    # operands (bf16 on the MXU, float32 sums in the kernel's own order),
+    # so `stored total - prefix` would hand the right side the whole
+    # difference between the two roundings, and a split at a feature's
+    # last bins made a small child out of mostly that (PERF.md section
+    # 7.1). What is left is float32's own rounding of the feature's
+    # total, 6e-8 of the node. (A suffix sum by a second cumsum would be
+    # exact for a small right side and cost 6 ms a tree on the chip,
+    # PERF.md section 6.)
+    prefix = jnp.cumsum(hist, axis=1)  # [F, B, 3]
+    total = prefix[:, -1:, :]          # [F, 1, 3] each feature's own sum
+    # the NaN bin is a feature's last
+    nan_at = ((t_idx == nb - 1) & has_nan)[:, :, None]
+    nan_bin = jnp.sum(jnp.where(nan_at, hist, 0.0), axis=1,
+                      keepdims=True)   # [F, 1, 3]
 
     # --- variant A: missing (NaN bin = last) goes RIGHT; left = prefix[t]
-    left_a = prefix  # [F, B, 3]
-    # --- variant B: missing goes LEFT. right = (non-NaN rows above t)
-    #     = prefix[nb-2] - prefix[t]; left = parent - right.
-    last_non_nan = jnp.take_along_axis(
-        prefix, jnp.maximum(meta.num_bins - 2, 0)[:, None, None], axis=1)  # [F,1,3]
-    right_b = jnp.maximum(last_non_nan - prefix, 0.0)
-
-    parent = jnp.stack([parent_sum_grad, parent_sum_hess, parent_count])
+    left_a = prefix
+    # --- variant B: missing goes LEFT with the bins up to t
+    left_b = prefix + nan_bin
 
     # net-gain shift (ref: FindBestThresholdFromHistogram min_gain_shift;
     # with smoothing the parent's gain is evaluated at its actual output)
@@ -341,7 +355,8 @@ def _gain_tensors(hist: jax.Array,
     cegb_delta = (meta.cegb_feat
                   + (hp.cegb_split_pen + meta.cegb_lazy) * parent_count)
 
-    def eval_variant(left, right, valid_extra, hp_eff):
+    def eval_variant(left, valid_extra, hp_eff):
+        right = total - left
         gl, hl, cl = left[..., GRAD], left[..., HESS], left[..., COUNT]
         gr, hr, cr = right[..., GRAD], right[..., HESS], right[..., COUNT]
         out_l = smooth_output(leaf_output(gl, hl, hp_eff), cl, parent_output,
@@ -378,16 +393,13 @@ def _gain_tensors(hist: jax.Array,
 
     is_cat = meta.is_categorical[:, None]
     base_valid_a = (t_idx < nb - 1) & ~is_cat
-    has_nan = meta.missing_type[:, None] == MISSING_NAN
     base_valid_b = has_nan & (t_idx < nb - 2) & ~is_cat
     if rand_bins is not None:
         rand_ok = t_idx == rand_bins[:, None]
         base_valid_a = base_valid_a & rand_ok
         base_valid_b = base_valid_b & rand_ok
-    gains_a = eval_variant(left_a, parent[None, None, :] - left_a,
-                           base_valid_a, hp)
-    gains_b = eval_variant(parent[None, None, :] - right_b, right_b,
-                           base_valid_b, hp)
+    gains_a = eval_variant(left_a, base_valid_a, hp)
+    gains_b = eval_variant(left_b, base_valid_b, hp)
 
     # --- variant C: categorical one-hot split, bin == t goes LEFT
     # (ref: feature_histogram.cpp:188-242 one-hot branch when
@@ -396,10 +408,9 @@ def _gain_tensors(hist: jax.Array,
     left_c = hist
     onehot_ok = nb <= hp.max_cat_to_onehot
     base_valid_c = is_cat & onehot_ok & (t_idx >= 1) & (t_idx < nb)
-    gains_c = eval_variant(left_c, parent[None, None, :] - left_c,
-                           base_valid_c, hp)
+    gains_c = eval_variant(left_c, base_valid_c, hp)
 
-    aux = dict(left_a=left_a, right_b=right_b, left_c=left_c, parent=parent,
+    aux = dict(left_a=left_a, left_b=left_b, left_c=left_c, total=total,
                parent_gain=parent_gain)
 
     if not has_categorical:
@@ -453,11 +464,11 @@ def _gain_tensors(hist: jax.Array,
 
     # right side must also keep min_data_per_group
     # (feature_histogram.cpp:302-305)
-    right_big_d = (parent[COUNT] - sortP[..., COUNT]) >= G
-    right_big_e = (parent[COUNT] - left_e[..., COUNT]) >= G
-    gains_d = eval_variant(sortP, parent[None, None, :] - sortP,
+    right_big_d = (total - sortP)[..., COUNT] >= G
+    right_big_e = (total - left_e)[..., COUNT] >= G
+    gains_d = eval_variant(sortP,
                            cat_pos_ok & group_ok(sortP) & right_big_d, hp_cat)
-    gains_e = eval_variant(left_e, parent[None, None, :] - left_e,
+    gains_e = eval_variant(left_e,
                            cat_pos_ok & group_ok(left_e) & right_big_e,
                            hp_cat)
 
@@ -529,7 +540,6 @@ def find_best_split(hist: jax.Array,
         hist, parent_sum_grad, parent_sum_hess, parent_count, meta, hp,
         feature_mask, parent_output, min_bound, max_bound, depth,
         has_categorical, rand_bins)
-    parent = aux["parent"]
     num_variants = gains.shape[-1]
     flat = gains.reshape(-1)
     best = jnp.argmax(flat)
@@ -541,19 +551,20 @@ def find_best_split(hist: jax.Array,
     variant_b = variant == 1
     variant_c = variant == 2
 
-    la = aux["left_a"][feature, threshold]
-    rb = aux["right_b"][feature, threshold]
-    lc_ = aux["left_c"][feature, threshold]
-    left = jnp.where(variant_b, parent - rb, jnp.where(variant_c, lc_, la))
+    def at_best(name):
+        return aux[name][feature, threshold]
+
+    left = jnp.where(variant_b, at_best("left_b"),
+                     jnp.where(variant_c, at_best("left_c"),
+                               at_best("left_a")))
     bidx = jnp.arange(num_bin_slots, dtype=jnp.int32)
     cat_mask = variant_c & (bidx == threshold)
 
     if num_variants == 5:
         variant_d = variant == 3
         variant_e = variant == 4
-        ld = aux["sortP"][feature, threshold]
-        le = aux["left_e"][feature, threshold]
-        left = jnp.where(variant_d, ld, jnp.where(variant_e, le, left))
+        left = jnp.where(variant_d, at_best("sortP"),
+                         jnp.where(variant_e, at_best("left_e"), left))
         rank_f = aux["rank"][feature]
         used_f = aux["used"][feature]
         elig_f = aux["eligible"][feature]
@@ -561,7 +572,9 @@ def find_best_split(hist: jax.Array,
         mask_e = (rank_f >= used_f - 1 - threshold) & elig_f
         cat_mask = jnp.where(variant_d, mask_d,
                              jnp.where(variant_e, mask_e, cat_mask))
-    right = parent - left
+    # the winner's two sides as the scan saw them, and nothing else: the
+    # children are stored, valued and bounded with these
+    right = aux["total"][feature, 0] - left
 
     is_cat_split = variant >= 2
     l2_eff = hp.lambda_l2 + jnp.where(variant >= 3, hp.cat_l2, 0.0)

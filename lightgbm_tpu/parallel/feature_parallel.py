@@ -89,9 +89,9 @@ def grow_tree_feature_parallel(bins_fm, grad, hess, sample_mask,
                              axis_name=axis_name)
 
     root_hist = build(bins_loc, grad, hess, sample_mask)
-    root_g = jnp.sum(grad * sample_mask, dtype=f32)
-    root_h = jnp.sum(hess * sample_mask, dtype=f32)
-    root_c = jnp.sum(sample_mask, dtype=f32)
+    # every shard holds all rows, so its own features' bins sum to the
+    # same totals (to float32 summation order)
+    root_g, root_h, root_c = hist_ops.node_totals(root_hist)
     root_out = leaf_output(root_g, root_h, hp)
     neg_inf, pos_inf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
     root_split = sync(find_best_split(root_hist, root_g, root_h, root_c,
@@ -99,20 +99,7 @@ def grow_tree_feature_parallel(bins_fm, grad, hess, sample_mask,
                                       neg_inf, pos_inf, jnp.int32(0),
                                       has_categorical))
 
-    zero_l = jnp.zeros((L,), f32)
-    leaves = _LeafSplits(
-        sum_grad=zero_l, sum_hess=zero_l, count=zero_l,
-        depth=jnp.zeros((L,), jnp.int32), output=zero_l,
-        gain=jnp.full((L,), K_MIN_SCORE, f32),
-        feature=jnp.zeros((L,), jnp.int32),
-        threshold=jnp.zeros((L,), jnp.int32),
-        default_left=jnp.zeros((L,), jnp.bool_),
-        left_sum_grad=zero_l, left_sum_hess=zero_l, left_count=zero_l,
-        left_output=zero_l, right_output=zero_l,
-        cat_mask=jnp.zeros((L, max_bins), jnp.bool_),
-        min_bound=jnp.full((L,), -jnp.inf, f32),
-        max_bound=jnp.full((L,), jnp.inf, f32),
-    )
+    leaves = _LeafSplits.empty(L, max_bins, f32)
     leaves = _store_split(leaves, 0, root_split, jnp.int32(1), root_out,
                           root_g, root_h, root_c, neg_inf, pos_inf, True)
 
@@ -141,12 +128,8 @@ def grow_tree_feature_parallel(bins_fm, grad, hess, sample_mask,
             row_leaf, bins_fm, best_leaf, new_leaf, feat, thr, dleft, cmask,
             meta.num_bins, meta.missing_type, meta.is_categorical, valid)
 
-        lg = leaves.left_sum_grad[best_leaf]
-        lh = leaves.left_sum_hess[best_leaf]
-        lc = leaves.left_count[best_leaf]
-        pg, ph, pc = (leaves.sum_grad[best_leaf],
-                      leaves.sum_hess[best_leaf], leaves.count[best_leaf])
-        rg, rh, rc = pg - lg, ph - lh, pc - lc
+        ph, pc = leaves.sum_hess[best_leaf], leaves.count[best_leaf]
+        (lg, lh, lc), (rg, rh, rc) = leaves.candidate_sides(best_leaf)
 
         left_smaller = lc <= rc
         small_id = jnp.where(left_smaller, best_leaf, new_leaf)

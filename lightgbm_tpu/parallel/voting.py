@@ -37,19 +37,11 @@ from ..obs import xla as obs_xla
 from ..ops import histogram as hist_ops
 from ..ops import partition as part_ops
 from ..ops import split as split_ops
-from ..ops.histogram import COUNT, GRAD, HESS
 from ..ops.split import (FeatureMeta, K_MIN_SCORE, SplitHyperParams,
                          find_best_split, leaf_output, per_feature_best_gain,
                          propagate_monotone_bounds)
 from . import mesh as mesh_lib
 from .scatter import allgather_argmax_best
-
-
-def _local_leaf_sums(local_hist: jax.Array):
-    """This shard's (grad, hess, count) sums for a leaf, from its local
-    histogram: feature 0's bins partition all local rows."""
-    s = jnp.sum(local_hist[0], axis=0)
-    return s[GRAD], s[HESS], s[COUNT]
 
 
 def _vote_and_reduce(local_hist, pg, ph, pc, parent_out, min_b, max_b,
@@ -69,7 +61,8 @@ def _vote_and_reduce(local_hist, pg, ph, pc, parent_out, min_b, max_b,
     issued collectives per program run, so the runtime byte/call
     counters match what the ICI actually carries.
     """
-    lg, lh, lc = _local_leaf_sums(local_hist)
+    # this shard's sums for the leaf, from its local histogram
+    lg, lh, lc = hist_ops.node_totals(local_hist)
     local_gain = per_feature_best_gain(local_hist, lg, lh, lc, meta, hp,
                                        feature_mask, parent_out,
                                        min_b, max_b, depth,
@@ -170,29 +163,13 @@ def grow_tree_voting(bins_fm, grad, hess, sample_mask, feature_mask,
     # root Allreduce, data_parallel_tree_learner.cpp:170)
     root_hist = build(bins_fm, grad, hess, sample_mask)
     root_g, root_h, root_c = obs_health.psum(
-        (jnp.sum(grad * sample_mask, dtype=f32),
-         jnp.sum(hess * sample_mask, dtype=f32),
-         jnp.sum(sample_mask, dtype=f32)),
-        axis_name, tag="root/psum")
+        hist_ops.node_totals(root_hist), axis_name, tag="root/psum")
     root_out = leaf_output(root_g, root_h, hp)
     neg_inf, pos_inf = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
     root_split = vote(root_hist, root_g, root_h, root_c, root_out,
                       neg_inf, pos_inf, jnp.int32(0))
 
-    zero_l = jnp.zeros((L,), f32)
-    leaves = _LeafSplits(
-        sum_grad=zero_l, sum_hess=zero_l, count=zero_l,
-        depth=jnp.zeros((L,), jnp.int32), output=zero_l,
-        gain=jnp.full((L,), K_MIN_SCORE, f32),
-        feature=jnp.zeros((L,), jnp.int32),
-        threshold=jnp.zeros((L,), jnp.int32),
-        default_left=jnp.zeros((L,), jnp.bool_),
-        left_sum_grad=zero_l, left_sum_hess=zero_l, left_count=zero_l,
-        left_output=zero_l, right_output=zero_l,
-        cat_mask=jnp.zeros((L, max_bins), jnp.bool_),
-        min_bound=jnp.full((L,), -jnp.inf, f32),
-        max_bound=jnp.full((L,), jnp.inf, f32),
-    )
+    leaves = _LeafSplits.empty(L, max_bins, f32)
     leaves = _store_split(leaves, 0, root_split, jnp.int32(1), root_out,
                           root_g, root_h, root_c, neg_inf, pos_inf, True)
 
@@ -221,12 +198,8 @@ def grow_tree_voting(bins_fm, grad, hess, sample_mask, feature_mask,
             meta.num_bins, meta.missing_type, meta.is_categorical, valid)
 
         # global child sums come from the stored (globally-reduced) split
-        lg = leaves.left_sum_grad[best_leaf]
-        lh = leaves.left_sum_hess[best_leaf]
-        lc = leaves.left_count[best_leaf]
-        pg, ph, pc = (leaves.sum_grad[best_leaf],
-                      leaves.sum_hess[best_leaf], leaves.count[best_leaf])
-        rg, rh, rc = pg - lg, ph - lh, pc - lc
+        ph, pc = leaves.sum_hess[best_leaf], leaves.count[best_leaf]
+        (lg, lh, lc), (rg, rh, rc) = leaves.candidate_sides(best_leaf)
 
         # local histograms: build smaller child locally, subtract
         left_smaller = lc <= rc

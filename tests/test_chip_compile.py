@@ -172,11 +172,24 @@ def test_row_operands_stay_lane_dense_at_higgs_rows(one_chip, kind):
 # the whole iteration program, for its layer table (ISSUE 26): what the
 # chip's compiler leaves of the lgbm/<layer> scopes is what the benchmark's
 # per-layer seconds are read through
+# the benchmark's two configurations: higgs-gpu63-int8 (quantized
+# gradients, the int8 kernel) and higgs-gpu63 (every tpu_* parameter at
+# its default: float histograms, the gradient computed inside the kernel)
+PATHS = {"int8": {"use_quantized_grad": True, "num_grad_quant_bins": 126},
+         "float": {}}
+KERNEL = {"int8": "%lgbm_hist_multi_int8", "float": "%lgbm_hist_multi_packed"}
+
+
+@pytest.fixture(scope="module", params=sorted(PATHS))
+def path(request):
+    return request.param
+
+
 @pytest.fixture(scope="module")
-def fused_iter_text(one_chip):
+def fused_iter_text(one_chip, path):
     """``boosting/fused_iter`` compiled for the described chip, as text:
-    the benchmark cell's path (int8 gradients, F=28, 63 bins) at 16k rows
-    and 31 leaves, a 15 s compile."""
+    a benchmark cell's path (F=28, 63 bins) at 16k rows and 31 leaves, a
+    15 s compile."""
     import numpy as np
 
     import lightgbm_tpu as lgb
@@ -191,9 +204,7 @@ def fused_iter_text(one_chip):
         y = (x[:, 0] + x[:, 1] > 0.5).astype(np.float64)
         g = lgb.Booster({"objective": "binary", "num_leaves": 31,
                          "max_bin": 63, "verbosity": -1,
-                         "use_quantized_grad": True,
-                         "num_grad_quant_bins": 126,
-                         "tpu_hist_impl": "pallas"},
+                         "tpu_hist_impl": "pallas", **PATHS[path]},
                         lgb.Dataset(x, label=y))._gbdt
         g._boost_from_average()
         args = (g.bins_fm, tuple(g._valid_bins), g._obj_state(), g.scores,
@@ -216,22 +227,32 @@ def fused_iter_table(fused_iter_text):
 
 @pytest.mark.parametrize("layer", ["gradient", "hist", "split",
                                    "partition", "score"])
-def test_compiled_iteration_keeps_each_layer(fused_iter_table, layer):
-    assert layer in fused_iter_table.values()
+def test_compiled_iteration_keeps_each_layer(fused_iter_table, path, layer):
+    """On the float path the gradient is computed inside the histogram
+    kernel and the root's totals come from the root histogram, so nothing
+    is left under ``lgbm/gradient``: ``layer_gradient_s`` reads 0 there
+    and ``layer_hist_s`` holds the gradient's arithmetic."""
+    kept = layer in fused_iter_table.values()
+    assert kept == ((path, layer) != ("float", "gradient"))
 
 
-def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table):
+def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table,
+                                                      path):
     """``name=`` on the pallas_call names the custom call's instruction,
     and keeps ``hist`` in it: benchmarks/metrics/hist_kernels.py finds the
-    kernels by that substring."""
+    kernels by that substring. The float path's kernel computes the
+    gradient itself and goes through the byte-sectioned call
+    (``lgbm_hist_multi_packed``, one value a byte for raw bins)."""
     kernels = {head: layer for head, layer in fused_iter_table.items()
-               if head.startswith("%lgbm_hist_multi_int8")}
+               if head.startswith("%lgbm_hist_multi")}
     assert kernels and set(kernels.values()) == {"hist"}
+    assert all(head.startswith(KERNEL[path]) for head in kernels), kernels
 
 
-@pytest.mark.parametrize("shape,layer", [("s32[16384]", "partition"),
-                                         ("s8[3,16384]", "hist"),
-                                         ("f32[16384]", "score")])
+@pytest.mark.parametrize("path,shape,layer", [
+    ("int8", "s32[16384]", "partition"), ("int8", "s8[3,16384]", "hist"),
+    ("int8", "f32[16384]", "score"), ("float", "s32[16384]", "partition"),
+    ("float", "f32[16384]", "score")], indirect=["path"])
 def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
     """The row-sized fusions each fall under one layer: ``s32[N]`` the
     wave partition's compare-and-select passes (PR 27; the ``u8[N]`` bin
@@ -244,6 +265,18 @@ def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
            if "fusion" in head.split(" = ")[0]
            and head.split(" = ")[1].startswith(shape + "{")}
     assert got == {layer}
+
+
+def test_no_row_sized_fusion_in_the_split_layer(fused_iter_table):
+    """A node's totals are read from its histogram ([B]-sized, scope
+    ``lgbm/split/totals``), never by a pass over the rows: every fusion
+    with a row-sized result belongs to a layer that works on rows."""
+    import re
+    row_sized = re.compile(rf"[\[,]{ITER_ROWS}[\],]")
+    layers = {lay for head, lay in fused_iter_table.items()
+              if "fusion" in head.split(" = ")[0]
+              and row_sized.search(head.split(" = ")[1])}
+    assert layers and layers <= {"gradient", "hist", "partition", "score"}
 
 
 def _row_sized_gathers(text, rows, scope):
