@@ -1304,9 +1304,9 @@ def grow_tree_waved(bins_fm: jax.Array,
         # of round-tripping materialized [N] buffers through HBM
         with jax.named_scope("lgbm/gradient"):
             grad, hess = fg_fn(fg_score, fg_label, fg_weight)
-        # build_bins <= 256 keeps bin ids byte-representable — the fused
-        # kernel reads bins through the byte-sectioned layout, so uint16
-        # storage (max_bin > 256) must stay on the materialized-ghT path
+        # uint16 storage (max_bin > 256) stays on the materialized-ghT
+        # path, where it has always run: the kernels' shared step takes
+        # uint16 ids on either path, but no test trains through this one
         use_kernel_fused = (hist_impl == "pallas" and bundle is None
                             and shard_mesh is None and build_bins <= 256)
     if sparse_shape is not None:

@@ -1,17 +1,43 @@
-"""Pallas TPU histogram kernel.
+"""Pallas TPU histogram kernels.
 
 The performance-critical op (ref: the CUDA shared-memory histogram kernels,
 src/treelearner/cuda/cuda_histogram_constructor.cu:21). The XLA one-hot
 formulation materializes the [N, B] one-hot in HBM (~B x 4 bytes per
-element); this kernel builds one-hot tiles in VMEM only, so HBM traffic
-drops to one read of the bin matrix (1 byte/element) plus the gh vectors —
-the bandwidth floor.
+element); these kernels build one-hot tiles in VMEM only, so HBM traffic
+drops to one read of the bin matrix (1 byte/element) plus the row operands.
 
-Layout: bins [F, N] (feature-major), gh [3, N] (grad, hess, count rows,
-pre-masked), output hist [F, 3, B].
+Layout: bins [F, N] (feature-major, or PackedBins), output hist
+[slots, F, B, 3] (multi-leaf) or [F, B, 3] (single-leaf). Grid:
+(feature blocks, row chunks); row chunks accumulate into the same output
+block (TPU grids execute sequentially, minor-dim fastest).
 
-Grid: (feature_blocks, row_chunks); row chunks accumulate into the same
-output block (TPU grids execute sequentially, minor-dim fastest).
+The multi-leaf kernels (every pass of the waved grower) share ONE step,
+`_accum_section_dots`, under three operand readers (float gh, int8 gh,
+gradient computed in the kernel), all through one `pallas_call`
+(`_multi_slabs`). What a step does:
+
+- The leaf operand ([128, R]: sublane 3 * slot + channel holds the
+  row's grad / hess / weight where the row is in that slot's leaf, the
+  chunk's R rows on lanes) is built once a row chunk, in the MXU's
+  operand type: int8, or bf16 (one pass by default; the float32 values
+  split over two or three bf16 passes for tpu_hist_precision=high /
+  highest, since the one-hot side is exact).
+- Every feature of the block gets a bin-aligned slab of `bp` one-hot
+  rows (max_bins rounded up to the operand's sublane tile: 64 at 63
+  bins), row b = bin b. A slab is built as packed 32-bit words, four
+  int8 (two bf16) one-hot rows a word: `bin_bits - 32 * word_row` is
+  the hit's bit offset or out of range, so a word vreg costs one
+  subtract, one unsigned compare, one shift and one select, and a
+  bitcast gives the operand. No division, no select over features, no
+  cast of a compare mask.
+- Dots are tall: [dot_feats * bp, R] x [128, R]^T with 512 one-hot
+  rows or more, so a latched [128, 128] tile of the leaf operand serves
+  hundreds of one-hot rows (A x B^T on the MXU, int32 or float32 sums).
+- `_fb_geometry` sizes the step from shapes and the scoped-VMEM limit
+  alone: at 28-32 features one feature block and 4096-8192 rows a step,
+  at 2000 features as many features a block as the accumulator leaves
+  room for. `global_metrics.meta["hist_geometry"]` says what each traced
+  kernel took.
 
 Every per-row operand (gh channels, row->leaf ids, the fused kernel's
 score/label/weight/mask) enters the kernels LANE-DENSE, as ``[k, N]``
@@ -19,15 +45,16 @@ with the rows on the minor axis. Mosaic lays a 2-D HBM operand out in
 (sublane, 128-lane) tiles, so an ``[N, 1]`` or ``[N, 3]`` column operand
 is padded to 128 lanes — 512 bytes per row instead of 4: at N = 10.5M
 each such operand took 5 GB of HBM and the v5e compiler refused the
-iteration program at 30.5 GB (asked without a chip, PR 21). The
-leaf-selected gh operand is therefore built transposed, ``[128, R]``,
-and contracted against the one-hot's row axis (A x B^T on the MXU).
+iteration program at 30.5 GB (asked without a chip, PR 21).
+
+PERF.md section 6 (PR 29) has the step's vector-operation counts from
+the compiler's own output and the chip's readings.
 """
 
 from __future__ import annotations
 
 import functools
-import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -37,14 +64,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .bin_pack import PackedBins
 
+# MXU passes of a float32 dot (single-leaf kernels); the multi-leaf
+# kernels' one-hot is exact in bf16 and they take fewer (_PARTS)
 _PRECISIONS = {
     "default": lax.Precision.DEFAULT,   # 1 bf16 MXU pass, f32 accumulation
     "high": lax.Precision.HIGH,         # 3 passes
     "highest": lax.Precision.HIGHEST,   # 6 passes (f32-faithful)
 }
 
-# byte-block width of the packed kernels' grid steps; bin_pack.PACK_ALIGN
-# guarantees every packed section is a multiple of this
+# byte-block width of the single-leaf packed kernel's grid steps;
+# bin_pack.PACK_ALIGN guarantees every packed section is a multiple of it
 _PACKED_CHUNK_BYTES = 1024
 
 # A [M, R] x B [128, R] -> [M, 128]: contract the row (lane) axis of both
@@ -88,11 +117,16 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, f_blk: int, max_bins: int,
         out_ref[f, :, :] += jax.lax.dot(gh, onehot, precision=prec)
 
 
-def _leaf_bop(g, h, w, rl, leafsel_ref, int8: bool):
+def _leaf_bop(g, h, w, rl, leafsel_ref, int8: bool, parts: int = 1):
     """The MXU's leaf-block-diagonal gh operand, transposed: [128, R]
     with sublane k = (leaf k//3, channel k%3) and the chunk's R rows on
-    lanes — shared by every multi-kernel variant. g/h/w/rl: [1, R]
-    rows; leafsel_ref: [128, 1] leaf id of each sublane."""
+    lanes, built ONCE a row chunk and latched for every feature of the
+    step. g/h/w/rl: [1, R] rows; leafsel_ref: [128, 1] leaf id of each
+    sublane. Returns the operand as a tuple of `parts` arrays in the
+    MXU's type: one int8, or the bf16 head of the float32 values
+    followed by the bf16 heads of what each rounding left over
+    (tpu_hist_precision=high / highest: the one-hot is exact in bf16, so
+    only this side needs the extra passes)."""
     r = rl.shape[1]
     csel = lax.broadcasted_iota(jnp.int32, (128, r), 0) % 3
     if int8:
@@ -102,7 +136,14 @@ def _leaf_bop(g, h, w, rl, leafsel_ref, int8: bool):
     gsel = jnp.where(csel == 0, g, jnp.where(csel == 1, h, w))
     bop = jnp.where(leafsel_ref[...] == rl, gsel,
                     jnp.zeros((), gsel.dtype))
-    return bop.astype(jnp.int8) if int8 else bop
+    if int8:
+        return (bop.astype(jnp.int8),)
+    out = []
+    for _ in range(parts):
+        head = bop.astype(jnp.bfloat16)
+        out.append(head)
+        bop = bop - head.astype(jnp.float32)
+    return tuple(out)
 
 
 def _gh_rows(gh):
@@ -110,283 +151,179 @@ def _gh_rows(gh):
     return gh[0:1], gh[1:2], gh[2:3]
 
 
-def _multi_kernel(bins_ref, gh_ref, rl_ref, leafsel_ref, out_ref, *,
-                  f_blk: int, group: int, max_bins: int, precise: bool):
-    """One grid step: f_blk features' transposed one-hots ([group*B, R]
-    per dot, built in VMEM) x a shared [128, R] leaf-selected gh operand
-    -> accumulate [f_blk*B, 128]."""
-    ch = pl.program_id(1)
-
-    @pl.when(ch == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    rl = rl_ref[...]       # [1, R] int32 row -> leaf
-    r = rl.shape[1]
-    # gh block: [3, R] f32 (grad, hess, weight)
-    bop = _leaf_bop(*_gh_rows(gh_ref[...]), rl, leafsel_ref, False)
-    prec = resolve_precision(precise)
-
-    rows = group * max_bins
-    riota = lax.broadcasted_iota(jnp.int32, (rows, r), 0)
-    for q in range(f_blk // group):
-        b_eff = jnp.zeros((rows, r), jnp.int32)
-        for p in range(group):
-            b_eff = jnp.where(
-                riota // max_bins == p,
-                bins_ref[q * group + p, :][None, :].astype(jnp.int32), b_eff)
-        onehot_t = (b_eff == riota % max_bins).astype(jnp.float32)
-        out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
-            onehot_t, bop, _CONTRACT_ROWS, precision=prec)
-
-
-def _row_operand_specs(row_chunk: int):
-    """BlockSpecs of the unpacked multi kernels' operands after the bin
-    block: gh [3, N], row_leaf [1, N], leafsel [128, 1]."""
-    return [
-        pl.BlockSpec((3, row_chunk), lambda j, i: (0, i),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, row_chunk), lambda j, i: (0, i),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((128, 1), lambda j, i: (0, 0),
-                     memory_space=pltpu.VMEM),
-    ]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("max_bins", "num_slots", "row_chunk",
-                                    "precise", "interpret"))
-def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
-                      leaf_ids: jax.Array, *, max_bins: int, num_slots: int,
-                      row_chunk: int = 2048, precise="highest",
-                      interpret=None) -> jax.Array:
-    """Histograms of up to `num_slots` leaves in ONE pass over the rows.
-
-    The one-hot (bins) operand is leaf-independent, so packing the MXU's
-    128 output columns with (leaf, channel) pairs builds J = 42 leaves'
-    histograms for the cost of one (the reference instead loops leaves,
-    touching each leaf's rows separately — cuda_histogram_constructor.cu:21
-    one kernel per leaf). Rows route to their leaf's columns via a
-    compare against row_leaf — the device analog of DataPartition.
-
-    bins_fm: [F, N] uint8/16 (or PackedBins); ghT: [N, 3] f32 pre-masked
-    (grad, hess, w); row_leaf: [N] int32; leaf_ids: [num_slots] int32
-    (pad with -2). Returns hist [num_slots, F, B, 3] f32.
-    """
-    if isinstance(bins_fm, PackedBins):
-        return _hist_multi_packed_f32(bins_fm, ghT, row_leaf, leaf_ids,
-                                      max_bins=max_bins,
-                                      num_slots=num_slots, precise=precise,
-                                      interpret=interpret)
-    num_features, n = bins_fm.shape
-    assert num_slots * 3 <= 128, "num_slots capped at 42 by MXU columns"
-    group = max(1, 128 // max_bins) if max_bins <= 128 else 1
-    # bins tile first dim must be a multiple of 8 (Mosaic) AND of group
-    # (the kernel consumes features in groups of `group` per dot)
-    f_blk = group * 8 // math.gcd(group, 8)
-    pad_f = (-num_features) % f_blk
-    if pad_f:
-        bins_fm = jnp.pad(bins_fm, ((0, pad_f), (0, 0)),
-                          constant_values=0)
-    fp = bins_fm.shape[0]
-    pad_n = (-n) % row_chunk
-    if pad_n:
-        bins_fm = jnp.pad(bins_fm, ((0, 0), (0, pad_n)),
-                          constant_values=0)
-        ghT = jnp.pad(ghT, ((0, pad_n), (0, 0)))  # zero gh: no contribution
-        row_leaf = jnp.pad(row_leaf, (0, pad_n), constant_values=-1)
-    npad = bins_fm.shape[1]
-
-    fblocks = fp // f_blk
-    rows = f_blk * max_bins
-    grid = (fblocks, npad // row_chunk)
-    out = pl.pallas_call(
-        functools.partial(_multi_kernel, f_blk=f_blk, group=group,
-                          max_bins=max_bins, precise=precise),
-        grid=grid,
-        in_specs=[pl.BlockSpec((f_blk, row_chunk), lambda j, i: (j, i),
-                               memory_space=pltpu.VMEM)]
-        + _row_operand_specs(row_chunk),
-        out_specs=pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), jnp.float32),
-        interpret=_resolve_interpret(interpret),
-        name="lgbm_hist_multi",
-    )(bins_fm, ghT.T, row_leaf[None, :].astype(jnp.int32),
-      _leafsel_col(leaf_ids, num_slots))
-    # [fblocks, f_blk*B, 128] -> [F, B, J, 3] -> [J, F, B, 3]
-    out = out[:, :, :3 * num_slots]
-    out = out.reshape(fp, max_bins, num_slots, 3)
-    out = jnp.moveaxis(out, 2, 0)
-    return out[:, :num_features]
-
-
-def _multi_kernel_int8(bins_ref, gh_ref, rl_ref, leafsel_ref, out_ref, *,
-                       f_blk: int, group: int, max_bins: int):
-    """Integer twin of _multi_kernel: int8 one-hot x int8 leaf-selected
-    quantized (grad, hess, weight) -> int32 accumulation. This is the MXU
-    shape of the reference's quantized histograms (ref:
-    gradient_discretizer.hpp:23 int8 packed gradients, bin.h:351-421
-    ConstructHistogramInt* variants) — exact integer arithmetic at twice
-    the bf16 MXU rate."""
-    ch = pl.program_id(1)
-
-    @pl.when(ch == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    rl = rl_ref[...]       # [1, R] int32 row -> leaf
-    r = rl.shape[1]
-    # gh block: [3, R] int8 (g_int, h_int, weight)
-    bop = _leaf_bop(*_gh_rows(gh_ref[...]), rl, leafsel_ref, True)
-
-    rows = group * max_bins
-    riota = lax.broadcasted_iota(jnp.int32, (rows, r), 0)
-    for q in range(f_blk // group):
-        b_eff = jnp.zeros((rows, r), jnp.int32)
-        for p in range(group):
-            b_eff = jnp.where(
-                riota // max_bins == p,
-                bins_ref[q * group + p, :][None, :].astype(jnp.int32), b_eff)
-        onehot_t = (b_eff == riota % max_bins).astype(jnp.int8)
-        out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
-            onehot_t, bop, _CONTRACT_ROWS,
-            preferred_element_type=jnp.int32)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("max_bins", "num_slots", "row_chunk",
-                                    "interpret"))
-def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
-                           row_leaf: jax.Array, leaf_ids: jax.Array, *,
-                           max_bins: int, num_slots: int,
-                           row_chunk: int = 2048,
-                           interpret=None) -> jax.Array:
-    """Quantized multi-leaf histograms: one pass, int32 accumulation.
-
-    ghT_i8: [N, 3] int8 (quantized grad, quantized hess, {0,1} weight),
-    pre-masked. Returns [num_slots, F, B, 3] int32 — callers scale by
-    (g_scale, h_scale, 1) to recover the f32 statistics. Safe for
-    N < 2^31 / (num_grad_quant_bins): |g_int| <= bins/2, so per-bin int32
-    sums cannot overflow at any realistic scale.
-    """
-    if isinstance(bins_fm, PackedBins):
-        return _hist_multi_packed_int8(bins_fm, ghT_i8, row_leaf, leaf_ids,
-                                       max_bins=max_bins,
-                                       num_slots=num_slots,
-                                       interpret=interpret)
-    num_features, n = bins_fm.shape
-    assert num_slots * 3 <= 128, "num_slots capped at 42 by MXU columns"
-    group = max(1, 128 // max_bins) if max_bins <= 128 else 1
-    f_blk = group * 8 // math.gcd(group, 8)
-    pad_f = (-num_features) % f_blk
-    if pad_f:
-        bins_fm = jnp.pad(bins_fm, ((0, pad_f), (0, 0)), constant_values=0)
-    fp = bins_fm.shape[0]
-    pad_n = (-n) % row_chunk
-    if pad_n:
-        bins_fm = jnp.pad(bins_fm, ((0, 0), (0, pad_n)), constant_values=0)
-        ghT_i8 = jnp.pad(ghT_i8, ((0, pad_n), (0, 0)))
-        row_leaf = jnp.pad(row_leaf, (0, pad_n), constant_values=-1)
-    npad = bins_fm.shape[1]
-
-    fblocks = fp // f_blk
-    rows = f_blk * max_bins
-    grid = (fblocks, npad // row_chunk)
-    out = pl.pallas_call(
-        functools.partial(_multi_kernel_int8, f_blk=f_blk, group=group,
-                          max_bins=max_bins),
-        grid=grid,
-        in_specs=[pl.BlockSpec((f_blk, row_chunk), lambda j, i: (j, i),
-                               memory_space=pltpu.VMEM)]
-        + _row_operand_specs(row_chunk),
-        out_specs=pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), jnp.int32),
-        interpret=_resolve_interpret(interpret),
-        name="lgbm_hist_multi_int8",
-    )(bins_fm, ghT_i8.T, row_leaf[None, :].astype(jnp.int32),
-      _leafsel_col(leaf_ids, num_slots))
-    out = out[:, :, :3 * num_slots]
-    out = out.reshape(fp, max_bins, num_slots, 3)
-    out = jnp.moveaxis(out, 2, 0)
-    return out[:, :num_features]
-
-
 # ---------------------------------------------------------------------------
-# packed-bin kernels: each grid step reads ONE block of packed bytes and
-# consumes every bit-section in it, so the dominant bin read shrinks by
-# the pack factor (bin_pack.PackedBins split-section layout: byte j of a
-# section-aligned block covers rows j, j+section, ...; the v-th section's
-# gh/row_leaf operands are the same [k, N] arrays blocked at
-# section-strided offsets — no lane interleave anywhere, just vpb dots
-# per feature group)
+# the multi-leaf kernels: ONE step body (_accum_section_dots) under three
+# operand readers (pre-built float gh, pre-built int8 gh, the gradient
+# computed in the kernel). Raw bins go through it as a one-value-a-byte
+# "packed" layout; PackedBins bring vpb values a byte (bin_pack split-
+# section layout: byte j of a section-aligned block covers rows j,
+# j+section, ...; the v-th section's row operands are the same [k, N]
+# arrays blocked at section-strided offsets, so nothing is interleaved).
 # ---------------------------------------------------------------------------
-def _accum_section_dots(bins_ref, out_ref, bops, *, f_blk: int, group: int,
-                        max_bins: int, vpb: int, int8: bool, precise):
-    """Accumulate all bit-sections of a packed byte block: one one-hot
-    build + dot per (feature-group, section). vpb=1 degenerates to the
-    unpacked kernels' loop (shift 0, mask 255)."""
+class HistGeometry(NamedTuple):
+    """What one grid step of a multi-leaf kernel holds (_fb_geometry)."""
+    bp: int          # one-hot rows a feature: max_bins up to the tile
+    f_blk: int       # features a step
+    row_chunk: int   # rows a step and bit-section
+    dot_feats: int   # features a dot: dot_feats * bp one-hot rows
+
+
+# Mosaic's scoped-VMEM limit for one kernel on the chips this runs on
+# (16 MiB unless a kernel asks for more; none does)
+_VMEM_LIMIT = 16 * 1024 * 1024
+# one-hot rows a dot streams past each latched [128, 128] weight tile
+_DOT_ROWS = 512
+_ROW_CHUNKS = (8192, 4096, 2048)
+
+
+def _step_vmem_bytes(g: HistGeometry, vpb: int, itemsize: int) -> int:
+    """Bytes of VMEM one grid step of geometry `g` holds, by the
+    buffers it names: the pipelined blocks (two of each), the bins as
+    32-bit words, the leaf operands (three bf16 passes at the most), one
+    dot's one-hot and result, and the accumulator. A vector temporary
+    that is consumed as it is made lives in registers and is not
+    counted; tests/test_chip_compile.py asks the compiler."""
+    rows = g.dot_feats * g.bp
+    bins = 2 * g.f_blk * g.row_chunk * (2 if g.bp > 256 else 1)
+    bins32 = g.f_blk * g.row_chunk * 4 * (1 if vpb == 1 else 2)
+    row_ops = vpb * 5 * 2 * 8 * g.row_chunk * 4     # <= 5 [1, R] rows
+    bops = vpb * 128 * g.row_chunk * (1 if itemsize == 1 else 6)
+    onehot = rows * g.row_chunk * itemsize
+    acc = (2 * g.f_blk * g.bp + rows) * 128 * 4
+    return bins + bins32 + row_ops + bops + onehot + acc
+
+
+def _fb_geometry(num_features: int, max_bins: int, vpb: int = 1,
+                 itemsize: int = 2, vmem_limit: int = _VMEM_LIMIT, *,
+                 section: int | None = None,
+                 rows: int | None = None) -> HistGeometry:
+    """The multi kernels' step, from shapes and VMEM alone.
+
+    `itemsize` is the MXU operand's (1 int8, 2 bf16): its sublane tile
+    (32 / 16 rows) is what a feature's slab is rounded up to. A dot
+    takes the fewest features that give it _DOT_ROWS one-hot rows. A
+    row chunk of _ROW_CHUNKS has to divide `section` (the bytes a
+    feature of PackedBins has) or, for raw bins of `rows` rows that the
+    caller pads, to add under an eighth to them; the feature block is
+    the largest that keeps the step under `vmem_limit`: at 28-32
+    features one block, at 2000 as many as the accumulator leaves room
+    for. Of the chunks that fit, the one with the fewest grid steps
+    (leaf-operand builds) a row wins."""
+    bp = _round_up(max_bins, 32 // itemsize)
+    k = 1
+    while k * bp < _DOT_ROWS:
+        k *= 2
+    unit = max(k, 8)    # a bins block is a whole number of 8-row tiles
+    whole = _round_up(num_features, unit)
+    best = None
+    for rc in _ROW_CHUNKS:
+        if rc != _ROW_CHUNKS[-1] and (
+                (section is not None and section % rc)
+                or (rows is not None and _round_up(rows, rc) - rows
+                    > rows // 8)):
+            continue
+        # fewest blocks that fit, split as evenly as the unit allows
+        for blocks in range(1, whole // unit + 1):
+            g = HistGeometry(
+                bp, _round_up(-(-num_features // blocks), unit), rc, k)
+            if _step_vmem_bytes(g, vpb, itemsize) <= vmem_limit:
+                break
+        else:
+            continue
+        steps_a_row = -(-num_features // g.f_blk) / rc
+        if best is None or steps_a_row < best[0]:
+            best = (steps_a_row, g)
+    assert best is not None, (num_features, max_bins, vpb, itemsize)
+    return best[1]
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _slab_words(b_row, wiota, one: int):
+    """One feature's one-hot slab as packed 32-bit words: [bp/pack, R]
+    where word row s holds the slab's rows pack*s .. pack*s+pack-1, one
+    a byte (int8, pack 4) or a half (bf16, pack 2): a row is hit where
+    the bin equals its index. b_row: [1, R] int32, the bins times the
+    operand's bits; wiota: [bp/pack, R] int32, 32 * the word row's
+    index: their difference is the hit's bit offset in the word, or
+    outside [0, 32). One subtract, one unsigned compare, one shift and
+    one select a word vreg, for pack one-hot vregs; the 8-bit compare
+    the int8 operand would want is not one this chip has."""
+    d = b_row - wiota
+    hit = lax.bitcast_convert_type(d, jnp.uint32) < 32
+    return jnp.where(hit, jnp.int32(one) << d, 0)
+
+
+def _accum_section_dots(bins_ref, out_ref, bops, *, geom: HistGeometry,
+                        vpb: int, int8: bool):
+    """The step every multi-leaf kernel runs: for each bit-section of
+    the byte block and each `dot_feats` features, build the features'
+    bin-aligned one-hot slabs ([dot_feats * bp, R], in the MXU's operand
+    type) and contract them with the section's leaf operand. vpb=1 is
+    the unpacked layout (no shift, no mask: uint16 ids pass whole)."""
     bits = 8 // vpb
-    bmask = (1 << bits) - 1
-    rows = group * max_bins
-    cb = bops[0].shape[1]
-    riota = lax.broadcasted_iota(jnp.int32, (rows, cb), 0)
-    prec = None if int8 else resolve_precision(precise)
-    for q in range(f_blk // group):
-        for v in range(vpb):
-            b_eff = jnp.zeros((rows, cb), jnp.int32)
-            for p in range(group):
-                col = (bins_ref[q * group + p, :].astype(jnp.int32)
-                       >> (bits * v)) & bmask
-                b_eff = jnp.where(riota // max_bins == p,
-                                  col[None, :], b_eff)
-            if int8:
-                onehot_t = (b_eff == riota % max_bins).astype(jnp.int8)
-                out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
-                    onehot_t, bops[v], _CONTRACT_ROWS,
-                    preferred_element_type=jnp.int32)
-            else:
-                onehot_t = (b_eff == riota % max_bins).astype(jnp.float32)
-                out_ref[0, q * rows:(q + 1) * rows, :] += lax.dot_general(
-                    onehot_t, bops[v], _CONTRACT_ROWS, precision=prec)
+    pack, one, optype = ((4, 1, jnp.int8) if int8
+                         else (2, 0x3F80, jnp.bfloat16))  # bf16(1.0)
+    acc_t = jnp.int32 if int8 else jnp.float32
+    ball = bins_ref[...].astype(jnp.int32)         # [f_blk, R]
+    cb = ball.shape[1]
+    wiota = 32 * lax.broadcasted_iota(jnp.int32, (geom.bp // pack, cb), 0)
+    rows = geom.dot_feats * geom.bp
+    obits = (32 // pack).bit_length() - 1          # log2 of the operand's bits
+    for v in range(vpb):
+        bsec = ball if vpb == 1 else (ball >> (bits * v)) & ((1 << bits) - 1)
+        bsec = bsec << obits
+        for q in range(geom.f_blk // geom.dot_feats):
+            f0 = q * geom.dot_feats
+            words = jnp.concatenate(
+                [_slab_words(bsec[f:f + 1], wiota, one)
+                 for f in range(f0, f0 + geom.dot_feats)], axis=0)
+            onehot_t = pltpu.bitcast(words, optype)   # [rows, R]
+            part = None
+            for bop in reversed(bops[v]):             # small parts first
+                d = lax.dot_general(onehot_t, bop, _CONTRACT_ROWS,
+                                    preferred_element_type=acc_t)
+                part = d if part is None else part + d
+            out_ref[0, q * rows:(q + 1) * rows, :] += part
 
 
-def _multi_kernel_packed(bins_ref, *refs, f_blk: int, group: int,
-                         max_bins: int, vpb: int, int8: bool, precise):
-    """Packed twin of _multi_kernel/_multi_kernel_int8: refs =
-    (gh_0..gh_{vpb-1}, rl_0..rl_{vpb-1}, leafsel, out)."""
+def _multi_kernel_packed(bins_ref, *refs, geom: HistGeometry, vpb: int,
+                         parts: int):
+    """Pre-built gh operand, float32 [3, N] or int8 [3, N] (the MXU
+    shape of the reference's quantized histograms, ref:
+    gradient_discretizer.hpp:23 int8 packed gradients, bin.h:351-421
+    ConstructHistogramInt*: exact integer arithmetic at twice the bf16
+    rate): refs = (gh_0..gh_{vpb-1}, rl_0..rl_{vpb-1}, leafsel, out)."""
     out_ref = refs[-1]
     leafsel_ref = refs[-2]
     gh_refs, rl_refs = refs[:vpb], refs[vpb:2 * vpb]
-    ch = pl.program_id(1)
+    int8 = gh_refs[0].dtype == jnp.int8
 
-    @pl.when(ch == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     bops = [_leaf_bop(*_gh_rows(gh_refs[v][...]), rl_refs[v][...],
-                      leafsel_ref, int8) for v in range(vpb)]
-    _accum_section_dots(bins_ref, out_ref, bops, f_blk=f_blk, group=group,
-                        max_bins=max_bins, vpb=vpb, int8=int8,
-                        precise=precise)
+                      leafsel_ref, int8, parts) for v in range(vpb)]
+    _accum_section_dots(bins_ref, out_ref, bops, geom=geom, vpb=vpb,
+                        int8=int8)
 
 
-def _multi_kernel_fused(bins_ref, *refs, f_blk: int, group: int,
-                        max_bins: int, vpb: int, precise, grad_fn,
-                        has_weight: bool):
+def _multi_kernel_fused(bins_ref, *refs, geom: HistGeometry, vpb: int,
+                        parts: int, grad_fn, has_weight: bool):
     """Gradient-fused multi kernel: instead of reading a pre-built
-    [R, 3] ghT operand, read (score, label[, weight], mask) vectors and
+    [3, R] gh operand, read (score, label[, weight], mask) vectors and
     compute grad/hess with the objective's pointwise function INSIDE the
-    kernel (VPU math under the MXU's bandwidth shadow). This removes the
-    standalone gradient/bagging element-wise pass — ghT is never
-    materialized in HBM — which is the ~0.5 GB/iter term of the cost
-    model. Works for packed (vpb>1) and raw uint8 (vpb=1) bins alike."""
+    kernel, once a row chunk (VPU math under the MXU's shadow). This
+    removes the standalone gradient/bagging element-wise pass — ghT is
+    never materialized in HBM. Works for packed (vpb>1) and raw uint8
+    (vpb=1) bins alike."""
     out_ref = refs[-1]
     leafsel_ref = refs[-2]
-    ch = pl.program_id(1)
 
-    @pl.when(ch == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -404,17 +341,9 @@ def _multi_kernel_fused(bins_ref, *refs, f_blk: int, group: int,
         mask, rl = op(2 + iw, v), op(3 + iw, v)
         g, h = grad_fn(score, label, weight)  # [1, cb] rows
         bops.append(_leaf_bop(g * mask, h * mask, mask, rl, leafsel_ref,
-                              False))
-    _accum_section_dots(bins_ref, out_ref, bops, f_blk=f_blk, group=group,
-                        max_bins=max_bins, vpb=vpb, int8=False,
-                        precise=precise)
-
-
-def _fb_geometry(num_features: int, max_bins: int):
-    """(group, f_blk) — the multi kernels' feature-block geometry."""
-    group = max(1, 128 // max_bins) if max_bins <= 128 else 1
-    f_blk = group * 8 // math.gcd(group, 8)
-    return group, f_blk
+                              False, parts))
+    _accum_section_dots(bins_ref, out_ref, bops, geom=geom, vpb=vpb,
+                        int8=False)
 
 
 def _leafsel_col(leaf_ids, num_slots: int):
@@ -427,26 +356,57 @@ def _leafsel_col(leaf_ids, num_slots: int):
                      -2).astype(jnp.int32)[:, None]
 
 
-def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
-                       max_bins: int, num_slots: int, out_dtype,
-                       interpret):
-    """Shared pallas_call plumbing of the packed multi kernels.
+# bf16 passes over the leaf operand for each tpu_hist_precision: the
+# one-hot side is exact, so XLA's 3 and 6 passes come down to 2 and 3
+_PARTS = {lax.Precision.DEFAULT: 1, lax.Precision.HIGH: 2,
+          lax.Precision.HIGHEST: 3}
 
-    row_vecs: list of ([N] or [k, N] array, pad_value) pairs; each
-    becomes vpb lane-dense operands blocked at section-strided offsets
-    so grid step i sees the rows matching byte block i's bit-sections.
-    Returns the histograms [num_slots, F, B, 3].
+
+def _packed_multi_call(bins_fm, row_vecs, leaf_ids, kernel, *,
+                       max_bins: int, num_slots: int, **kw):
+    """Histograms [num_slots, F, B, 3] of the multi-leaf kernels (int32
+    for int8 operands, else float32): `_multi_slabs` less the padding."""
+    out = _multi_slabs(bins_fm, row_vecs, leaf_ids, kernel,
+                       max_bins=max_bins, num_slots=num_slots, **kw)
+    num_features = bins_fm.shape[0]
+    # [F', bp, 128] -> [F, B, J, 3] -> [J, F, B, 3]: padded features,
+    # a slab's rows at max_bins and beyond (never hit) and the columns
+    # past the last slot go
+    out = out[:num_features, :max_bins, :3 * num_slots]
+    out = out.reshape(num_features, max_bins, num_slots, 3)
+    return jnp.moveaxis(out, 2, 0)
+
+
+def _multi_slabs(bins_fm, row_vecs, leaf_ids, kernel, *, max_bins: int,
+                 num_slots: int, int8: bool, precise=None, interpret=None,
+                 name: str):
+    """The one pallas_call of the multi-leaf kernels.
+
+    bins_fm: PackedBins, or raw [F, N] uint8/uint16 bins (padded here
+    to whole row chunks: bin 0 under a leaf id of -1). row_vecs: list of
+    ([N] or [N, k] array, pad_value) pairs; each becomes vpb lane-dense
+    operands ([1, N] or [k, N]) blocked at section-strided offsets, so
+    that grid step i sees the rows of byte block i's bit-sections.
+    Returns every feature's slab, [F padded to whole blocks, bp, 128]:
+    row b of a slab is bin b, column 3 * slot + channel.
     """
-    num_features = pb.data.shape[0]
-    vpb, sec = pb.vpb, pb.section
-    group, f_blk = _fb_geometry(num_features, max_bins)
-    data = pb.data
+    assert num_slots * 3 <= 128, "num_slots capped at 42 by MXU columns"
+    itemsize = 1 if int8 else 2
+    num_features, n = bins_fm.shape
+    if isinstance(bins_fm, PackedBins):
+        data, vpb, sec = bins_fm.data, bins_fm.vpb, bins_fm.section
+        geom = _fb_geometry(num_features, max_bins, vpb, itemsize,
+                            section=sec)
+    else:
+        vpb = 1
+        geom = _fb_geometry(num_features, max_bins, 1, itemsize, rows=n)
+        sec = _round_up(n, geom.row_chunk)
+        data = jnp.pad(bins_fm, ((0, 0), (0, sec - n)))
+    f_blk, cb = geom.f_blk, geom.row_chunk
     pad_f = (-num_features) % f_blk
     if pad_f:
         data = jnp.pad(data, ((0, pad_f), (0, 0)), constant_values=0)
     fp = data.shape[0]
-    cb = min(_PACKED_CHUNK_BYTES, sec)
-    assert sec % cb == 0, "bin_pack.PACK_ALIGN must tile the byte chunk"
     nsb = sec // cb
     n_rows = vpb * sec
 
@@ -456,9 +416,12 @@ def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
     # operand-major layout (all of operand k's sections consecutively) —
     # the kernels index refs[k * vpb + v]
     for vec, pad_val in row_vecs:
-        arr = vec[None, :] if vec.ndim == 1 else vec
-        arr = jnp.pad(arr, ((0, 0), (0, n_rows - arr.shape[1])),
-                      constant_values=pad_val)
+        # padded as the caller holds it ([N] or [N, k]), then turned
+        # lane-dense: the pad of a 1-D vector reads as [1, N] for free,
+        # where padding its [1, N] view costs a copy of the vector
+        pad = ((0, n_rows - vec.shape[0]),) + ((0, 0),) * (vec.ndim - 1)
+        arr = jnp.pad(vec, pad, constant_values=pad_val)
+        arr = arr[None, :] if vec.ndim == 1 else arr.T
         for v in range(vpb):
             in_specs.append(pl.BlockSpec(
                 (arr.shape[0], cb), lambda j, i, v=v: (0, i + v * nsb),
@@ -469,47 +432,97 @@ def _packed_multi_call(pb: PackedBins, row_vecs, leaf_ids, kernel, *,
     operands.append(_leafsel_col(leaf_ids, num_slots))
 
     fblocks = fp // f_blk
-    rows = f_blk * max_bins
+    rows = f_blk * geom.bp
+    grid = (fblocks, nsb)
+    _note_geometry(name, geom, vpb=vpb, grid=grid, int8=int8,
+                   num_features=num_features, max_bins=max_bins,
+                   rows=n_rows)
+    parts = 1 if int8 else _PARTS[resolve_precision(precise)]
     out = pl.pallas_call(
-        functools.partial(kernel, f_blk=f_blk, group=group,
-                          max_bins=max_bins, vpb=vpb),
-        grid=(fblocks, nsb),
+        functools.partial(kernel, geom=geom, vpb=vpb, parts=parts),
+        grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((fblocks, rows, 128), out_dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (fblocks, rows, 128), jnp.int32 if int8 else jnp.float32),
         interpret=_resolve_interpret(interpret),
-        name="lgbm_hist_multi_packed",
+        name=name,
     )(*operands)
-    out = out[:, :, :3 * num_slots]
-    out = out.reshape(fp, max_bins, num_slots, 3)
-    return jnp.moveaxis(out, 2, 0)[:, :num_features]
+    return out.reshape(fp, geom.bp, 128)
 
 
-@functools.partial(jax.jit, static_argnames=("max_bins", "num_slots",
-                                             "interpret", "precise"))
-def _hist_multi_packed_f32(pb, ghT, row_leaf, leaf_ids, *, max_bins: int,
-                           num_slots: int, precise="highest",
-                           interpret=None):
-    kern = functools.partial(_multi_kernel_packed, int8=False,
-                             precise=precise)
+def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
+                   rows):
+    """Publish a kernel's geometry as it is traced: in the list
+    ``global_metrics.meta["hist_geometry"]`` beside ``hist_traffic``,
+    and as the args of a ``hist`` program span (``lgbm/hist`` in a
+    profiler session). It says whether the large step engaged at a
+    shape, or fell back for VMEM or for a section no large chunk
+    divides."""
+    from ..obs.metrics import global_metrics
+    from ..obs.trace import global_tracer
+    rec = {"kernel": name, "features": num_features, "max_bins": max_bins,
+           "rows": rows, "bp": geom.bp, "features_per_step": geom.f_blk,
+           "features_per_dot": geom.dot_feats, "row_chunk": geom.row_chunk,
+           "grid_steps": grid[0] * grid[1], "pack_factor": vpb,
+           "operand": "int8" if int8 else "bfloat16"}
+    seen = global_metrics.meta.setdefault("hist_geometry", [])
+    if rec not in seen:
+        seen.append(rec)
+    with global_tracer.span("hist", args=rec):
+        pass
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("max_bins", "num_slots", "precise",
+                                    "interpret"))
+def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
+                      leaf_ids: jax.Array, *, max_bins: int, num_slots: int,
+                      precise="highest", interpret=None) -> jax.Array:
+    """Histograms of up to `num_slots` leaves in ONE pass over the rows.
+
+    The one-hot (bins) operand is leaf-independent, so packing the MXU's
+    128 output columns with (leaf, channel) pairs builds J = 42 leaves'
+    histograms for the cost of one (the reference instead loops leaves,
+    touching each leaf's rows separately — cuda_histogram_constructor.cu:21
+    one kernel per leaf). Rows route to their leaf's columns via a
+    compare against row_leaf — the device analog of DataPartition.
+
+    bins_fm: [F, N] uint8/16 (or PackedBins); ghT: [N, 3] f32 pre-masked
+    (grad, hess, w); row_leaf: [N] int32; leaf_ids: [num_slots] int32
+    (pad with -2). Returns hist [num_slots, F, B, 3] f32.
+    """
+    packed = isinstance(bins_fm, PackedBins)
     return _packed_multi_call(
-        pb, [(ghT.T, 0.0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
-        kern,
-        max_bins=max_bins, num_slots=num_slots, out_dtype=jnp.float32,
-        interpret=interpret)
+        bins_fm, [(ghT, 0.0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
+        _multi_kernel_packed,
+        max_bins=max_bins, num_slots=num_slots, int8=False,
+        precise=precise, interpret=interpret,
+        name="lgbm_hist_multi_packed" if packed else "lgbm_hist_multi")
 
 
-@functools.partial(jax.jit, static_argnames=("max_bins", "num_slots",
-                                             "interpret"))
-def _hist_multi_packed_int8(pb, ghT_i8, row_leaf, leaf_ids, *,
-                            max_bins: int, num_slots: int, interpret=None):
-    kern = functools.partial(_multi_kernel_packed, int8=True, precise=None)
+@functools.partial(jax.jit,
+                   static_argnames=("max_bins", "num_slots", "interpret"))
+def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
+                           row_leaf: jax.Array, leaf_ids: jax.Array, *,
+                           max_bins: int, num_slots: int,
+                           interpret=None) -> jax.Array:
+    """Quantized multi-leaf histograms: one pass, int32 accumulation.
+
+    ghT_i8: [N, 3] int8 (quantized grad, quantized hess, {0,1} weight),
+    pre-masked. Returns [num_slots, F, B, 3] int32 — callers scale by
+    (g_scale, h_scale, 1) to recover the f32 statistics. Safe for
+    N < 2^31 / (num_grad_quant_bins): |g_int| <= bins/2, so per-bin int32
+    sums cannot overflow at any realistic scale.
+    """
+    packed = isinstance(bins_fm, PackedBins)
     return _packed_multi_call(
-        pb, [(ghT_i8.T, 0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
-        kern,
-        max_bins=max_bins, num_slots=num_slots, out_dtype=jnp.int32,
-        interpret=interpret)
+        bins_fm, [(ghT_i8, 0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
+        _multi_kernel_packed,
+        max_bins=max_bins, num_slots=num_slots, int8=True,
+        interpret=interpret,
+        name="lgbm_hist_multi_packed" if packed else "lgbm_hist_multi_int8")
 
 
 @functools.partial(jax.jit,
@@ -522,29 +535,20 @@ def hist_pallas_multi_fused(bins_fm, score, label, weight, mask, row_leaf,
     """Multi-leaf histograms with the gradient pass fused in: operands
     are (score, label[, weight], mask) instead of a pre-built ghT, and
     grad_fn (the objective's pointwise gradient) runs inside the kernel.
-    Accepts PackedBins or raw [F, N] uint8 bins. Returns [S, F, B, 3]."""
-    # the kernel reads bins through the byte-sectioned path (vpb=1 masks
-    # with & 255): uint16 ids would alias silently — refuse them
-    assert max_bins <= 256, \
-        "hist_pallas_multi_fused needs byte-representable bin ids"
+    Accepts PackedBins or raw [F, N] bins. Returns [S, F, B, 3]."""
     has_weight = weight is not None
-    kern0 = functools.partial(_multi_kernel_fused, precise=precise,
-                              grad_fn=grad_fn, has_weight=has_weight)
     vecs = [(score.astype(jnp.float32), 0.0),
             (label.astype(jnp.float32), 0.0)]
     if has_weight:
         vecs.append((weight.astype(jnp.float32), 0.0))
     vecs.append((mask.astype(jnp.float32), 0.0))
     vecs.append((row_leaf.astype(jnp.int32), -1))
-    if not isinstance(bins_fm, PackedBins):
-        # unpacked: wrap the raw matrix as a vpb=1 "packed" layout — the
-        # kernel's shift-0/mask-255 section loop is then the identity
-        n = bins_fm.shape[1]
-        sec = -(-n // _PACKED_CHUNK_BYTES) * _PACKED_CHUNK_BYTES
-        bins_fm = PackedBins(jnp.pad(bins_fm, ((0, 0), (0, sec - n))), n, 1)
     return _packed_multi_call(
-        bins_fm, vecs, leaf_ids, kern0, max_bins=max_bins,
-        num_slots=num_slots, out_dtype=jnp.float32, interpret=interpret)
+        bins_fm, vecs, leaf_ids,
+        functools.partial(_multi_kernel_fused, grad_fn=grad_fn,
+                          has_weight=has_weight),
+        max_bins=max_bins, num_slots=num_slots, int8=False,
+        precise=precise, interpret=interpret, name="lgbm_hist_multi_packed")
 
 
 def _hist_kernel_packed(bins_ref, *refs, f_blk: int, max_bins: int,

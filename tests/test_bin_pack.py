@@ -10,6 +10,8 @@ alike. The no-subtraction oracle reorders f32 accumulation, so its
 gate is tolerance-based (documented in config.tpu_wave_subtract).
 """
 
+import functools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -419,6 +421,44 @@ def test_traffic_meta_reaches_obs_and_model_consistency():
         ht["hist_bytes_per_iter"]
     assert global_metrics.meta["hist_bytes_reduction"] > 1.0
     assert bst._gbdt._bin_pack_vpb == 2
+
+
+@pytest.mark.parametrize("kind", ["int8", "float"])
+def test_published_geometry_at_the_benchmark_shape(kind):
+    """The geometry each traced multi-leaf kernel took is published under
+    ``global_metrics.meta["hist_geometry"]`` beside ``hist_traffic``. At
+    the benchmark cells' 63M x 28 (traced abstractly: nothing runs) both
+    operand types take one feature block and under 20,000 grid steps a
+    pass, where the parent took 123,048 and 246,096."""
+    import jax
+    from lightgbm_tpu.obs.metrics import global_metrics
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    n, f, slots = 63_000_000, 28, 42
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    vec, rl, ids = s((n,), jnp.float32), s((n,), jnp.int32), s((slots,),
+                                                              jnp.int32)
+    kw = dict(max_bins=63, num_slots=slots, interpret=True)
+    if kind == "int8":
+        jax.eval_shape(functools.partial(ph.hist_pallas_multi_int8, **kw),
+                       s((f, n), jnp.uint8), s((n, 3), jnp.int8), rl, ids)
+    else:
+        jax.eval_shape(
+            functools.partial(ph.hist_pallas_multi_fused, precise="default",
+                              grad_fn=lambda sc, lb, w: (sc - lb, sc * sc),
+                              **kw),
+            s((f, n), jnp.uint8), vec, vec, None, vec, rl, ids)
+    operand = "int8" if kind == "int8" else "bfloat16"
+    got, = [g for g in global_metrics.meta["hist_geometry"]
+            if g["rows"] >= n and g["operand"] == operand]
+    assert got["kernel"] == ("lgbm_hist_multi_int8" if kind == "int8"
+                             else "lgbm_hist_multi_packed")
+    assert (got["features"], got["max_bins"], got["bp"]) == (f, 63, 64)
+    assert got["features_per_step"] == 32 and got["pack_factor"] == 1
+    assert got["row_chunk"] >= 4096 and got["features_per_dot"] * 64 >= 512
+    assert got["grid_steps"] == -(-n // got["row_chunk"]) < 20_000
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,19 @@ ITER_ROWS = 1 << 14            # rows of the whole iteration program below
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def llo_dir(tmp_path_factory):
+    """Where Mosaic writes each kernel's stages. libtpu reads the flag
+    once, as it is loaded, so it is set before the topology is described
+    (`one_chip` asks for this fixture)."""
+    path = tmp_path_factory.mktemp("mosaic")
+    os.environ["LIBTPU_INIT_ARGS"] = (
+        os.environ.get("LIBTPU_INIT_ARGS", "")
+        + f" --xla_mosaic_dump_to={path}").strip()
+    return path
+
+
+@pytest.fixture(scope="module")
+def one_chip(llo_dir):
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -77,14 +89,15 @@ def _shapes(one_chip):
     return s
 
 
-def _bins(s, n, max_bins, vpb=1):
-    """[F, n] bin ids as a shape on the described chip: raw uint8/uint16
-    for vpb=1, else PackedBins with vpb values per byte."""
+def _bins(s, n, max_bins, vpb=1, features=F):
+    """[features, n] bin ids as a shape on the described chip: raw
+    uint8/uint16 for vpb=1, else PackedBins with vpb values per byte."""
     if vpb == 1:
-        return s((F, n), jnp.uint8 if max_bins <= 256 else jnp.uint16)
+        return s((features, n),
+                 jnp.uint8 if max_bins <= 256 else jnp.uint16)
     section = -(-n // vpb)
     section = -(-section // PACK_ALIGN) * PACK_ALIGN
-    return PackedBins(s((F, section), jnp.uint8), n, vpb)
+    return PackedBins(s((features, section), jnp.uint8), n, vpb)
 
 
 def _kernel(kind, s, bins, n, max_bins, precise="default"):
@@ -168,6 +181,75 @@ def test_row_operands_stay_lane_dense_at_higgs_rows(one_chip, kind):
     assert mem.temp_size_in_bytes < 1 << 30, mem
 
 
+# (features, max_bins): the benchmark's shape, the wide shape (PERF.md
+# section 6), the one-feature-a-256-row-slab branch at Criteo's width, and
+# uint16 ids
+GEOMETRIES = [(28, 63), (2000, 63), (67, 255), (28, 300)]
+
+
+@pytest.mark.parametrize("itemsize", [1, 2], ids=["int8", "bf16"])
+@pytest.mark.parametrize("features,max_bins", GEOMETRIES)
+def test_geometry_stays_under_scoped_vmem(features, max_bins, itemsize):
+    """`_fb_geometry` by its own arithmetic: whole tiles, dots of 512
+    one-hot rows or more, and a step under the scoped-VMEM limit."""
+    g = ph._fb_geometry(features, max_bins, 1, itemsize)
+    assert g.bp >= max_bins and g.bp % (32 // itemsize) == 0
+    assert g.f_blk % max(g.dot_feats, 8) == 0
+    assert g.dot_feats * g.bp >= 512 and g.row_chunk in ph._ROW_CHUNKS
+    assert ph._step_vmem_bytes(g, 1, itemsize) <= ph._VMEM_LIMIT
+    # a leaf operand serves 2048 one-hot rows or more (32 features at 63
+    # bins: one block at the narrow shape), where it served 504
+    assert g.f_blk * g.bp >= 2048
+
+
+@pytest.mark.parametrize("kind", ["multi", "int8"])
+@pytest.mark.parametrize("features,max_bins", GEOMETRIES[1:])
+def test_geometry_is_accepted(one_chip, kind, features, max_bins):
+    """What the arithmetic allows the compiler takes, at the shapes
+    beyond test_kernel_is_accepted's 28 features."""
+    n = 1 << 17
+    s = _shapes(one_chip)
+    fn, args = _kernel(kind, s, _bins(s, n, max_bins, features=features),
+                       n, max_bins)
+    _compiles_to_mosaic(fn, *args)
+
+
+# operations of a step's vector program that are not the VPU's
+_NOT_ALU = ("vector_load", "vector_store", "vmatmul", "vlatch", "vmatres",
+            "vdwg")
+
+
+def llo_counts(llo_dir, fn, *args):
+    """{op: count} of one kernel's last Mosaic stage (``post-finalize-
+    llo``: the step's vector program, unrolled), compiled for the
+    described chip. The count is per grid step."""
+    import collections
+    import re
+    for old in llo_dir.glob("*"):
+        old.unlink()
+    jax.jit(fn).lower(*args).compile()
+    dump, = llo_dir.glob("*post-finalize-llo*")
+    return collections.Counter(
+        re.findall(r'"?llo\.(v[a-z_0-9]+)', dump.read_text()))
+
+
+def test_int8_step_vector_alu_count(one_chip, llo_dir):
+    """The int8 step at the benchmark's shape was bound by the VPU: 41k
+    vector-ALU operations per 2048 rows x 32 features (three selects a
+    one-hot vreg, the mask's cast to int8, the leaf operand rebuilt per 8
+    features), beside 4.1k cycles of matmul. Bin-aligned slabs built as
+    packed words take 11.8k (PERF.md section 6, PR 29), of which 4.1k add
+    the matmuls' popped results; the count cannot creep back unseen."""
+    s = _shapes(one_chip)
+    fn, args = _kernel("int8", s, _bins(s, N, 63), N, 63)
+    ops = llo_counts(llo_dir, fn, *args)
+    geom = ph._fb_geometry(F, 63, 1, 1, rows=N)
+    per = 2048 * 32 / (geom.row_chunk * geom.f_blk)
+    alu = sum(n for op, n in ops.items() if op not in _NOT_ALU)
+    assert ops["vmatmul"] * per == 1024 and ops["vlatch"] * per <= 256
+    assert 4096 < alu * per < 20_000, ops
+
+
 # ---------------------------------------------------------------------------
 # the whole iteration program, for its layer table (ISSUE 26): what the
 # chip's compiler leaves of the lgbm/<layer> scopes is what the benchmark's
@@ -246,7 +328,9 @@ def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table,
     kernels = {head: layer for head, layer in fused_iter_table.items()
                if head.startswith("%lgbm_hist_multi")}
     assert kernels and set(kernels.values()) == {"hist"}
-    assert all(head.startswith(KERNEL[path]) for head in kernels), kernels
+    # one kernel name a program: every pass runs the one step
+    names = {head.split(" = ")[0].split(".")[0] for head in kernels}
+    assert names == {KERNEL[path]}, kernels
 
 
 @pytest.mark.parametrize("path,shape,layer", [

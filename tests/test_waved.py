@@ -8,13 +8,14 @@ agreement (tests/python_package_test/test_dual.py:19); waved-vs-exact is
 the analogous gate for the batched TPU grower.
 """
 
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.ops.pallas_histogram import (hist_multi_xla,
-                                               hist_pallas_multi)
 from tests.conftest import make_binary, make_regression
 
 
@@ -158,53 +159,99 @@ def test_waved_with_bagging_and_feature_fraction():
     assert _auc(y, bst.predict(X)) > 0.85
 
 
-def test_hist_pallas_multi_matches_xla():
-    """Execute the Pallas multi-leaf kernel in interpreter mode on CPU and
-    require exact agreement with the XLA loop implementation."""
-    r = np.random.RandomState(0)
-    n, f, b, slots = 700, 5, 16, 42
-    bins = jnp.asarray(r.randint(0, b, (f, n)), jnp.uint8)
-    mask = (r.rand(n) < 0.8).astype(np.float32)
-    ghT = jnp.asarray(
-        np.stack([r.randn(n) * mask, np.abs(r.randn(n)) * mask, mask],
-                 axis=1), jnp.float32)
-    row_leaf = jnp.asarray(r.randint(0, 6, n), jnp.int32)
-    leaf_ids = jnp.asarray([0, 2, 5, 1] + [-2] * (slots - 4), jnp.int32)
-
-    ref = hist_multi_xla(bins, ghT, row_leaf, leaf_ids,
-                         max_bins=b, num_slots=slots)
-    pal = hist_pallas_multi(bins, ghT, row_leaf, leaf_ids,
-                            max_bins=b, num_slots=slots, interpret=True)
-    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
-    # padded slots stay empty
-    assert np.all(np.asarray(pal[4:]) == 0.0)
+# (max_bins, values a byte, features, slots, rows): every branch of
+# pallas_histogram._fb_geometry: a slab of one tile and of several (63 and
+# 64 bins give 64 rows, 255 and 256 give 256, 300 gives 304 or 320 and
+# uint16 ids; 15/16 and 3/4 bins the 4-bit and 2-bit PackedBins), a
+# padded feature block (5, 28, 70), more than one block (70 features at
+# 255 bins), a row count no chunk divides, a section only the smallest
+# chunk divides (9000 rows at 2 a byte: 6144 bytes)
+STEP_CASES = [(63, 1, 28, 42, 5000), (63, 1, 5, 1, 2500),
+              (64, 1, 70, 8, 2500), (255, 1, 5, 8, 2500),
+              (255, 1, 70, 42, 2100), (256, 1, 28, 1, 2500),
+              (300, 1, 5, 8, 2500), (300, 1, 28, 42, 2100),
+              (15, 2, 28, 8, 9000), (16, 2, 5, 42, 4100),
+              (3, 4, 28, 8, 9000), (4, 4, 70, 1, 8200)]
 
 
-def test_hist_pallas_multi_int8_matches_xla():
-    """The int8 quantized multi-leaf kernel (interpret mode) must agree
-    EXACTLY with the f32 XLA path on integer-valued inputs: both compute
-    sums of small integers, which f32 represents exactly."""
-    from lightgbm_tpu.ops.pallas_histogram import hist_pallas_multi_int8
-    r = np.random.RandomState(2)
-    n, f, b, slots = 600, 5, 16, 42
-    bins = jnp.asarray(r.randint(0, b, (f, n)), jnp.uint8)
-    mask = (r.rand(n) < 0.8).astype(np.int8)
-    g_int = (r.randint(-3, 4, n) * mask).astype(np.int8)
-    h_int = (r.randint(0, 5, n) * mask).astype(np.int8)
-    ghT_i8 = jnp.asarray(np.stack([g_int, h_int, mask], axis=1), jnp.int8)
-    row_leaf = jnp.asarray(r.randint(0, 6, n), jnp.int32)
-    leaf_ids = jnp.asarray([0, 3, 5, 1] + [-2] * (slots - 4), jnp.int32)
+def _binary_grad(score, label, weight):
+    p = jax.nn.sigmoid(score)
+    return p - label, p * (1.0 - p)
 
-    hist_i = hist_pallas_multi_int8(bins, ghT_i8, row_leaf, leaf_ids,
-                                    max_bins=b, num_slots=slots,
-                                    interpret=True)
-    ghT_f = jnp.asarray(np.stack([g_int, h_int, mask], axis=1), jnp.float32)
-    ref = hist_multi_xla(bins, ghT_f, row_leaf, leaf_ids,
-                         max_bins=b, num_slots=slots)
-    assert hist_i.dtype == jnp.int32
-    np.testing.assert_array_equal(np.asarray(hist_i, np.float32),
-                                  np.asarray(ref))
+
+@pytest.mark.parametrize("kind", ["int8", "float", "fused"])
+@pytest.mark.parametrize("max_bins,vpb,f,slots,n", STEP_CASES)
+def test_shared_step_matches_xla_twins(kind, max_bins, vpb, f, slots, n):
+    """The one step every multi-leaf Pallas kernel runs
+    (`_accum_section_dots`, interpret mode), under each of its three
+    operand readers, against the XLA twins: bit for bit on int8, to
+    float32 rounding on float (tpu_hist_precision=highest: three bf16
+    passes over the leaf operand); padded slots stay empty, and so do a
+    slab's rows at max_bins and beyond."""
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    from lightgbm_tpu.ops.bin_pack import pack_bins_host, to_device
+    r = np.random.RandomState(max_bins + f + n)
+    bins = r.randint(0, max_bins, (f, n)).astype(
+        np.uint8 if max_bins <= 256 else np.uint16)
+    # 16 and 4 bins have no sentinel left in their bits: the program
+    # leaves them unpacked, the kernels take them (test_chip_compile)
+    arg = (to_device(pack_bins_host(bins, {2: 15, 4: 3}[vpb])) if vpb > 1
+           else jnp.asarray(bins))
+    assert getattr(arg, "vpb", 1) == vpb
+    mask = r.rand(n) < 0.8
+    row_leaf = jnp.asarray(r.randint(0, slots + 3, n), jnp.int32)
+    live = min(slots, 4)
+    leaf_ids = jnp.asarray(list(r.permutation(slots + 3)[:live])
+                           + [-2] * (slots - live), jnp.int32)
+    kw = dict(max_bins=max_bins, num_slots=slots)
+    m = jnp.asarray(mask, jnp.float32)
+    if kind == "int8":
+        gh = jnp.asarray(np.stack([r.randint(-63, 64, n) * mask,
+                                   r.randint(0, 64, n) * mask, mask], 1),
+                         jnp.int8)
+        want = ph.hist_multi_int8_xla(jnp.asarray(bins), gh, row_leaf,
+                                      leaf_ids, **kw)
+        vecs, kernel = [(gh, 0)], ph._multi_kernel_packed
+        got = ph.hist_pallas_multi_int8(arg, gh, row_leaf, leaf_ids,
+                                        interpret=True, **kw)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        score = jnp.asarray(r.randn(n), jnp.float32)
+        label = jnp.asarray(r.rand(n) < 0.5, jnp.float32)
+        g, h = _binary_grad(score, label, None)
+        gh = jnp.stack([g * m, h * m, m], 1)
+        want = ph.hist_multi_xla(jnp.asarray(bins), gh, row_leaf, leaf_ids,
+                                 **kw)
+        if kind == "float":
+            vecs, kernel = [(gh, 0.0)], ph._multi_kernel_packed
+            got = ph.hist_pallas_multi(arg, gh, row_leaf, leaf_ids,
+                                       interpret=True, **kw)
+        else:
+            vecs = [(score, 0.0), (label, 0.0), (m, 0.0)]
+            kernel = functools.partial(ph._multi_kernel_fused,
+                                       grad_fn=_binary_grad,
+                                       has_weight=False)
+            got = ph.hist_pallas_multi_fused(
+                arg, score, label, None, m, row_leaf, leaf_ids,
+                grad_fn=_binary_grad, interpret=True, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-6, atol=2e-5)
+    assert np.all(np.asarray(got[live:]) == 0)
+    slabs = np.asarray(ph._multi_slabs(
+        arg, vecs + [(row_leaf, -1)], leaf_ids, kernel, int8=kind == "int8",
+        precise="highest", interpret=True, name="lgbm_hist_multi", **kw))
+    from lightgbm_tpu.obs.metrics import global_metrics
+    geom, = [g for g in global_metrics.meta["hist_geometry"]
+             if (g["kernel"], g["features"], g["max_bins"], g["pack_factor"],
+                 g["operand"] == "int8") == ("lgbm_hist_multi", f, max_bins,
+                                             vpb, kind == "int8")]
+    assert slabs.shape[0] % geom["features_per_step"] == 0
+    assert slabs.shape[0] >= f and slabs.shape[1] == geom["bp"] >= max_bins
+    assert not slabs[:, max_bins:].any() and not slabs[f:, 1:].any()
+    np.testing.assert_array_equal(
+        slabs[:f, :max_bins, :3 * slots].reshape(f, max_bins, slots, 3),
+        np.moveaxis(np.asarray(got), 0, 2))
 
 
 def test_waved_quantized_grad_trains():
