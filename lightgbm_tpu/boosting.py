@@ -17,8 +17,10 @@ Reference order of operations preserved (gbdt.cpp:353-461):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -93,6 +95,77 @@ def _records_to_host(recs):
     return jax.device_get(stacked)
 
 
+class _ResidentFeed:
+    """Bins feed of resident training: the device tensor the booster
+    holds; nothing to stage, nothing to account."""
+
+    orchestrated = False
+
+    def take(self, bins_fm):
+        return bins_fm
+
+    def dispatched(self) -> None:
+        pass
+
+    def done(self, scores) -> None:
+        pass
+
+
+class _SlabFeed:
+    """Bins feed of out-of-core training (tpu_stream) over a
+    HostSlabBins plan, with the streaming pipeline's accounting. One
+    slab: the fused program's bins operand is the staged device copy of
+    the whole (packed) bin matrix, uploaded once and cached — the bins
+    are immutable, so re-staging identical bytes every iteration would
+    only waste link bandwidth, and holding the one copy is exactly the
+    memory the model budgeted for the slab pair. The plan degenerates to
+    resident behavior with an explicit upload, which is what makes
+    single-slab streamed models bit-identical. Several slabs: the
+    streamed composition feeds itself (HostSlabBins.feed), wave by
+    wave."""
+
+    def __init__(self, plan):
+        self._plan = plan
+        self._staged = None
+
+    @property
+    def orchestrated(self) -> bool:
+        return self._plan.n_slabs > 1
+
+    def take(self, bins_fm):
+        if self._staged is None:
+            self._staged = self._plan.stage_noted(0)
+        return self._staged
+
+    def dispatched(self) -> None:
+        """Right after the fused program dispatches (async): the overlap
+        classifier's count of compute in flight (the cached single-slab
+        upload needs no re-stage)."""
+        self._plan.stats.note_dispatch()
+
+    def done(self, scores) -> None:
+        """End of a streamed iteration: the sync resets the overlap
+        classifier's in-flight count so a later pipeline can't inherit
+        stale dispatches."""
+        t0 = time.perf_counter()
+        jax.block_until_ready(scores)
+        self._plan.stats.note_block(time.perf_counter() - t0)
+        self._plan.stats.iterations_total += 1
+        self.publish()
+
+    def publish(self) -> None:
+        """Publish the streaming pipeline accounting (always-on meta ->
+        bench JSON `stream` field + lgbmtpu_stream_* OpenMetrics)."""
+        plan = self._plan
+        global_metrics.set_meta("stream", {
+            **plan.stats.summary(),
+            "slab_rows": int(plan.slab_rows),
+            "n_slabs": int(plan.n_slabs),
+            "num_data": int(plan.num_data),
+            "host_bytes": int(plan.nbytes_host),
+        })
+
+
 class GBDT:
     """Gradient Boosted Decision Trees (ref: src/boosting/gbdt.h:38)."""
 
@@ -136,7 +209,8 @@ class GBDT:
         self._bin_pack_vpb = 1
         self._stream = self._resolve_stream(train_set)
         self._stream_progs: Dict = {}
-        self._stream_next_bins = None  # cross-iteration upload prefetch
+        self._feed = (_SlabFeed(self._stream) if self._stream is not None
+                      else _ResidentFeed())
         if self._stream is not None:
             self.bins_fm = self._stream
             self._bin_pack_vpb = self._stream.vpb
@@ -493,8 +567,8 @@ class GBDT:
             # out-of-core streaming: the grower is host-orchestrated
             # over HostSlabBins; the slow path's `self._grow` becomes
             # the streamed adapter (same call signature, bins argument
-            # carries the plan), and the fast paths route through
-            # _train_one_iter_stream
+            # carries the plan), and the fast path takes its bins from
+            # the slab feed
             self._stream.mesh = shard_mesh or getattr(self, "mesh", None)
             self._stream_grower = self._make_stream_grower(hist_impl)
             self._grow = self._stream_grow_slow
@@ -984,12 +1058,93 @@ class GBDT:
                                  hist_reduce=getattr(
                                      self, "_hist_reduce", "psum"))
 
-    def _grow_class_traced(self, grow, bins_fm, k, key, grad, hess,
-                           sample_mask, scores_k, it):
-        """Traced growth of class k's tree for one iteration: GOSS,
-        gradient quantization, feature sampling, growth, leaf renewal.
-        Shared by the GBDT and DART fused programs. Returns
-        (rec, row_leaf)."""
+    # ------------------------------------------------------------------
+    # One boosting iteration, traced: a head (bagging mask, gradients), a
+    # step per class (sample, grow, tail) and a finish. Each stage is
+    # written once, here; the programs below only compose them. The
+    # resident composition is one program (_make_fused); the streamed one
+    # (tpu_stream) cuts it where arrays must materialize for the
+    # host-orchestrated slab grower, each stage WHOLE inside one program
+    # so XLA's FMA-contraction choices cannot diverge. Same stages, same
+    # RNG folds => streamed models bit-identical to resident ones
+    # whenever the slab accumulation itself is exact (single slab, or
+    # int8-quantized histograms at any slab count). A booster with
+    # device state of its own (DART) overrides stages, never a
+    # composition: `hist` are its buffers the tail rewrites, `drop` its
+    # other device inputs, `carry` what its head hands to the later
+    # stages; GBDT has none of the three.
+    _tags = {"fused": "fused_iter", "prep": "prep", "post": "class_post"}
+    _n_hist = 0
+    _fused_donate = (3, 4, 5)
+
+    @contextlib.contextmanager
+    def _traced_obj_state(self, obj_state):
+        """The objective's device state swapped in for the length of a
+        trace. Every N-sized objective buffer (label, weight, pad arrays)
+        reaches a program as an explicit argument this way — closure
+        capture would bake them into the HLO as multi-hundred-MB literal
+        constants and overflow compilation at Higgs scale."""
+        obj = self.objective
+        if obj is None:
+            yield
+            return
+        old_state = obj.swap_device_state(obj_state)
+        try:
+            yield
+        finally:
+            obj.swap_device_state(old_state)
+
+    def _evolved_obj_state(self):
+        """Objective state a trace has updated, as program outputs:
+        objectives that evolve device state across iterations (e.g.
+        lambdarank position biases) assign tracers to their attributes
+        during the trace; collecting the state inside the
+        _traced_obj_state frame returns the updates instead of losing
+        them at restore. Evolving subset only — returning the full state
+        would copy every constant [N] label/weight buffer per iter."""
+        return (self.objective.device_state(evolving_only=True)
+                if self.objective is not None
+                else {"arrays": {}, "sub": {}})
+
+    def _iter_bagging(self, sample_mask, it):
+        """The iteration's RNG key (every later fold starts from it) and
+        its bagging mask."""
+        key = jax.random.fold_in(self._bagging_key, it)
+        return key, self._sampling_in_jit(jax.random.fold_in(key, 1), it,
+                                          sample_mask)
+
+    def _iter_head(self, scores, sample_mask, it, lr, hist, drop):
+        """Stage 1, the head: bagging mask and gradients [K, N]. Returns
+        (key, sample_mask, grad_all, hess_all, sentinel operands,
+        carry)."""
+        key, sample_mask = self._iter_bagging(sample_mask, it)
+        sen = (None, None)
+        if self._fused_grad_fn is not None:
+            # gradients fold into the histogram waves (see _class_grow)
+            # — no [N] gradient buffers in this program at all
+            grad_all = hess_all = (None,)
+            if self._health_armed:
+                # NaN/Inf sentinel operands: the same pointwise formula
+                # the grower evaluates — XLA CSEs the two, so the fused
+                # path stays fused
+                with jax.named_scope("lgbm/gradient"):
+                    sen = self._fused_grad_fn(
+                        scores[0], self.objective.label,
+                        self.objective.weight)
+        else:
+            grad_all, hess_all = self._grad_fn(scores)
+            sen = (grad_all, hess_all)
+        return key, sample_mask, grad_all, hess_all, sen, None
+
+    def _grad_scores(self, scores, carry):
+        """The [K, N] scores the head took the gradients at."""
+        return scores
+
+    def _class_sample(self, k, key, grad, hess, sample_mask):
+        """Stage 2a: class k's GOSS, gradient quantization and feature
+        sampling, on the salts 100+k, 300+k, 200+k of the iteration key.
+        Returns (mask, grad, hess, true_grad, true_hess, quant, fmask);
+        grad is None where the gradient folds into the kernel."""
         mask = sample_mask
         if self.config.data_sample_strategy == "goss":
             mask, scale = self._goss_in_jit(
@@ -1002,138 +1157,142 @@ class GBDT:
                 jax.random.fold_in(key, 300 + k), grad, hess)
         fmask = self._feature_mask_in_jit(
             jax.random.fold_in(key, 200 + k))
-        node_key = (jax.random.fold_in(
-            self._extra_key,
-            it * self.num_tree_per_iteration + k)
+        return mask, grad, hess, true_grad, true_hess, quant, fmask
+
+    def _use_int8_hist(self) -> bool:
+        """int8 integer-histogram passes (the exact grower consumes the
+        dequantized f32 values instead). |h_int| <= bins and
+        |g_int| <= bins/2+1, so the int8 cast is exact only for
+        bins <= 126 — larger settings stay on the f32 hist path."""
+        return (self._quant_enabled and self._use_waved() and
+                int(self.config.num_grad_quant_bins) <= 126)
+
+    def _node_key(self, k, it):
+        return (jax.random.fold_in(
+            self._extra_key, it * self.num_tree_per_iteration + k)
             if self._use_node_rand else None)
+
+    def _class_grow(self, grow, bins_fm, k, it, grad, hess, mask, fmask,
+                    quant, scores_k):
+        """Stage 2b of the resident composition: class k's tree grown
+        inside the trace. Returns (rec, row_leaf). (The streamed
+        composition grows on the host, StreamTreeGrower.grow, from the
+        operands _stream_operands builds of the same `quant`.)"""
+        node_key = self._node_key(k, it)
         grow_kw = {}
-        if quant is not None and self._use_waved() and \
-                int(self.config.num_grad_quant_bins) <= 126:
-            # int8 integer-histogram passes (the exact grower
-            # consumes the dequantized f32 values instead).
-            # |h_int| <= bins and |g_int| <= bins/2+1, so the
-            # int8 cast is exact only for bins <= 126 — larger
-            # settings stay on the f32 hist path
+        if quant is not None and self._use_int8_hist():
             grow_kw["quant"] = quant
         if grad is None:
-            # fused gradient/histogram wave (tpu_fused_grad): the
-            # caller skipped _grad_fn entirely; the grower derives
-            # gh from the objective's pointwise formula — in-kernel
-            # on the pallas path
+            # fused gradient/histogram wave (tpu_fused_grad): the head
+            # skipped _grad_fn entirely; the grower derives gh from the
+            # objective's pointwise formula — in-kernel on the pallas
+            # path
             grow_kw["fused_grad"] = (self._fused_grad_fn,
                                      self.objective.label,
                                      self.objective.weight, scores_k)
-        rec, row_leaf = grow(bins_fm, grad, hess, mask, fmask,
-                             self.feature_meta, self.hp,
-                             self.max_depth, self._forced,
-                             node_key, **grow_kw)
-        if self._quant_enabled and \
-                self.config.quant_train_renew_leaf:
-            rec = self._renew_leaves_in_jit(
-                rec, row_leaf, true_grad, true_hess, mask)
-        obj = self.objective
-        if obj is not None:
+        return grow(bins_fm, grad, hess, mask, fmask, self.feature_meta,
+                    self.hp, self.max_depth, self._forced, node_key,
+                    **grow_kw)
+
+    def _class_tail(self, k, rec, row_leaf, scores, scores_k, valid_scores,
+                    valid_bins, mask, true_grad, true_hess, lr, carry, hist):
+        """Stage 3, the tail: leaf renewal, then the booster's score rule
+        on the training scores and on every valid set's replayed leaves
+        — ONE program in every composition, so the multiply-gather-add
+        keeps its FMA shape. `scores_k` is class k's row of
+        _grad_scores (the caller's slice: the fused program takes it
+        before the sample stage, where its grower may need it). Returns
+        (rec, scores, valid_scores, hist)."""
+        if self._quant_enabled and self.config.quant_train_renew_leaf:
+            rec = self._renew_leaves_in_jit(rec, row_leaf, true_grad,
+                                            true_hess, mask)
+        if self.objective is not None:
             with jax.named_scope("lgbm/renew"):
-                renewed_lv = obj.renew_leaves_traced(
+                renewed_lv = self.objective.renew_leaves_traced(
                     rec.leaf_value, row_leaf, scores_k, mask)
                 if renewed_lv is not None:
                     rec = rec._replace(leaf_value=jnp.where(
                         rec.num_leaves > 1, renewed_lv, rec.leaf_value))
-        return rec, row_leaf
+        scores, leaf_vals, hist = self._score_rule(
+            k, rec, row_leaf, scores, lr, carry, hist)
+        new_valid = list(valid_scores)
+        for vi in range(len(valid_bins)):
+            with jax.named_scope("lgbm/valid"):
+                vleaf = replay_tree(
+                    rec, valid_bins[vi], self.feature_meta, self._bundle,
+                    num_data=self._valid_sets[vi][0].num_data)
+                new_valid[vi], hist = self._valid_score_rule(
+                    k, vi, new_valid[vi], leaf_vals, vleaf, carry, hist)
+        return rec, scores, tuple(new_valid), hist
+
+    def _score_rule(self, k, rec, row_leaf, scores, lr, carry, hist):
+        """How class k's new tree lands on the training scores. Returns
+        (scores, the leaf outputs the valid sets take, hist)."""
+        with jax.named_scope("lgbm/score"):
+            # 1-leaf trees contribute nothing (the reference stops
+            # training instead, gbdt.cpp should_continue)
+            leaf_vals = jnp.where(rec.num_leaves > 1,
+                                  rec.leaf_value * lr, 0.0)
+            scores = scores.at[k].add(leaf_vals[row_leaf])
+        return scores, leaf_vals, hist
+
+    def _valid_score_rule(self, k, vi, valid, leaf_vals, vleaf, carry,
+                          hist):
+        """The score rule on valid set vi (traced under lgbm/valid)."""
+        return valid.at[k].add(leaf_vals[vleaf]), hist
+
+    def _iter_finish(self, carry, drop):
+        """Stage 4: what is left of the booster's device state to update
+        once every class has its tree; returns it as a tuple."""
+        return ()
 
     def _make_fused(self):
-        """Build the one-XLA-program-per-iteration jit. All N-sized device
-        buffers (bin tensor, valid bins, objective label/weight/pad arrays)
-        are explicit arguments — closure capture would bake them into the
-        HLO as multi-hundred-MB literal constants and overflow compilation
-        at Higgs scale."""
+        """Build the one-XLA-program-per-iteration jit: head, sample +
+        grow + tail per class, finish. All N-sized device buffers (bin
+        tensor, valid bins, objective state) are explicit arguments, see
+        _traced_obj_state; `rest` is (*hist, *drop, it, lr)."""
         grow = self._grow_partial()
-        sentinel = self._health_armed
 
         def fused(bins_fm, valid_bins, obj_state, scores, sample_mask,
-                  valid_scores, it, lr):
-            obj = self.objective
-            old_state = (obj.swap_device_state(obj_state)
-                         if obj is not None else None)
-            try:
-                key = jax.random.fold_in(self._bagging_key, it)
-                sample_mask = self._sampling_in_jit(
-                    jax.random.fold_in(key, 1), it, sample_mask)
-                sen_g = sen_h = None
-                if self._fused_grad_fn is not None:
-                    # gradients fold into the histogram waves (see
-                    # _grow_class_traced) — no [N] gradient buffers in
-                    # this program at all
-                    grad_all = hess_all = (None,)
-                    if sentinel:
-                        # NaN/Inf sentinel operands: the same pointwise
-                        # formula the grower evaluates — XLA CSEs the
-                        # two, so the fused path stays fused
-                        with jax.named_scope("lgbm/gradient"):
-                            sen_g, sen_h = self._fused_grad_fn(
-                                scores[0], obj.label, obj.weight)
-                else:
-                    grad_all, hess_all = self._grad_fn(scores)
-                    if sentinel:
-                        sen_g, sen_h = grad_all, hess_all
+                  valid_scores, *rest):
+            hist, drop = rest[:self._n_hist], rest[self._n_hist:-2]
+            it, lr = rest[-2:]
+            with self._traced_obj_state(obj_state):
+                key, sample_mask, grad_all, hess_all, sen, carry = \
+                    self._iter_head(scores, sample_mask, it, lr, hist, drop)
                 recs = []
-                new_valid = list(valid_scores)
                 for k in range(self.num_tree_per_iteration):
-                    rec, row_leaf = self._grow_class_traced(
-                        grow, bins_fm, k, key, grad_all[k], hess_all[k],
-                        sample_mask, scores[k], it)
-                    with jax.named_scope("lgbm/score"):
-                        # 1-leaf trees contribute nothing (the reference
-                        # stops training instead, gbdt.cpp
-                        # should_continue)
-                        leaf_vals = jnp.where(rec.num_leaves > 1,
-                                              rec.leaf_value * lr, 0.0)
-                        scores = scores.at[k].add(leaf_vals[row_leaf])
-                    for vi in range(len(valid_bins)):
-                        with jax.named_scope("lgbm/valid"):
-                            vleaf = replay_tree(
-                                rec, valid_bins[vi], self.feature_meta,
-                                self._bundle,
-                                num_data=self._valid_sets[vi][0].num_data)
-                            new_valid[vi] = new_valid[vi].at[k].add(
-                                leaf_vals[vleaf])
+                    grad, hess, scores_k = (
+                        grad_all[k], hess_all[k],
+                        self._grad_scores(scores, carry)[k])
+                    mask, grad, hess, true_grad, true_hess, quant, fmask = \
+                        self._class_sample(k, key, grad, hess, sample_mask)
+                    rec, row_leaf = self._class_grow(
+                        grow, bins_fm, k, it, grad, hess, mask, fmask,
+                        quant, scores_k)
+                    rec, scores, valid_scores, hist = self._class_tail(
+                        k, rec, row_leaf, scores, scores_k, valid_scores,
+                        valid_bins, mask, true_grad, true_hess, lr, carry,
+                        hist)
                     recs.append(rec)
-                stacked = _stack_class_records(recs)
-                # updated objective state: objectives that evolve device
-                # state across iterations (e.g. lambdarank position
-                # biases) assign tracers to their attributes during the
-                # trace; collecting the state here returns the updates
-                # as program outputs instead of losing them at restore.
-                # Evolving subset only — returning the full state would
-                # copy every constant [N] label/weight buffer per iter
-                out_state = (obj.device_state(evolving_only=True)
-                             if obj is not None
-                             else {"arrays": {}, "sub": {}})
-                if sentinel:
+                state = self._iter_finish(carry, drop)
+                outs = (scores, sample_mask, tuple(valid_scores),
+                        _stack_class_records(recs),
+                        self._evolved_obj_state(), *hist, *state)
+                if self._health_armed:
                     # pure reductions as an EXTRA output: the training
                     # math is untouched, so models are bit-identical
                     # with the sentinel on vs off (tests assert)
-                    return (scores, sample_mask, tuple(new_valid),
-                            stacked, out_state,
-                            _nonfinite_counts(sen_g, sen_h, scores))
-                return (scores, sample_mask, tuple(new_valid), stacked,
-                        out_state)
-            finally:
-                if obj is not None:
-                    obj.swap_device_state(old_state)
+                    outs += (_nonfinite_counts(*sen, scores),)
+                return outs
 
-        return obs_xla.instrumented_jit("boosting/fused_iter", fused,
-                                        phase="train",
-                                        donate_argnums=(3, 4, 5))
+        return obs_xla.instrumented_jit(
+            "boosting/" + self._tags["fused"], fused, phase="train",
+            donate_argnums=self._fused_donate)
 
     # ------------------------------------------------------------------
-    # streamed path (tpu_stream): the fused program's math, split at
-    # materialization boundaries so the grower can be host-orchestrated
-    # over HostSlabBins slabs. Same RNG folds, same traced expressions
-    # (each kept whole within one program so XLA's FMA-contraction
-    # choices can't diverge) => models bit-identical to the resident
-    # fused path whenever the slab accumulation itself is exact
-    # (single slab, or int8-quantized histograms at any slab count).
+    # streamed composition (tpu_stream, several slabs): the stages as
+    # programs of their own around the host-orchestrated slab grower
     def _make_stream_grower(self, hist_impl: str):
         from .learner import StreamTreeGrower
         mesh = self._stream.mesh
@@ -1165,121 +1324,65 @@ class GBDT:
         return prog
 
     def _make_stream_prep(self):
-        """Head of the streamed iteration: bagging + gradients — the
-        same RNG folds and expressions as the fused program's head."""
-        def prep(obj_state, scores, sample_mask, it):
-            obj = self.objective
-            old = obj.swap_device_state(obj_state) if obj is not None \
-                else None
-            try:
-                key = jax.random.fold_in(self._bagging_key, it)
-                sample_mask = self._sampling_in_jit(
-                    jax.random.fold_in(key, 1), it, sample_mask)
-                grad_all, hess_all = self._grad_fn(scores)
-                out_state = (obj.device_state(evolving_only=True)
-                             if obj is not None
-                             else {"arrays": {}, "sub": {}})
-                return sample_mask, grad_all, hess_all, out_state
-            finally:
-                if obj is not None:
-                    obj.swap_device_state(old)
+        """The head as a program."""
+        def prep(obj_state, scores, sample_mask, it, lr, hist, drop):
+            with self._traced_obj_state(obj_state):
+                _key, sample_mask, grad_all, hess_all, _sen, carry = \
+                    self._iter_head(scores, sample_mask, it, lr, hist, drop)
+                return (sample_mask, grad_all, hess_all,
+                        self._evolved_obj_state(), carry)
         return prep
 
-    def _make_stream_class_prep(self, k: int):
-        """Per-class sampling/quantization + the grower's resident
-        operands: the pre-masked ghT histogram operand (int8 when the
-        int8 wave path applies, f32 otherwise), its dequantization
-        vector, and the feature mask. Identical RNG
-        salts to _grow_class_traced."""
-        use_int8 = (self._quant_enabled and
-                    int(self.config.num_grad_quant_bins) <= 126)
+    @jax.named_scope("lgbm/gradient")
+    def _stream_operands(self, grad, hess, mask, quant):
+        """The slab grower's resident operands: the pre-masked ghT
+        histogram operand (int8 when the int8 wave path applies, f32
+        otherwise) and its dequantization vector."""
+        f32 = jnp.float32
+        if self._use_int8_hist():
+            g_int, h_int, g_scale, h_scale = quant
+            m8 = mask.astype(jnp.int8)
+            ghT = jnp.stack([g_int.astype(jnp.int8) * m8,
+                             h_int.astype(jnp.int8) * m8, m8], axis=1)
+            return ghT, jnp.stack([g_scale, h_scale,
+                                   jnp.float32(1.0)]).astype(f32)
+        ghT = jnp.stack([grad * mask, hess * mask, mask],
+                        axis=1).astype(f32)
+        return ghT, jnp.ones((3,), f32)
 
+    def _make_stream_class_prep(self, k: int):
+        """Class k's sample stage as a program, with the grower's
+        operands."""
         def class_prep(grad, hess, sample_mask, it):
             key = jax.random.fold_in(self._bagging_key, it)
-            mask = sample_mask
-            if self.config.data_sample_strategy == "goss":
-                mask, scale = self._goss_in_jit(
-                    jax.random.fold_in(key, 100 + k), grad, hess)
-                grad, hess = grad * scale, hess * scale
-            true_grad, true_hess = grad, hess
-            quant = None
-            if self._quant_enabled:
-                grad, hess, quant = self._discretize_in_jit(
-                    jax.random.fold_in(key, 300 + k), grad, hess)
-            fmask = self._feature_mask_in_jit(
-                jax.random.fold_in(key, 200 + k))
-            f32 = jnp.float32
-            with jax.named_scope("lgbm/gradient"):
-                if use_int8:
-                    g_int, h_int, g_scale, h_scale = quant
-                    m8 = mask.astype(jnp.int8)
-                    ghT = jnp.stack([g_int.astype(jnp.int8) * m8,
-                                     h_int.astype(jnp.int8) * m8, m8],
-                                    axis=1)
-                    hscale = jnp.stack([g_scale, h_scale,
-                                        jnp.float32(1.0)]).astype(f32)
-                else:
-                    ghT = jnp.stack([grad * mask, hess * mask, mask],
-                                    axis=1).astype(f32)
-                    hscale = jnp.ones((3,), f32)
+            mask, grad, hess, true_grad, true_hess, quant, fmask = \
+                self._class_sample(k, key, grad, hess, sample_mask)
+            ghT, hscale = self._stream_operands(grad, hess, mask, quant)
             return (ghT, hscale, fmask, true_grad, true_hess, mask)
         return class_prep
 
     def _make_stream_class_post(self, k: int):
-        """Leaf renewal + score/valid updates for one grown class —
-        the tail of the fused loop body, kept in ONE program so the
-        multiply-gather-add keeps the fused path's FMA shape."""
+        """Class k's tail as a program."""
         def class_post(obj_state, rec, row_leaf, scores, valid_scores,
-                       valid_bins, mask, true_grad, true_hess, lr):
-            obj = self.objective
-            old = obj.swap_device_state(obj_state) if obj is not None \
-                else None
-            try:
-                if self._quant_enabled and \
-                        self.config.quant_train_renew_leaf:
-                    rec = self._renew_leaves_in_jit(
-                        rec, row_leaf, true_grad, true_hess, mask)
-                if obj is not None:
-                    with jax.named_scope("lgbm/renew"):
-                        renewed_lv = obj.renew_leaves_traced(
-                            rec.leaf_value, row_leaf, scores[k], mask)
-                        if renewed_lv is not None:
-                            rec = rec._replace(leaf_value=jnp.where(
-                                rec.num_leaves > 1, renewed_lv,
-                                rec.leaf_value))
-                with jax.named_scope("lgbm/score"):
-                    leaf_vals = jnp.where(rec.num_leaves > 1,
-                                          rec.leaf_value * lr, 0.0)
-                    scores = scores.at[k].add(leaf_vals[row_leaf])
-                new_valid = list(valid_scores)
-                for vi in range(len(valid_bins)):
-                    with jax.named_scope("lgbm/valid"):
-                        vleaf = replay_tree(
-                            rec, valid_bins[vi], self.feature_meta,
-                            self._bundle,
-                            num_data=self._valid_sets[vi][0].num_data)
-                        new_valid[vi] = new_valid[vi].at[k].add(
-                            leaf_vals[vleaf])
-                return rec, scores, tuple(new_valid)
-            finally:
-                if obj is not None:
-                    obj.swap_device_state(old)
+                       valid_bins, mask, true_grad, true_hess, lr, carry,
+                       hist):
+            with self._traced_obj_state(obj_state):
+                return self._class_tail(
+                    k, rec, row_leaf, scores,
+                    self._grad_scores(scores, carry)[k], valid_scores,
+                    valid_bins, mask, true_grad, true_hess, lr, carry, hist)
         return class_post
 
     def _stream_grow_class(self, k: int, grad_k, hess_k, sample_mask, it):
-        """Shared per-class streamed growth (fast twin + DART twin):
-        class prep program -> host-orchestrated slab grower."""
+        """Stage 2 of the streamed composition: class prep program ->
+        host-orchestrated slab grower."""
         cp = self._stream_prog(f"class_prep_{k}",
                                lambda: self._make_stream_class_prep(k))
         ghT, hscale, fmask, true_grad, true_hess, mask = cp(
             grad_k, hess_k, sample_mask, it)
-        node_key = (jax.random.fold_in(
-            self._extra_key,
-            self.iter * self.num_tree_per_iteration + k)
-            if self._use_node_rand else None)
         rec, row_leaf = self._stream_grower.grow(
             ghT, hscale, fmask, self.feature_meta, self.hp,
-            self.max_depth, node_key)
+            self.max_depth, self._node_key(k, self.iter))
         return rec, row_leaf, mask, true_grad, true_hess
 
     def _stream_grow_slow(self, bins_fm, grad, hess, mask, feature_mask,
@@ -1301,149 +1404,85 @@ class GBDT:
             prep(grad, hess, mask), jnp.ones((3,), jnp.float32),
             feature_mask, meta, hp, max_depth, node_key)
 
-    def _note_stream_meta(self) -> None:
-        """Publish the streaming pipeline accounting (always-on meta ->
-        bench JSON `stream` field + lgbmtpu_stream_* OpenMetrics)."""
-        from .io.streaming import global_stream_stats
-        plan = self._stream
-        global_metrics.set_meta("stream", {
-            **global_stream_stats.summary(),
-            "slab_rows": int(plan.slab_rows),
-            "n_slabs": int(plan.n_slabs),
-            "num_data": int(plan.num_data),
-            "host_bytes": int(plan.nbytes_host),
-        })
-
-    def _stream_take_bins(self):
-        """Single-slab streaming: the staged device copy of the whole
-        (packed) bin matrix. Uploaded once and cached — the bins are
-        immutable, so re-staging identical bytes every iteration would
-        only waste link bandwidth, and holding the one copy is exactly
-        the memory the model budgeted for the slab pair. The plan
-        degenerates to resident behavior with an explicit upload, which
-        is what makes single-slab streamed models bit-identical."""
-        dev = self._stream_next_bins
-        if dev is None:
-            dev = self._stream_next_bins = self._stream.stage_noted(0)
-        return dev
-
-    def _stream_prefetch_bins(self) -> None:
-        """Called right after the fused program dispatches (async):
-        bookkeeping hook of the cross-iteration pipeline (the cached
-        single-slab upload needs no re-stage; multi-slab plans overlap
-        via HostSlabBins.feed instead)."""
-        self._stream.stats.note_dispatch()
-
-    def _train_one_iter_stream(self) -> bool:
-        """Streamed iteration dispatch. A single-slab plan (the whole
-        matrix fits the streaming budget — every fits-in-HBM fixture)
-        runs the SAME fused XLA program as resident training on a
-        staged-once upload of the bins: bit-identical models by
-        construction. Multi-slab plans run the host-orchestrated slab
-        grower (bit-identical to the resident host/slow path; int8
-        histograms stay bit-identical at any slab count)."""
-        if self._stream.n_slabs == 1:
-            return self._train_one_iter_fused_upload()
-        return self._train_one_iter_stream_orchestrated()
-
-    def _train_one_iter_fused_upload(self) -> bool:
-        import time as _time
-        from .io.streaming import global_stream_stats as _stats
-        self._boost_from_average()
-        if self._fused is None:
-            self._fused = self._make_fused()
-        bins = self._stream_take_bins()
-        with global_tracer.span("train/iteration",
-                                block=lambda: self.scores):
-            out = self._fused(
-                bins, tuple(self._valid_bins), self._obj_state(),
-                self.scores, self._sample_mask, tuple(self._valid_scores),
-                jnp.int32(self.iter), jnp.float32(self.shrinkage_rate))
-            self._stream_prefetch_bins()
-            if self._health_armed:
-                (self.scores, self._sample_mask, valid, recs,
-                 new_obj_state, self._health_vec) = out
-            else:
-                (self.scores, self._sample_mask, valid, recs,
-                 new_obj_state) = out
-            t0 = _time.perf_counter()
-            jax.block_until_ready(self.scores)
-            _stats.note_block(_time.perf_counter() - t0)
+    def _train_one_iter_stream_orchestrated(self, hist, drop, it, lr):
+        """The streamed composition, host-orchestrated (bit-identical to
+        the resident host/slow path; int8 histograms stay bit-identical
+        at any slab count). Returns what the fused program returns past
+        the valid scores, less the objective state."""
+        prep = self._stream_prog(self._tags["prep"], self._make_stream_prep)
+        self._sample_mask, grad_all, hess_all, new_obj_state, carry = prep(
+            self._obj_state(), self.scores, self._sample_mask, it, lr, hist,
+            drop)
         if self.objective is not None:
             self.objective.swap_device_state(new_obj_state)
-        self._valid_scores = list(valid)
-        self._device_records.append(recs)
-        self._record_lrs.append(self.shrinkage_rate)
-        _stats.iterations_total += 1
-        self._note_stream_meta()
-        self.iter += 1
-        return False
+        recs = []
+        for k in range(self.num_tree_per_iteration):
+            rec, row_leaf, mask, true_g, true_h = self._stream_grow_class(
+                k, grad_all[k], hess_all[k], self._sample_mask, it)
+            post = self._stream_prog(
+                f"{self._tags['post']}_{k}",
+                lambda k=k: self._make_stream_class_post(k))
+            rec, self.scores, valid, hist = post(
+                self._obj_state(), rec, row_leaf, self.scores,
+                tuple(self._valid_scores), tuple(self._valid_bins), mask,
+                true_g, true_h, lr, carry, hist)
+            self._valid_scores = list(valid)
+            recs.append(rec)
+        state = ()
+        if drop:
+            state = self._stream_prog(
+                self._tags["finish"], lambda: self._iter_finish)(carry, drop)
+        if self._health_armed:
+            sen = self._stream_prog("sentinel", lambda: _nonfinite_counts)
+            self._health_vec = sen(grad_all, hess_all, self.scores)
+        return (_stack_class_records(recs), *hist, *state)
 
-    def _train_one_iter_stream_orchestrated(self) -> bool:
-        import time as _time
-        self._boost_from_average()
-        from .io.streaming import global_stream_stats as _stats
-        prep = self._stream_prog("prep", self._make_stream_prep)
-        with global_tracer.span("train/iteration",
-                                block=lambda: self.scores):
-            it = jnp.int32(self.iter)
-            lr = jnp.float32(self.shrinkage_rate)
-            sample_mask, grad_all, hess_all, new_obj_state = prep(
-                self._obj_state(), self.scores, self._sample_mask, it)
-            self._sample_mask = sample_mask
-            if self.objective is not None:
-                self.objective.swap_device_state(new_obj_state)
-            recs = []
-            for k in range(self.num_tree_per_iteration):
-                rec, row_leaf, mask, true_g, true_h = \
-                    self._stream_grow_class(k, grad_all[k], hess_all[k],
-                                            sample_mask, it)
-                post = self._stream_prog(
-                    f"class_post_{k}",
-                    lambda k=k: self._make_stream_class_post(k))
-                rec, self.scores, valid = post(
-                    self._obj_state(), rec, row_leaf, self.scores,
-                    tuple(self._valid_scores), tuple(self._valid_bins),
-                    mask, true_g, true_h, lr)
-                self._valid_scores = list(valid)
-                recs.append(rec)
-            if self._health_armed:
-                sen = self._stream_prog(
-                    "sentinel", lambda: _nonfinite_counts)
-                self._health_vec = sen(grad_all, hess_all, self.scores)
-            t0 = _time.perf_counter()
-            jax.block_until_ready(self.scores)
-            _stats.note_block(_time.perf_counter() - t0)
-        _stats.iterations_total += 1
-        self._device_records.append(_stack_class_records(recs))
+    # ------------------------------------------------------------------
+    # the host driver of both compositions
+    def _begin_iteration(self):
+        """Host work before the iteration's programs. Returns (what
+        _end_iteration takes, hist, drop, lr)."""
+        return None, (), (), jnp.float32(self.shrinkage_rate)
+
+    def _end_iteration(self, plan, state) -> None:
+        """Host bookkeeping after the iteration's programs; `state` is
+        (*hist, *_iter_finish's) as the programs left it."""
         self._record_lrs.append(self.shrinkage_rate)
-        self._note_stream_meta()
-        self.iter += 1
-        return False
 
     def _train_one_iter_fast(self) -> bool:
-        if self._stream is not None:
-            return self._train_one_iter_stream()
+        """One iteration as device programs, no host round-trip inside.
+        The bins come from the feed: resident training and a single-slab
+        streamed plan (the whole matrix fits the streaming budget — every
+        fits-in-HBM fixture) run the SAME fused program, the second on a
+        staged-once upload: bit-identical models by construction;
+        multi-slab plans run the streamed composition."""
         self._boost_from_average()
-        if self._fused is None:
-            self._fused = self._make_fused()
+        plan, hist, drop, lr = self._begin_iteration()
+        it = jnp.int32(self.iter)
+        feed = self._feed
         with global_tracer.span("train/iteration",
                                 block=lambda: self.scores):
-            out = self._fused(
-                self.bins_fm, tuple(self._valid_bins), self._obj_state(),
-                self.scores, self._sample_mask, tuple(self._valid_scores),
-                jnp.int32(self.iter), jnp.float32(self.shrinkage_rate))
-            if self._health_armed:
-                (self.scores, self._sample_mask, valid, recs,
-                 new_obj_state, self._health_vec) = out
+            if feed.orchestrated:
+                recs, *state = self._train_one_iter_stream_orchestrated(
+                    hist, drop, it, lr)
             else:
+                if self._fused is None:
+                    self._fused = self._make_fused()
+                out = self._fused(
+                    feed.take(self.bins_fm), tuple(self._valid_bins),
+                    self._obj_state(), self.scores, self._sample_mask,
+                    tuple(self._valid_scores), *hist, *drop, it, lr)
+                feed.dispatched()
+                if self._health_armed:
+                    out, self._health_vec = out[:-1], out[-1]
                 (self.scores, self._sample_mask, valid, recs,
-                 new_obj_state) = out
-        if self.objective is not None:
-            self.objective.swap_device_state(new_obj_state)
-        self._valid_scores = list(valid)
+                 new_obj_state, *state) = out
+                if self.objective is not None:
+                    self.objective.swap_device_state(new_obj_state)
+                self._valid_scores = list(valid)
+            feed.done(self.scores)
         self._device_records.append(recs)
-        self._record_lrs.append(self.shrinkage_rate)
+        self._end_iteration(plan, state)
         self.iter += 1
         return False
 
@@ -1504,26 +1543,10 @@ class GBDT:
                 (u < cfg.bagging_fraction).astype(jnp.float32), 0.0)
 
     def _goss_mask(self, grad, hess):
-        """GOSS: keep top_rate by |g*h|, sample other_rate of the rest and
-        amplify them (ref: goss.hpp:60-131)."""
-        cfg = self.config
-        top_rate, other_rate = cfg.top_rate, cfg.other_rate
-        n = self.num_data
-        top_k = max(1, int(n * top_rate))
-        other_k = max(1, int(n * other_rate))
-        score = jnp.abs(grad) * jnp.abs(hess)
-        if self._row_pad:
-            score = jnp.where(self._valid_rows(score.shape[0]), score, -1.0)
-        thr = -jnp.sort(-score)[top_k - 1]
-        is_top = score >= thr
-        key = jax.random.fold_in(self._bagging_key, self.iter + (1 << 20))
-        u = self._pad_tail(jax.random.uniform(key, (n,)), 2.0)
-        keep_rest_p = other_k / max(n - top_k, 1)
-        is_other = (~is_top) & (u < keep_rest_p)
-        amplify = (1.0 - top_rate) / other_rate
-        mask = (is_top | is_other).astype(jnp.float32)
-        scale = jnp.where(is_other, amplify, 1.0)
-        return mask, scale
+        """GOSS on the host loop's own key (ref: goss.hpp:60-131)."""
+        return self._goss_in_jit(
+            jax.random.fold_in(self._bagging_key, self.iter + (1 << 20)),
+            grad, hess)
 
     def _feature_mask(self):
         cfg = self.config
@@ -1576,11 +1599,7 @@ class GBDT:
                 pad = ((0, 0), (0, self._row_pad))
                 g, h = jnp.pad(g, pad), jnp.pad(h, pad)
             return g, h
-        obj = self.objective
-        if hasattr(obj, "get_gradients_multi"):
-            return obj.get_gradients_multi(self.scores)
-        g, h = obj.get_gradients(self.scores[0])
-        return g[None, :], h[None, :]
+        return self._grad_fn(self.scores)
 
     # ------------------------------------------------------------------
     def train_one_iter(self, custom_grad=None, custom_hess=None) -> bool:
@@ -1892,13 +1911,7 @@ class GBDT:
             # accounting as the fast twins — and the end-of-iteration
             # sync resets the overlap classifier's in-flight count so a
             # later pipeline can't inherit stale dispatches
-            import time as _time
-            from .io.streaming import global_stream_stats as _stats
-            t0 = _time.perf_counter()
-            jax.block_until_ready(self.scores)
-            _stats.note_block(_time.perf_counter() - t0)
-            _stats.iterations_total += 1
-            self._note_stream_meta()
+            self._feed.done(self.scores)
         self.iter += 1
         return False
 
@@ -2297,7 +2310,7 @@ class DART(GBDT):
         self._dart_t = 0             # fused iterations stored
         self._dart_base = 0          # _host_models index of first fused iter
         self._dart_unshrunk: List[dict] = []  # host unshrunk records
-        self._dart_fused = None      # jitted program
+        self._init_vec = None        # [K] init scores, a trace constant
         self._dart_fast_disabled = False
         self._cur_shrinkage = float(config.learning_rate)
         self._dart_update_score = None  # see _slow_score_update
@@ -2361,6 +2374,9 @@ class DART(GBDT):
                 else jnp.uint16 if leaves <= 65536 else jnp.int32)
 
     def _ensure_dart_state(self) -> None:
+        if self._init_vec is None:
+            self._init_vec = jnp.asarray(
+                np.asarray(self.init_scores, np.float32))
         k = self.num_tree_per_iteration
         leaves = self._static["num_leaves"]
         dt = self._dart_hist_dtype()
@@ -2416,414 +2432,135 @@ class DART(GBDT):
             self._sum_tree_weight -= self._tree_weights[s] * sub
             self._tree_weights[s] *= old_factor
 
-    def _make_fused_dart(self):
-        """One-XLA-program DART iteration. Drop selection happens on the
-        host from host-held tree weights (no device data involved), the
-        dropped trees' score contributions are recomputed on device by
-        indexing the leaf-assignment history, and normalization
-        (dart.hpp:159) becomes a per-tree factor buffer update — the
-        model's trees materialize later as unshrunk records x factors."""
-        grow = self._grow_partial()
-        xgb_mode = bool(self.config.xgboost_dart_mode)
-        k_per = self.num_tree_per_iteration
-        sentinel = self._health_armed
+    # -- the DART iteration: GBDT's compositions over a head extended by
+    # the drop, score rules of its own and a finish. Drop selection
+    # happens on the host from host-held tree weights (no device data
+    # involved), the dropped trees' score contributions are recomputed on
+    # device by indexing the leaf-assignment history, and normalization
+    # (dart.hpp:159) becomes a per-tree factor buffer update — the
+    # model's trees materialize later as unshrunk records x factors.
+    # hist = (leaf_hist, vhists, leaf_vals), drop = (factors, dropped,
+    # n_drop, t_cur).
+    _tags = {"fused": "fused_dart_iter", "prep": "dart_prep",
+             "post": "dart_post", "finish": "dart_factors"}
+    _n_hist = 3
+    _fused_donate = (3, 4, 5, 6, 7, 8, 9)
 
-        # the reference bakes the boost-from-average bias into the first
-        # tree AFTER its score update (gbdt.cpp:426 AddBias), so dropped
-        # first trees carry the bias and later normalizations scale it.
-        # The history buffer therefore stores lv + bias/creation_factor
-        # for iteration 0: factor[t] * buffer then reproduces the
-        # reference's current leaf values at every later point in time.
-        with_bias = self._dart_base == 0 and any(
-            abs(s) > K_EPSILON for s in self.init_scores)
-        init_vec = jnp.asarray(np.asarray(self.init_scores, np.float32))
+    def _iter_head(self, scores, sample_mask, it, lr, hist, drop):
+        """GBDT's head with the dropped trees taken out of the scores
+        before the gradients."""
+        leaf_hist, vhists, leaf_vals = hist
+        factors, dropped, n_drop, t_cur = drop
+        key, sample_mask = self._iter_bagging(sample_mask, it)
+        live = dropped >= 0                      # [D]
+        d_gather = jnp.where(live, dropped, 0)
+        d_scatter = jnp.where(live, dropped, factors.shape[0])  # OOB = no-op
+        fac_d = factors[d_gather] * live.astype(jnp.float32)
 
-        def fused(bins_fm, valid_bins, obj_state, scores, sample_mask,
-                  valid_scores, leaf_hist, vhists, leaf_vals, factors,
-                  dropped, n_drop, t_cur, it, lr):
-            obj = self.objective
-            old_state = obj.swap_device_state(obj_state)
-            try:
-                t_max = leaf_hist.shape[0]
-                key = jax.random.fold_in(self._bagging_key, it)
-                sample_mask = self._sampling_in_jit(
-                    jax.random.fold_in(key, 1), it, sample_mask)
+        def drop_delta(leaves, vals):
+            h = jnp.take(leaves, d_gather, axis=0).astype(jnp.int32)
+            v = jnp.take(vals, d_gather, axis=0) * fac_d[:, None, None]
+            return jnp.take_along_axis(v, h, axis=2).sum(axis=0)
 
-                live = dropped >= 0                      # [D]
-                d_gather = jnp.where(live, dropped, 0)
-                d_scatter = jnp.where(live, dropped, t_max)  # OOB = no-op
-                fac_d = factors[d_gather] * live.astype(jnp.float32)
+        with jax.named_scope("lgbm/score/drop"):
+            delta = drop_delta(leaf_hist, leaf_vals)  # [K, N]
+            scores_adj = scores - delta
+        with jax.named_scope("lgbm/valid/drop"):
+            deltas_v = tuple(drop_delta(vh, leaf_vals) for vh in vhists)
+        grad_all, hess_all = self._grad_fn(scores_adj)
+        kd = n_drop.astype(jnp.float32)
+        if self.config.xgboost_dart_mode:
+            new_factor = jnp.where(n_drop > 0, lr / (lr + kd), lr)
+            old_factor = kd / (kd + lr)
+        else:
+            new_factor = lr / (1.0 + kd)
+            old_factor = kd / (kd + 1.0)
+        carry = dict(scores_adj=scores_adj, delta=delta, deltas_v=deltas_v,
+                     new_factor=new_factor, old_factor=old_factor,
+                     d_scatter=d_scatter, t_cur=t_cur)
+        return (key, sample_mask, grad_all, hess_all,
+                (grad_all, hess_all), carry)
 
-                def drop_delta(hist, vals):
-                    h = jnp.take(hist, d_gather, axis=0).astype(jnp.int32)
-                    v = jnp.take(vals, d_gather, axis=0) * \
-                        fac_d[:, None, None]
-                    return jnp.take_along_axis(v, h, axis=2).sum(axis=0)
+    def _grad_scores(self, scores, carry):
+        return carry["scores_adj"]
 
-                with jax.named_scope("lgbm/score/drop"):
-                    delta = drop_delta(leaf_hist, leaf_vals)  # [K, N]
-                    scores_adj = scores - delta
-                with jax.named_scope("lgbm/valid/drop"):
-                    deltas_v = [drop_delta(vhists[vi], leaf_vals)
-                                for vi in range(len(valid_bins))]
-                grad_all, hess_all = self._grad_fn(scores_adj)
+    def _score_rule(self, k, rec, row_leaf, scores, lr, carry, hist):
+        leaf_hist, vhists, leaf_vals = hist
+        t_cur, new_factor = carry["t_cur"], carry["new_factor"]
+        with jax.named_scope("lgbm/score"):
+            lv = jnp.where(rec.num_leaves > 1, rec.leaf_value, 0.0)
+            scores = scores.at[k].set(
+                carry["scores_adj"][k]
+                + carry["old_factor"] * carry["delta"][k]
+                + new_factor * lv[row_leaf])
+            leaf_hist = leaf_hist.at[t_cur, k].set(
+                row_leaf.astype(leaf_hist.dtype))
+            lv_store = lv
+            # the reference bakes the boost-from-average bias into the
+            # first tree AFTER its score update (gbdt.cpp:426 AddBias),
+            # so dropped first trees carry the bias and later
+            # normalizations scale it. The history buffer therefore
+            # stores lv + bias/creation_factor for iteration 0:
+            # factor[t] * buffer then reproduces the reference's current
+            # leaf values at every later point in time.
+            if self._dart_base == 0 and any(
+                    abs(s) > K_EPSILON for s in self.init_scores):
+                # bias applies to 1-LEAF first-iteration trees too: the
+                # reference's constant tree carries leaf_value == init
+                # (AsConstantTree), and a drop must subtract it — a
+                # class with (near-) empty data keeps a 1-leaf tree
+                # whose bias the history would otherwise lose
+                # (multiclass DART parity, tests/test_engine.py)
+                lv_store = lv + jnp.where(
+                    t_cur == 0, self._init_vec[k] / new_factor, 0.0)
+            leaf_vals = leaf_vals.at[t_cur, k].set(lv_store)
+        return scores, lv, (leaf_hist, vhists, leaf_vals)
 
-                kd = n_drop.astype(jnp.float32)
-                if xgb_mode:
-                    new_factor = jnp.where(n_drop > 0, lr / (lr + kd), lr)
-                    old_factor = kd / (kd + lr)
-                else:
-                    new_factor = lr / (1.0 + kd)
-                    old_factor = kd / (kd + 1.0)
+    def _valid_score_rule(self, k, vi, valid, leaf_vals, vleaf, carry,
+                          hist):
+        valid = valid.at[k].set(
+            valid[k] - (1.0 - carry["old_factor"]) * carry["deltas_v"][vi][k]
+            + carry["new_factor"] * leaf_vals[vleaf])
+        leaf_hist, vhists, leaf_vals_hist = hist
+        vhists = list(vhists)
+        vhists[vi] = vhists[vi].at[carry["t_cur"], k].set(
+            vleaf.astype(vhists[vi].dtype))
+        return valid, (leaf_hist, tuple(vhists), leaf_vals_hist)
 
-                hd = leaf_hist.dtype
-                recs = []
-                new_valid = list(valid_scores)
-                new_vhists = list(vhists)
-                for k in range(k_per):
-                    rec, row_leaf = self._grow_class_traced(
-                        grow, bins_fm, k, key, grad_all[k], hess_all[k],
-                        sample_mask, scores_adj[k], it)
-                    with jax.named_scope("lgbm/score"):
-                        lv = jnp.where(rec.num_leaves > 1,
-                                       rec.leaf_value, 0.0)
-                        scores = scores.at[k].set(
-                            scores_adj[k] + old_factor * delta[k]
-                            + new_factor * lv[row_leaf])
-                        leaf_hist = leaf_hist.at[t_cur, k].set(
-                            row_leaf.astype(hd))
-                        lv_store = lv
-                        if with_bias:
-                            # bias applies to 1-LEAF first-iteration
-                            # trees too: the reference's constant tree
-                            # carries leaf_value == init
-                            # (AsConstantTree), and a drop must
-                            # subtract it — a class with (near-) empty
-                            # data keeps a 1-leaf tree whose bias the
-                            # history would otherwise lose (multiclass
-                            # DART parity, tests/test_engine.py)
-                            lv_store = lv + jnp.where(
-                                t_cur == 0, init_vec[k] / new_factor,
-                                0.0)
-                        leaf_vals = leaf_vals.at[t_cur, k].set(lv_store)
-                    for vi in range(len(valid_bins)):
-                        with jax.named_scope("lgbm/valid"):
-                            vleaf = replay_tree(
-                                rec, valid_bins[vi], self.feature_meta,
-                                self._bundle,
-                                num_data=self._valid_sets[vi][0].num_data)
-                            new_valid[vi] = new_valid[vi].at[k].set(
-                                new_valid[vi][k]
-                                - (1.0 - old_factor) * deltas_v[vi][k]
-                                + new_factor * lv[vleaf])
-                            new_vhists[vi] = \
-                                new_vhists[vi].at[t_cur, k].set(
-                                    vleaf.astype(hd))
-                    recs.append(rec)
-                with jax.named_scope("lgbm/score"):
-                    factors = factors.at[d_scatter].multiply(old_factor)
-                    factors = factors.at[t_cur].set(new_factor)
-                stacked = _stack_class_records(recs)
-                out_state = obj.device_state(evolving_only=True)
-                outs = (scores, sample_mask, tuple(new_valid), stacked,
-                        out_state, leaf_hist, tuple(new_vhists), leaf_vals,
-                        factors)
-                if sentinel:  # see _make_fused: pure extra reductions
-                    outs = outs + (_nonfinite_counts(
-                        grad_all, hess_all, scores),)
-                return outs
-            finally:
-                obj.swap_device_state(old_state)
+    @jax.named_scope("lgbm/score")
+    def _iter_finish(self, carry, drop):
+        """Normalize as a factor-buffer update: the dropped trees scaled
+        by old_factor, the new tree entered at new_factor."""
+        factors = drop[0].at[carry["d_scatter"]].multiply(
+            carry["old_factor"])
+        return (factors.at[carry["t_cur"]].set(carry["new_factor"]),)
 
-        return obs_xla.instrumented_jit("boosting/fused_dart_iter", fused,
-                                        phase="train",
-                                        donate_argnums=(3, 4, 5, 6, 7, 8, 9))
-
-    # -- streamed DART twin (tpu_stream): _make_fused_dart's math split
-    # at the same materialization boundaries as the GBDT streamed path
-    def _make_stream_dart_prep(self):
-        xgb_mode = bool(self.config.xgboost_dart_mode)
-        n_valid = len(self._valid_sets)
-
-        def prep(obj_state, scores, sample_mask, leaf_hist, vhists,
-                 leaf_vals, factors, dropped, n_drop, it, lr):
-            obj = self.objective
-            old = obj.swap_device_state(obj_state)
-            try:
-                key = jax.random.fold_in(self._bagging_key, it)
-                sample_mask = self._sampling_in_jit(
-                    jax.random.fold_in(key, 1), it, sample_mask)
-                live = dropped >= 0
-                d_gather = jnp.where(live, dropped, 0)
-                fac_d = factors[d_gather] * live.astype(jnp.float32)
-
-                def drop_delta(hist, vals):
-                    h = jnp.take(hist, d_gather, axis=0).astype(jnp.int32)
-                    v = jnp.take(vals, d_gather, axis=0) * \
-                        fac_d[:, None, None]
-                    return jnp.take_along_axis(v, h, axis=2).sum(axis=0)
-
-                with jax.named_scope("lgbm/score/drop"):
-                    delta = drop_delta(leaf_hist, leaf_vals)
-                    scores_adj = scores - delta
-                with jax.named_scope("lgbm/valid/drop"):
-                    deltas_v = tuple(drop_delta(vhists[vi], leaf_vals)
-                                     for vi in range(n_valid))
-                grad_all, hess_all = self._grad_fn(scores_adj)
-                kd = n_drop.astype(jnp.float32)
-                if xgb_mode:
-                    new_factor = jnp.where(n_drop > 0, lr / (lr + kd), lr)
-                    old_factor = kd / (kd + lr)
-                else:
-                    new_factor = lr / (1.0 + kd)
-                    old_factor = kd / (kd + 1.0)
-                out_state = obj.device_state(evolving_only=True)
-                return (sample_mask, scores_adj, delta, deltas_v,
-                        grad_all, hess_all, new_factor, old_factor,
-                        out_state)
-            finally:
-                obj.swap_device_state(old)
-        return prep
-
-    def _make_stream_dart_post(self, k: int):
-        hd = self._dart_hist_dtype()
-        with_bias = self._dart_base == 0 and any(
-            abs(s) > K_EPSILON for s in self.init_scores)
-        init_vec = jnp.asarray(np.asarray(self.init_scores, np.float32))
-
-        def post(obj_state, rec, row_leaf, scores, scores_adj, delta,
-                 valid_scores, valid_bins, vhists, leaf_hist, leaf_vals,
-                 new_factor, old_factor, deltas_v, t_cur, mask,
-                 true_grad, true_hess):
-            obj = self.objective
-            old = obj.swap_device_state(obj_state) if obj is not None \
-                else None
-            try:
-                if self._quant_enabled and \
-                        self.config.quant_train_renew_leaf:
-                    rec = self._renew_leaves_in_jit(
-                        rec, row_leaf, true_grad, true_hess, mask)
-                if obj is not None:
-                    with jax.named_scope("lgbm/renew"):
-                        renewed_lv = obj.renew_leaves_traced(
-                            rec.leaf_value, row_leaf, scores_adj[k], mask)
-                        if renewed_lv is not None:
-                            rec = rec._replace(leaf_value=jnp.where(
-                                rec.num_leaves > 1, renewed_lv,
-                                rec.leaf_value))
-                with jax.named_scope("lgbm/score"):
-                    lv = jnp.where(rec.num_leaves > 1, rec.leaf_value,
-                                   0.0)
-                    scores = scores.at[k].set(
-                        scores_adj[k] + old_factor * delta[k]
-                        + new_factor * lv[row_leaf])
-                    leaf_hist = leaf_hist.at[t_cur, k].set(
-                        row_leaf.astype(hd))
-                    lv_store = lv
-                    if with_bias:
-                        # see _make_fused_dart: first-iteration trees
-                        # carry bias/creation_factor in the history
-                        # buffer
-                        lv_store = lv + jnp.where(
-                            t_cur == 0, init_vec[k] / new_factor, 0.0)
-                    leaf_vals = leaf_vals.at[t_cur, k].set(lv_store)
-                new_valid = list(valid_scores)
-                new_vhists = list(vhists)
-                for vi in range(len(valid_bins)):
-                    with jax.named_scope("lgbm/valid"):
-                        vleaf = replay_tree(
-                            rec, valid_bins[vi], self.feature_meta,
-                            self._bundle,
-                            num_data=self._valid_sets[vi][0].num_data)
-                        new_valid[vi] = new_valid[vi].at[k].set(
-                            new_valid[vi][k]
-                            - (1.0 - old_factor) * deltas_v[vi][k]
-                            + new_factor * lv[vleaf])
-                        new_vhists[vi] = \
-                            new_vhists[vi].at[t_cur, k].set(
-                                vleaf.astype(hd))
-                return (rec, scores, tuple(new_valid),
-                        tuple(new_vhists), leaf_hist, leaf_vals)
-            finally:
-                if obj is not None:
-                    obj.swap_device_state(old)
-        return post
-
-    def _make_stream_dart_factors(self):
-        @jax.named_scope("lgbm/score")
-        def upd(factors, dropped, t_cur, new_factor, old_factor):
-            t_max = factors.shape[0]
-            live = dropped >= 0
-            d_scatter = jnp.where(live, dropped, t_max)  # OOB = no-op
-            factors = factors.at[d_scatter].multiply(old_factor)
-            return factors.at[t_cur].set(new_factor)
-        return upd
-
-    def _train_one_iter_fused_upload(self) -> bool:
-        """Single-slab streamed DART: the fused DART program on a
-        per-iteration upload of the bins (see the GBDT twin)."""
-        import time as _time
-        from .io.streaming import global_stream_stats as _stats
-        self._boost_from_average()
+    # -- the two hooks around GBDT's driver
+    def _begin_iteration(self):
         self._ensure_dart_state()
         drop_slots = self._select_drop(self._dart_t)
         n_drop = len(drop_slots)
         global_metrics.observe("dart_dropped_trees", n_drop)
-        d_cap = max(int(self.config.max_drop), 1)
-        dropped = np.full(d_cap, -1, np.int32)
+        dropped = np.full(max(int(self.config.max_drop), 1), -1, np.int32)
         dropped[:n_drop] = drop_slots
-        if self._dart_fused is None:
-            self._dart_fused = self._make_fused_dart()
         st = self._dart
-        bins = self._stream_take_bins()
-        with global_tracer.span("train/iteration",
-                                block=lambda: self.scores):
-            out = self._dart_fused(
-                bins, tuple(self._valid_bins), self._obj_state(),
-                self.scores, self._sample_mask, tuple(self._valid_scores),
-                st["leaf_hist"], tuple(st["vhist"]), st["leaf_vals"],
-                st["factors"], jnp.asarray(dropped), jnp.int32(n_drop),
-                jnp.int32(self._dart_t), jnp.int32(self.iter),
+        return (drop_slots,
+                (st["leaf_hist"], tuple(st["vhist"]), st["leaf_vals"]),
+                (st["factors"], jnp.asarray(dropped), jnp.int32(n_drop),
+                 jnp.int32(self._dart_t)),
                 jnp.float32(self.config.learning_rate))
-            self._stream_prefetch_bins()
-            if self._health_armed:
-                out, self._health_vec = out[:-1], out[-1]
-            (self.scores, self._sample_mask, valid, recs, new_obj_state,
-             st["leaf_hist"], vhist, st["leaf_vals"],
-             st["factors"]) = out
-            t0 = _time.perf_counter()
-            jax.block_until_ready(self.scores)
-            _stats.note_block(_time.perf_counter() - t0)
+
+    def _end_iteration(self, drop_slots, state) -> None:
+        st = self._dart
+        st["leaf_hist"], vhist, st["leaf_vals"], st["factors"] = state
         st["vhist"] = list(vhist)
-        if self.objective is not None:
-            self.objective.swap_device_state(new_obj_state)
-        self._valid_scores = list(valid)
-        self._device_records.append(recs)
         self._dart_t += 1
-        self.iter += 1
-        _stats.iterations_total += 1
-        self._note_stream_meta()
-        new_factor, _old = self._dart_factors(n_drop)
-        self._update_drop_weights(drop_slots)
-        self._tree_weights.append(new_factor)
-        self._sum_tree_weight += new_factor
-        return False
-
-    def _train_one_iter_stream_orchestrated(self) -> bool:
-        import time as _time
-        self._boost_from_average()
-        self._ensure_dart_state()
-        from .io.streaming import global_stream_stats as _stats
-        drop_slots = self._select_drop(self._dart_t)
-        n_drop = len(drop_slots)
-        global_metrics.observe("dart_dropped_trees", n_drop)
-        d_cap = max(int(self.config.max_drop), 1)
-        dropped = np.full(d_cap, -1, np.int32)
-        dropped[:n_drop] = drop_slots
-        dropped = jnp.asarray(dropped)
-        st = self._dart
-        prep = self._stream_prog("dart_prep", self._make_stream_dart_prep)
-        with global_tracer.span("train/iteration",
-                                block=lambda: self.scores):
-            it = jnp.int32(self.iter)
-            t_cur = jnp.int32(self._dart_t)
-            (sample_mask, scores_adj, delta, deltas_v, grad_all,
-             hess_all, new_f, old_f, new_obj_state) = prep(
-                self._obj_state(), self.scores, self._sample_mask,
-                st["leaf_hist"], tuple(st["vhist"]), st["leaf_vals"],
-                st["factors"], dropped, jnp.int32(n_drop), it,
-                jnp.float32(self.config.learning_rate))
-            self._sample_mask = sample_mask
-            if self.objective is not None:
-                self.objective.swap_device_state(new_obj_state)
-            recs = []
-            scores = self.scores
-            valid = tuple(self._valid_scores)
-            vhists = tuple(st["vhist"])
-            leaf_hist, leaf_vals = st["leaf_hist"], st["leaf_vals"]
-            for k in range(self.num_tree_per_iteration):
-                rec, row_leaf, mask, true_g, true_h = \
-                    self._stream_grow_class(k, grad_all[k], hess_all[k],
-                                            sample_mask, it)
-                post = self._stream_prog(
-                    f"dart_post_{k}",
-                    lambda k=k: self._make_stream_dart_post(k))
-                (rec, scores, valid, vhists, leaf_hist, leaf_vals) = \
-                    post(self._obj_state(), rec, row_leaf, scores,
-                         scores_adj, delta, valid,
-                         tuple(self._valid_bins), vhists, leaf_hist,
-                         leaf_vals, new_f, old_f, deltas_v, t_cur,
-                         mask, true_g, true_h)
-                recs.append(rec)
-            fac = self._stream_prog("dart_factors",
-                                    self._make_stream_dart_factors)
-            st["factors"] = fac(st["factors"], dropped, t_cur, new_f,
-                                old_f)
-            self.scores = scores
-            self._valid_scores = list(valid)
-            st["vhist"] = list(vhists)
-            st["leaf_hist"], st["leaf_vals"] = leaf_hist, leaf_vals
-            if self._health_armed:
-                sen = self._stream_prog(
-                    "sentinel", lambda: _nonfinite_counts)
-                self._health_vec = sen(grad_all, hess_all, self.scores)
-            t0 = _time.perf_counter()
-            jax.block_until_ready(self.scores)
-            _stats.note_block(_time.perf_counter() - t0)
-        _stats.iterations_total += 1
-        self._device_records.append(_stack_class_records(recs))
-        self._dart_t += 1
-        self.iter += 1
-        self._note_stream_meta()
-        new_factor, _old = self._dart_factors(n_drop)
-        self._update_drop_weights(drop_slots)
-        self._tree_weights.append(new_factor)
-        self._sum_tree_weight += new_factor
-        return False
-
-    def _train_one_iter_fast(self) -> bool:
-        """Fused DART iteration (the DART twin of the GBDT fast path)."""
-        if self._stream is not None:
-            return self._train_one_iter_stream()
-        self._boost_from_average()
-        self._ensure_dart_state()
-        drop_slots = self._select_drop(self._dart_t)
-        n_drop = len(drop_slots)
-        global_metrics.observe("dart_dropped_trees", n_drop)
-        d_cap = max(int(self.config.max_drop), 1)
-        dropped = np.full(d_cap, -1, np.int32)
-        dropped[:n_drop] = drop_slots
-        if self._dart_fused is None:
-            self._dart_fused = self._make_fused_dart()
-        st = self._dart
-        with global_tracer.span("train/iteration",
-                                block=lambda: self.scores):
-            out = self._dart_fused(
-                self.bins_fm, tuple(self._valid_bins), self._obj_state(),
-                self.scores, self._sample_mask, tuple(self._valid_scores),
-                st["leaf_hist"], tuple(st["vhist"]), st["leaf_vals"],
-                st["factors"], jnp.asarray(dropped), jnp.int32(n_drop),
-                jnp.int32(self._dart_t), jnp.int32(self.iter),
-                jnp.float32(self.config.learning_rate))
-            if self._health_armed:
-                out, self._health_vec = out[:-1], out[-1]
-            (self.scores, self._sample_mask, valid, recs, new_obj_state,
-             st["leaf_hist"], vhist, st["leaf_vals"],
-             st["factors"]) = out
-        st["vhist"] = list(vhist)
-        if self.objective is not None:
-            self.objective.swap_device_state(new_obj_state)
-        self._valid_scores = list(valid)
-        self._device_records.append(recs)
-        self._dart_t += 1
-        self.iter += 1
         # host weight bookkeeping — uses only host-known values (drop
         # count), so no device sync happens
-        new_factor, _old = self._dart_factors(n_drop)
+        new_factor, _old = self._dart_factors(len(drop_slots))
         self._update_drop_weights(drop_slots)
         self._tree_weights.append(new_factor)
         self._sum_tree_weight += new_factor
-        return False
 
     def _materialize_records_inner(self) -> None:
         if self._dart is None:
@@ -2890,7 +2627,7 @@ class DART(GBDT):
         self._materialize_records()
         self._dart_unshrunk = []
         self._dart = None
-        self._dart_fused = None
+        self._fused = None  # _score_rule reads _dart_base while it traces
 
     def add_valid(self, valid_set, raw_data) -> None:
         super().add_valid(valid_set, raw_data)
@@ -2900,7 +2637,6 @@ class DART(GBDT):
             self._dart_fast_disabled = True
         else:
             self._dart = None
-            self._dart_fused = None
 
     def rollback_one_iter(self) -> None:
         if self.iter <= 0:
@@ -3031,13 +2767,7 @@ class RF(GBDT):
                 np.asarray(self.init_scores, np.float32)[:, None])
             base_score = jnp.broadcast_to(
                 init, (self.num_tree_per_iteration, self.num_data))
-            obj = self.objective
-            if hasattr(obj, "get_gradients_multi"):
-                g, h = obj.get_gradients_multi(base_score)
-            else:
-                g0, h0 = obj.get_gradients(base_score[0])
-                g, h = g0[None, :], h0[None, :]
-            self._base_grad = (g, h)
+            self._base_grad = self._grad_fn(base_score)
         return self._base_grad
 
     def predict_raw(self, data, start_iteration=0, num_iteration=-1,

@@ -50,10 +50,50 @@ def _lowered_text(path: str) -> str:
     return _lowered[path]
 
 
+# the programs the same stages are composed into besides the fused
+# iteration (boosting.py): each names the layers its stages must keep
+COMPOSED = {
+    "boosting/fused_dart_iter": LAYERS + ("valid",),
+    "boosting/stream_prep": ("gradient",),
+    "boosting/stream_class_prep_0": ("gradient",),
+    "boosting/stream_class_post_0": ("score", "valid"),
+    "boosting/stream_dart_prep": ("gradient", "score", "valid"),
+    "boosting/stream_dart_post_0": ("score", "valid"),
+    "boosting/stream_dart_factors": ("score",),
+}
+_composed = {}
+
+
+def _composed_layers(tag: str) -> set:
+    """Layers in the table of a composed program, read from the
+    executables of three tiny runs: resident DART, and GBDT and DART
+    streamed in two slabs, each with a valid set and int8 gradients."""
+    if not _composed:
+        x, y = _data(3000)
+        xv, yv = _data(300, seed=1)
+        dart = {"boosting": "dart", "drop_rate": 0.9, "max_drop": 3}
+        stream = {"tpu_stream": "on", "tpu_stream_slab_rows": 2048}
+        for extra in (dart, stream, {**dart, **stream}):
+            p = dict({"objective": "binary", "num_leaves": 7, "max_bin": 63,
+                      "verbosity": -1}, **PATHS["waved-int8"], **extra)
+            ds = lgb.Dataset(x, label=y, params=p)
+            lgb.train(p, ds, 2, valid_sets=[
+                lgb.Dataset(xv, label=yv, params=p, reference=ds)])
+        for t in COMPOSED:
+            _composed[t] = set(obs_profile.layer_table(t).values())
+    return _composed[tag]
+
+
 # (a) ------------------------------------------------------------------
-@pytest.mark.parametrize("layer", LAYERS)
-@pytest.mark.parametrize("path", sorted(PATHS))
+@pytest.mark.parametrize(
+    "path,layer",
+    [(p, la) for p in sorted(PATHS) for la in LAYERS]
+    + [(t, la) for t in sorted(COMPOSED) for la in COMPOSED[t]])
 def test_fused_iteration_scopes_each_layer(path, layer):
+    if path in COMPOSED:
+        assert layer in _composed_layers(path), \
+            f"no instruction under lgbm/{layer} in {path}"
+        return
     # inside a scan body's private function a location's name is
     # relative ("lgbm/partition/..."), elsewhere it follows "jit(fused)/"
     assert re.search(rf'[/"]lgbm/{layer}[/"]', _lowered_text(path)), \

@@ -49,13 +49,17 @@ def _data(n=1500, f=6, seed=0):
     return X, y
 
 
-def _train(X, y, extra, iters=3, rounds=None):
+def _train(X, y, extra, iters=3, rounds=None, valid=None):
     params = {**dict(objective="binary", num_leaves=15, learning_rate=0.1,
                      max_bin=63, min_data_in_leaf=5, verbosity=-1),
               **extra}
     ds = lgb.Dataset(X, label=y, params=params)
     ds.construct()
-    return lgb.train(params, ds, num_boost_round=rounds or iters)
+    vs = (None if valid is None else
+          [lgb.Dataset(valid[0], label=valid[1], params=params,
+                       reference=ds)])
+    return lgb.train(params, ds, num_boost_round=rounds or iters,
+                     valid_sets=vs)
 
 
 def _strip_params(s):
@@ -155,17 +159,37 @@ MATRIX = {
     "quantized": {"use_quantized_grad": True},
     "2shard": {"tree_learner": "data", "tpu_num_shards": 2},
     "rf": {"boosting": "rf", "bagging_fraction": 0.7, "bagging_freq": 1},
+    # the stages both compositions share (ISSUE 30). "valid": a valid
+    # set rides along; "slabs": streamed in two slabs, so the streamed
+    # composition itself runs (int8 sums are exact at any slab count)
+    "dart+valid": {"boosting": "dart", "drop_rate": 0.5, "max_drop": 5,
+                   "valid": True},
+    "quantized+renew": {"use_quantized_grad": True,
+                        "quant_train_renew_leaf": True, "slabs": True},
+    "goss+quantized": {"data_sample_strategy": "goss",
+                       "use_quantized_grad": True},
+    "multiclassova+dart": {"objective": "multiclassova", "num_class": 3,
+                           "boosting": "dart", "drop_rate": 0.5,
+                           "max_drop": 5},
 }
 
 
 class TestBitIdentity:
     @pytest.mark.parametrize("name", sorted(MATRIX))
     def test_streamed_matches_resident(self, name):
-        X, y = _data()
-        resident = _train(X, y, MATRIX[name]).model_to_string()
-        streamed = _train(X, y, {**MATRIX[name], "tpu_stream": "on"}
-                          ).model_to_string()
-        assert _strip_params(streamed) == _strip_params(resident)
+        extra = dict(MATRIX[name])
+        valid = _data(400, seed=5) if extra.pop("valid", False) else None
+        slabs = 2 if extra.pop("slabs", False) else 1
+        stream = {"tpu_stream": "on",
+                  "tpu_stream_slab_rows": 2048 if slabs == 2 else 0}
+        X, y = _data(3000 if slabs == 2 else 1500)
+        if "num_class" in extra:
+            y = (np.abs(X[:, 0] * 3).astype(int) % 3).astype(np.float32)
+        resident = _train(X, y, extra, valid=valid).model_to_string()
+        streamed = _train(X, y, {**extra, **stream}, valid=valid)
+        assert streamed._gbdt._stream.n_slabs == slabs
+        assert _strip_params(streamed.model_to_string()) == \
+            _strip_params(resident)
 
     def test_streamed_matches_resident_with_valid_set(self):
         X, y = _data()
