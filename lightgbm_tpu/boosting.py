@@ -41,6 +41,7 @@ from .obs.trace import global_tracer
 from .timer import global_timer  # noqa: F401  (compat facade re-export)
 from .objectives import ObjectiveFunction, create_objective
 from .ops import histogram as hist_ops
+from .ops.partition import per_row_lookup
 from .ops.split import FeatureMeta, SplitHyperParams, leaf_output
 from .tree import Tree
 
@@ -369,13 +370,16 @@ class GBDT:
         # grown-tree jit (shared across iterations; one XLA program per tree)
         self._build_grow(hist_ops.resolve_impl(config.tpu_hist_impl))
         # slow-path twin of the fused program's score update: the
-        # multiply and the add must live in ONE program so XLA makes the
-        # same FMA-contraction choice as inside the fused iteration —
-        # split across two jits the add rounds separately and the paths
-        # drift by one ulp, which flips sign-function gradients (L1
-        # family) on rows sitting at score == label
+        # multiply at [L], the lookup (per_row_lookup: selects over the
+        # L values, no row-sized gather) and the add must live in ONE
+        # program and take the fused iteration's shape, so XLA makes the
+        # same FMA-contraction choice in both — split across two jits
+        # the add rounds separately and the paths drift by one ulp,
+        # which flips sign-function gradients (L1 family) on rows
+        # sitting at score == label
         self._update_score_shrunk = jax.jit(
-            lambda score, lv, lr, row_leaf: score + (lv * lr)[row_leaf])
+            lambda score, lv, lr, row_leaf:
+            score + per_row_lookup(lv * lr, row_leaf))
 
     def _maybe_pack_bins(self, binned):
         """Bit-packed device bins for `binned`, or None when ineligible
@@ -1198,8 +1202,9 @@ class GBDT:
                     valid_bins, mask, true_grad, true_hess, lr, carry, hist):
         """Stage 3, the tail: leaf renewal, then the booster's score rule
         on the training scores and on every valid set's replayed leaves
-        — ONE program in every composition, so the multiply-gather-add
-        keeps its FMA shape. `scores_k` is class k's row of
+        — ONE program in every composition, so the multiply at [L], the
+        per_row_lookup (selects over the L values, no row-sized gather)
+        and the add keep their FMA shape. `scores_k` is class k's row of
         _grad_scores (the caller's slice: the fused program takes it
         before the sample stage, where its grower may need it). Returns
         (rec, scores, valid_scores, hist)."""
@@ -1233,13 +1238,13 @@ class GBDT:
             # training instead, gbdt.cpp should_continue)
             leaf_vals = jnp.where(rec.num_leaves > 1,
                                   rec.leaf_value * lr, 0.0)
-            scores = scores.at[k].add(leaf_vals[row_leaf])
+            scores = scores.at[k].add(per_row_lookup(leaf_vals, row_leaf))
         return scores, leaf_vals, hist
 
     def _valid_score_rule(self, k, vi, valid, leaf_vals, vleaf, carry,
                           hist):
         """The score rule on valid set vi (traced under lgbm/valid)."""
-        return valid.at[k].add(leaf_vals[vleaf]), hist
+        return valid.at[k].add(per_row_lookup(leaf_vals, vleaf)), hist
 
     def _iter_finish(self, carry, drop):
         """Stage 4: what is left of the booster's device state to update
@@ -2330,8 +2335,8 @@ class DART(GBDT):
 
     def _slow_score_update(self, tree, lv32: np.ndarray, row_leaf, k):
         # bit-aligned with the fused DART program's creation add
-        # (`scores_adj + old_factor*delta + new_factor*lv[row_leaf]`):
-        # PRE-shrinkage f32 leaf values, gathered FIRST, then multiplied
+        # (`scores_adj + old_factor*delta + new_factor*lookup(lv, leaf)`):
+        # PRE-shrinkage f32 leaf values, looked up FIRST, then multiplied
         # by the f32 drop-factor and added in one XLA program — the same
         # FMA-contraction shape, so drop-free iterations are bitwise
         # identical between the paths. (The GBDT twin multiplies before
@@ -2342,7 +2347,8 @@ class DART(GBDT):
         # split flip born in the drop-FREE early iterations.
         if self._dart_update_score is None:
             self._dart_update_score = jax.jit(
-                lambda score, lv, nf, rl: score + nf * lv[rl])
+                lambda score, lv, nf, rl:
+                score + nf * per_row_lookup(lv, rl))
         return self._dart_update_score(
             self.scores[k], jnp.asarray(lv32),
             jnp.float32(self._tree_shrinkage()), row_leaf)
@@ -2492,7 +2498,7 @@ class DART(GBDT):
             scores = scores.at[k].set(
                 carry["scores_adj"][k]
                 + carry["old_factor"] * carry["delta"][k]
-                + new_factor * lv[row_leaf])
+                + new_factor * per_row_lookup(lv, row_leaf))
             leaf_hist = leaf_hist.at[t_cur, k].set(
                 row_leaf.astype(leaf_hist.dtype))
             lv_store = lv
@@ -2520,7 +2526,7 @@ class DART(GBDT):
                           hist):
         valid = valid.at[k].set(
             valid[k] - (1.0 - carry["old_factor"]) * carry["deltas_v"][vi][k]
-            + carry["new_factor"] * leaf_vals[vleaf])
+            + carry["new_factor"] * per_row_lookup(leaf_vals, vleaf))
         leaf_hist, vhists, leaf_vals_hist = hist
         vhists = list(vhists)
         vhists[vi] = vhists[vi].at[carry["t_cur"], k].set(

@@ -92,6 +92,53 @@ def _per_row(m: jax.Array, rec: jax.Array) -> jax.Array:
     return jnp.sum(jnp.where(m, rec[:, None], 0), axis=0, dtype=rec.dtype)
 
 
+# Largest table `per_row_lookup` reads by selects; a longer one is
+# gathered. The selects cost L - 1 vector operations a row and as many
+# instructions to compile, the gather a constant 5.6-8.6 ns a row: PERF.md
+# section 6 (PR 31) holds the chip readings of both at 63M rows (a 74th of
+# the gather's time at 255 entries, a 14th at 1,023 after a compile of
+# 11 s; at 4,095 still a 5th, after a compile of 53 s).
+LOOKUP_SELECT_MAX = 1023
+
+
+def per_row_lookup(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` for a small table: row i's value of `table` ([L])
+    at idx[i] (idx: [N] int32), [N] of table.dtype.
+
+    At L <= LOOKUP_SELECT_MAX (a static shape) nothing row-sized is
+    gathered: bit b of a row's index selects between the two halves of
+    every 2^(b+1) entries, level by level, L - 1 selects a row in one
+    fused pass over idx; above it the table is gathered, which costs the
+    same whatever L is. For 0 <= idx < L the two forms agree bit for
+    bit (a select copies its value; NaN, infinities and -0.0 reach the
+    rows that name them and no other). Outside that range they differ:
+    the selects return 0, the gather clamps an index >= L to L - 1 and
+    wraps a negative one. Every grower's row -> leaf map and
+    `replay_tree`'s stay inside [0, num_leaves) (padded rows of sharded
+    storage walk the tree like any row; the -1 leaf of `_pad_rows` lives
+    inside a histogram call only), so no caller sees the difference.
+    """
+    L = table.shape[0]
+    if L > LOOKUP_SELECT_MAX:
+        global_metrics.note_trace("ops/row_lookup_gather")
+        return table[idx]
+    global_metrics.note_trace("ops/row_lookup_select")
+    vals = [table[j] for j in range(L)]
+    bit = 0
+    while len(vals) > 1:
+        odd = ((idx >> bit) & 1) == 1
+        # an entry with no sibling (ragged L) goes up as it is: the
+        # indices that would reach the missing one are >= L
+        vals = [jnp.where(odd, vals[j + 1], vals[j]) if j + 1 < len(vals)
+                else vals[j] for j in range(0, len(vals), 2)]
+        bit += 1
+    out = jnp.where((idx >= 0) & (idx < L), vals[0], 0)
+    # the result is written as the [N] vector it is: fused into a
+    # consumer shaped [1, N] (the scores of one class) the selects ran
+    # on one sublane of eight, 0.035 s for 0.007 (PERF.md section 6)
+    return jax.lax.optimization_barrier(out)
+
+
 def _per_row_fields(m: jax.Array, fields: dict) -> dict:
     """Per-row values of the per-split `fields` ({name: (values [W],
     bits)}, every value in [0, 2^bits) where it matters): the fields

@@ -267,11 +267,23 @@ def path(request):
     return request.param
 
 
+_FUSED_ITER_TEXT = {}    # path -> text, compiled once a path
+
+
 @pytest.fixture(scope="module")
 def fused_iter_text(one_chip, path):
     """``boosting/fused_iter`` compiled for the described chip, as text:
-    a benchmark cell's path (F=28, 63 bins) at 16k rows and 31 leaves, a
-    15 s compile."""
+    a benchmark cell's path (F=28, 63 bins, 255 leaves) at 16k rows, a
+    40 s compile. (At 31 leaves the compiler turns a ``table[idx]`` into
+    selects by itself, and the score update's test below would see no
+    gather in any program.) pytest sets a parametrised fixture up anew
+    whenever the tests' order changes paths, hence the memo."""
+    if path not in _FUSED_ITER_TEXT:
+        _FUSED_ITER_TEXT[path] = _compile_fused_iter(one_chip, path)
+    return _FUSED_ITER_TEXT[path]
+
+
+def _compile_fused_iter(one_chip, path):
     import numpy as np
 
     import lightgbm_tpu as lgb
@@ -284,7 +296,7 @@ def fused_iter_text(one_chip, path):
         r = np.random.RandomState(0)
         x = r.randn(ITER_ROWS, F) + 0.26
         y = (x[:, 0] + x[:, 1] > 0.5).astype(np.float64)
-        g = lgb.Booster({"objective": "binary", "num_leaves": 31,
+        g = lgb.Booster({"objective": "binary", "num_leaves": 255,
                          "max_bin": 63, "verbosity": -1,
                          "tpu_hist_impl": "pallas", **PATHS[path]},
                         lgb.Dataset(x, label=y))._gbdt
@@ -341,10 +353,11 @@ def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
     """The row-sized fusions each fall under one layer: ``s32[N]`` the
     wave partition's compare-and-select passes (PR 27; the ``u8[N]`` bin
     gather it replaced is gone), ``s8[3, N]`` the int8 kernel's
-    operand, ``f32[N]`` the score update (PERF.md section 5). The
-    ``layer_*_s`` metrics are defined by the scopes in the program: a
-    change that moves a ``named_scope`` moves seconds between them, and
-    shows here first."""
+    operand, ``f32[N]`` the score update: its selects, written as the
+    vector they make before the add takes them (PR 31; the gather they
+    replaced is gone; PERF.md section 5). The ``layer_*_s`` metrics are
+    defined by the scopes in the program: a change that moves a
+    ``named_scope`` moves seconds between them, and shows here first."""
     got = {lay for head, lay in fused_iter_table.items()
            if "fusion" in head.split(" = ")[0]
            and head.split(" = ")[1].startswith(shape + "{")}
@@ -396,12 +409,30 @@ def test_partition_gathers_nothing_row_sized(fused_iter_text):
     assert "lgbm/partition" in fused_iter_text
     assert not _row_sized_gathers(fused_iter_text, ITER_ROWS,
                                   "lgbm/partition")
-    # the detector sees a row-sized gather where there is one: the score
-    # update's leaf_vals[row_leaf] at a width XLA does not rewrite
+    # the detector sees a row-sized gather where there is one: a plain
+    # table[idx] at a width XLA does not rewrite
     gathered = jax.jit(lambda t, i: t[i]).lower(
         jax.ShapeDtypeStruct((1 << 12,), jnp.float32),
         jax.ShapeDtypeStruct((ITER_ROWS,), jnp.int32)).compile().as_text()
     assert _row_sized_gathers(gathered, ITER_ROWS, "")
+
+
+def test_score_update_gathers_nothing_row_sized(fused_iter_text, one_chip):
+    """PR 31: ``_score_rule`` brings a leaf's value to its rows by
+    selects (``ops/partition.per_row_lookup``). The gather it replaced,
+    ``leaf_vals[row_leaf]`` from 255 entries, took 0.54 s of the
+    benchmark cells' 1.85 and 2.99 s an iteration (PERF.md section 6)."""
+    from lightgbm_tpu.ops import partition as part_ops
+    assert "lgbm/score" in fused_iter_text
+    assert not _row_sized_gathers(fused_iter_text, ITER_ROWS, "lgbm/score")
+    # the helper alone gathers nothing, and the plain indexing it replaced
+    # is a gather the detector sees
+    s = _shapes(one_chip)
+    args = s((255,), jnp.float32), s((ITER_ROWS,), jnp.int32)
+    for fn, gathers in ((part_ops.per_row_lookup, False),
+                        (lambda t, i: t[i], True)):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert bool(_row_sized_gathers(text, ITER_ROWS, "")) == gathers
 
 
 @pytest.mark.parametrize("rows,features", [(1 << 20, 28), (1 << 17, 2000)])
