@@ -233,8 +233,8 @@ class Dataset:
             forced_bins = {int(e["feature"]): e["bin_upper_bound"]
                            for e in spec}
 
-        from .obs.trace import global_tracer
-        with global_tracer.span("data/binning"):
+        from .dataset import binning_span
+        with binning_span():
             if _is_sparse(self.data):
                 self._binned = BinnedDataset.from_sparse(
                     self.data, cfg, metadata=meta,

@@ -10,6 +10,10 @@ mesh axis for data-parallel training).
 
 from __future__ import annotations
 
+import contextlib
+import os
+import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -17,6 +21,36 @@ import numpy as np
 from . import binning
 from .binning import BinMapper
 from .config import Config
+from .obs.metrics import global_metrics
+from .obs.trace import global_tracer
+
+# columns a worker takes at a time when the mappers are fitted: their
+# sampled rows are gathered into one [block, S] float64 array, a
+# contiguous row a column (4-51 MB at the default 200k-row sample)
+_FIT_BLOCK = 32
+_FIT_WORKERS_MAX = 16
+
+
+@contextlib.contextmanager
+def binning_span(name: Optional[str] = None, **facts):
+    """The span ``data/binning`` (``name`` None) or its child
+    ``data/binning/<name>``, with its seconds counted whether or not the
+    tracer is on. The outer span appends one record to the list
+    ``global_metrics.meta["data_binning"]`` and writes ``seconds`` into it
+    when it closes; a child inside it adds ``<name>_s`` and ``facts`` to
+    that record (outside any, to a record nobody keeps)."""
+    records = global_metrics.meta.setdefault("data_binning", [])
+    if name is None:
+        records.append({})
+    is_open = bool(records) and "seconds" not in records[-1]
+    record = records[-1] if is_open else {}
+    record.update(facts)
+    t = time.perf_counter()
+    try:
+        with global_tracer.span("data/binning" + (f"/{name}" if name else "")):
+            yield record
+    finally:
+        record[f"{name}_s" if name else "seconds"] = time.perf_counter() - t
 
 
 def is_sparse(data) -> bool:
@@ -70,6 +104,59 @@ def _transform_all(data: np.ndarray, mappers: List[BinMapper],
         if j not in done:
             bins_fm[j] = mappers[j].transform(data[:, col])
     return bins_fm
+
+
+def _fit_workers(columns: int) -> int:
+    """Threads the mappers of ``columns`` columns are fitted on."""
+    from . import native as _native
+    if not _native.available():
+        return 1
+    blocks = -(-columns // _FIT_BLOCK)
+    return max(1, min(blocks, _FIT_WORKERS_MAX, os.cpu_count() or 1))
+
+
+def _fit_mappers(data: np.ndarray, sample_rows: Optional[np.ndarray],
+                 config: Config, categorical_features: Sequence[int],
+                 forced_bins: Optional[Dict[int, List[float]]],
+                 workers: int) -> List[BinMapper]:
+    """One fitted BinMapper a column of ``data`` [N, F], each from the
+    column's values at ``sample_rows`` (all rows if None).
+
+    The columns go in blocks of ``_FIT_BLOCK``: a block's sampled rows are
+    gathered into a [block, S] float64 array, so that every ``fit`` reads
+    a contiguous vector and not a strided column of the row-major matrix
+    (at 2000 columns a stride of 16 kB a value). Where the native library
+    finds the bounds the blocks are spread over ``workers`` threads (ctypes
+    and NumPy's passes release the interpreter lock); without it the
+    Python loops of ``binning._greedy_find_bin`` hold the lock, and
+    ``_fit_workers`` says 1: the blocks run one after another. Each
+    mapper depends on its own column's values only: the result is the
+    same whatever the block size and the number of workers."""
+    f = data.shape[1]
+    cat_set = set(int(c) for c in categorical_features)
+    max_bin_by_feature = config.max_bin_by_feature
+    if max_bin_by_feature is None or len(max_bin_by_feature) != f:
+        max_bin_by_feature = None
+
+    def fit_block(c0: int) -> List[BinMapper]:
+        block = data[:, c0:c0 + _FIT_BLOCK]
+        if sample_rows is not None:
+            block = block[sample_rows]
+        values = np.ascontiguousarray(block.T, dtype=np.float64)
+        return [BinMapper().fit(
+            values[col - c0],
+            max_bin=int(config.max_bin if max_bin_by_feature is None
+                        else max_bin_by_feature[col]),
+            min_data_in_bin=int(config.min_data_in_bin),
+            use_missing=bool(config.use_missing),
+            zero_as_missing=bool(config.zero_as_missing),
+            is_categorical=col in cat_set,
+            forced_bounds=forced_bins.get(col) if forced_bins else None)
+            for col in range(c0, min(f, c0 + _FIT_BLOCK))]
+
+    with ThreadPoolExecutor(workers) as pool:
+        blocks = pool.map(fit_block, range(0, f, _FIT_BLOCK))
+        return [m for block in blocks for m in block]
 
 
 class Metadata:
@@ -208,7 +295,8 @@ class BinnedDataset:
             logical_dtype = (np.uint8 if max(
                 (m.num_bins for m in mappers), default=1) <= 256
                 else np.uint16)
-            bins_fm = _transform_all(data, mappers, used, logical_dtype)
+            with binning_span("transform"):
+                bins_fm = _transform_all(data, mappers, used, logical_dtype)
             if reference.bundle_info is not None:
                 from .bundling import build_bundled_matrix
                 nb = np.array([m.num_bins for m in mappers], np.int64)
@@ -223,32 +311,16 @@ class BinnedDataset:
 
         # sample rows for binning (ref: bin_construct_sample_cnt)
         sample_cnt = min(n, int(config.bin_construct_sample_cnt))
+        sample_rows = None
         if sample_cnt < n:
             rng = np.random.RandomState(config.data_random_seed)
-            sample_idx = rng.choice(n, sample_cnt, replace=False)
-            sample = data[np.sort(sample_idx)]
-        else:
-            sample = data
-
-        cat_set = set(int(c) for c in categorical_features)
-        mappers_all: List[BinMapper] = []
-        max_bin_by_feature = config.max_bin_by_feature
-        for col in range(f):
-            mb = int(config.max_bin)
-            if max_bin_by_feature is not None and len(max_bin_by_feature) == f:
-                mb = int(max_bin_by_feature[col])
-            forced = None
-            if forced_bins and col in forced_bins:
-                forced = forced_bins[col]
-            m = BinMapper().fit(
-                np.asarray(sample[:, col], dtype=np.float64),
-                max_bin=mb,
-                min_data_in_bin=int(config.min_data_in_bin),
-                use_missing=bool(config.use_missing),
-                zero_as_missing=bool(config.zero_as_missing),
-                is_categorical=col in cat_set,
-                forced_bounds=forced)
-            mappers_all.append(m)
+            sample_rows = np.sort(rng.choice(n, sample_cnt, replace=False))
+        workers = _fit_workers(f)
+        with binning_span("find_bins", columns=f, sample_rows=sample_cnt,
+                          workers=workers):
+            mappers_all = _fit_mappers(data, sample_rows, config,
+                                       categorical_features, forced_bins,
+                                       workers)
 
         used = [i for i, m in enumerate(mappers_all)
                 if not (config.feature_pre_filter and m.is_trivial)]
@@ -257,11 +329,13 @@ class BinnedDataset:
         mappers = [mappers_all[i] for i in used]
         max_bins = max((m.num_bins for m in mappers), default=1)
         dtype = np.uint8 if max_bins <= 256 else np.uint16
-        bins_fm = _transform_all(data, mappers, used, dtype)
+        with binning_span("transform"):
+            bins_fm = _transform_all(data, mappers, used, dtype)
         ds = cls(bins_fm, mappers, used, f, metadata, feature_names)
         ds.raw_data = data
         if config.enable_bundle and len(mappers) > 1:
-            ds._try_bundle(config)
+            with binning_span("bundle"):
+                ds._try_bundle(config)
         return ds
 
     @classmethod
@@ -469,6 +543,12 @@ class BinnedDataset:
         nb = np.array([m.num_bins for m in self.mappers], np.int64)
         default_bins = np.array([m.default_bin for m in self.mappers],
                                 np.int64)
+        # the offset encoding represents "default" as stored bin 0, so
+        # only default-bin-0 features can share a bundle; others are
+        # stored verbatim as singletons
+        bundleable = default_bins == 0
+        if np.count_nonzero(bundleable) < 2:
+            return  # nothing to pair: every bundle would be a singleton
         # conflict detection on a row SAMPLE (ref: FindGroups samples too)
         # — a full scan would cost O(F*G*N) host time on exactly the
         # wide-sparse data EFB exists for
@@ -481,14 +561,21 @@ class BinnedDataset:
         else:
             sample = self.bins_fm
         nonzero = sample != default_bins[:, None].astype(self.bins_fm.dtype)
-        # the offset encoding represents "default" as stored bin 0, so
-        # only default-bin-0 features can share a bundle; others are
-        # stored verbatim as singletons
+        max_conflict_rate = float(config.max_conflict_rate)
+        # Two columns with a and b non-default rows of the S sampled share
+        # at least a + b - S of them, and `find_bundles` joins a column to
+        # a bundle only while the shared rows stay within the budget. If
+        # the two sparsest bundleable columns already exceed it, so does
+        # every pair (dense data): the search would end with singletons.
+        fewest = np.sort(nonzero.sum(axis=1)[bundleable])[:2]
+        if int(fewest.sum()) - sample.shape[1] > int(
+                max_conflict_rate * sample.shape[1]):
+            return
         bundles = find_bundles(
             nonzero, nb,
-            max_conflict_rate=float(config.max_conflict_rate),
+            max_conflict_rate=max_conflict_rate,
             max_bundle_bins=max(int(self.max_bins), 256),
-            bundleable=(default_bins == 0))
+            bundleable=bundleable)
         if not should_bundle(bundles, len(self.mappers)):
             return
         bundled, info = build_bundled_matrix(self.bins_fm, nb, bundles)

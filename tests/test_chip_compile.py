@@ -279,11 +279,15 @@ def fused_iter_text(one_chip, path):
     gather in any program.) pytest sets a parametrised fixture up anew
     whenever the tests' order changes paths, hence the memo."""
     if path not in _FUSED_ITER_TEXT:
-        _FUSED_ITER_TEXT[path] = _compile_fused_iter(one_chip, path)
+        _FUSED_ITER_TEXT[path] = _compile_fused_iter(one_chip,
+                                                     path).as_text()
     return _FUSED_ITER_TEXT[path]
 
 
-def _compile_fused_iter(one_chip, path):
+def _compile_fused_iter(one_chip, path, features=F, rows=None):
+    """``boosting/fused_iter`` compiled for the described chip, from a
+    Booster built on ITER_ROWS rows and lowered at ``rows`` of them (at
+    ITER_ROWS if None)."""
     import numpy as np
 
     import lightgbm_tpu as lgb
@@ -294,7 +298,7 @@ def _compile_fused_iter(one_chip, path):
     mp.setattr(hist_ops, "cpu_backend", lambda: False)
     try:
         r = np.random.RandomState(0)
-        x = r.randn(ITER_ROWS, F) + 0.26
+        x = r.randn(ITER_ROWS, features) + 0.26
         y = (x[:, 0] + x[:, 1] > 0.5).astype(np.float64)
         g = lgb.Booster({"objective": "binary", "num_leaves": 255,
                          "max_bin": 63, "verbosity": -1,
@@ -305,9 +309,10 @@ def _compile_fused_iter(one_chip, path):
                 g._sample_mask, tuple(g._valid_scores), jnp.int32(0),
                 jnp.float32(0.1))
         shapes = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), args)
-        return g._make_fused().lower(*shapes).compile().as_text()
+            lambda a: jax.ShapeDtypeStruct(
+                tuple(rows if rows and d == ITER_ROWS else d
+                      for d in a.shape), a.dtype, sharding=one_chip), args)
+        return g._make_fused().lower(*shapes).compile()
     finally:
         mp.undo()
 
@@ -433,6 +438,37 @@ def test_score_update_gathers_nothing_row_sized(fused_iter_text, one_chip):
                         (lambda t, i: t[i], True)):
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert bool(_row_sized_gathers(text, ITER_ROWS, "")) == gathers
+
+
+def test_wide_float_iteration_fits_the_chip(one_chip):
+    """``epsilon-gpu63.train``'s program (PR 32): the float iteration at
+    1,200,000 x 2000, 63 bins, 255 leaves, every ``tpu_*`` parameter at
+    its default, compiled at its real size (two minutes: the one test
+    here that is). The chip's compiler accepts it, what it holds (its
+    arguments, the 2.4 GB of bins among them, and its temporaries) is over
+    the 2.00 GiB a cell has to fill and fits the chip's 16 GB, the
+    kernel is the many-block float one, and the row-sized fusions fall
+    under the layers they do in the narrow cells' programs."""
+    from lightgbm_tpu.obs.profile import parse_layer_table
+    rows = 1_200_000
+    compiled = _compile_fused_iter(one_chip, "float", features=2000,
+                                   rows=rows)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes >= 2000 * rows
+    assert 2 * 2 ** 30 <= held < 16e9, mem
+    table = parse_layer_table(compiled.as_text())
+    kernels = {head.split(" = ")[0].split(".")[0] for head in table
+               if head.startswith("%lgbm_hist_multi")}
+    assert kernels == {KERNEL["float"]}
+    assert "gradient" not in table.values()
+    for shape, layer in ((f"s32[{rows}]", "partition"),
+                         (f"f32[{rows}]", "score")):
+        got = {lay for head, lay in table.items()
+               if "fusion" in head.split(" = ")[0]
+               and head.split(" = ")[1].startswith(shape + "{")}
+        assert got == {layer}, (shape, got)
 
 
 @pytest.mark.parametrize("rows,features", [(1 << 20, 28), (1 << 17, 2000)])

@@ -164,14 +164,17 @@ def test_waved_with_bagging_and_feature_fraction():
 # 64 bins give 64 rows, 255 and 256 give 256, 300 gives 304 or 320 and
 # uint16 ids; 15/16 and 3/4 bins the 4-bit and 2-bit PackedBins), a
 # padded feature block (5, 28, 70), more than one block (70 features at
-# 255 bins), a row count no chunk divides, a section only the smallest
+# 255 bins, 2000 at 63), a row count no chunk divides, a section only the smallest
 # chunk divides (9000 rows at 2 a byte: 6144 bytes)
 STEP_CASES = [(63, 1, 28, 42, 5000), (63, 1, 5, 1, 2500),
               (64, 1, 70, 8, 2500), (255, 1, 5, 8, 2500),
               (255, 1, 70, 42, 2100), (256, 1, 28, 1, 2500),
               (300, 1, 5, 8, 2500), (300, 1, 28, 42, 2100),
               (15, 2, 28, 8, 9000), (16, 2, 5, 42, 4100),
-              (3, 4, 28, 8, 9000), (4, 4, 70, 1, 8200)]
+              (3, 4, 28, 8, 9000), (4, 4, 70, 1, 8200),
+              # the wide cell's shape (PR 32): many feature blocks a row
+              # chunk, the last one padded
+              (63, 1, 2000, 42, 2100)]
 
 
 def _binary_grad(score, label, weight):
@@ -339,6 +342,9 @@ WAVE_CASES = {
     # 8 mask words; a categorical split on the last bin
     "B255-cat-bin254": dict(N=2000, F=6, B=255, L=31, W=5, cat_last=True),
     "F33": dict(N=700, F=33, B=16, L=15, W=5),
+    # the wide cell's shape (PR 32): a full wave over 2000 stored rows
+    "F2000-W42-L255": dict(N=3000, F=2000, B=63, L=255, W=42, live=213,
+                           has_categorical=False),
     "all-invalid": dict(N=500, F=6, B=16, L=15, W=5, all_invalid=True),
     # uint16 bins: thresholds and NaN codes wider than a byte
     "B300-uint16": dict(N=1500, F=5, B=300, L=15, W=4,
