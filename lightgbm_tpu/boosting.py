@@ -655,6 +655,38 @@ class GBDT:
             round(oracle["hist_bytes_per_iter"]
                   / max(actual["hist_bytes_per_iter"], 1), 4))
 
+    # trees whose passes `hist_live_rows` keeps (the newest)
+    _LIVE_ROWS_KEPT = 8
+
+    def _note_hist_live_rows(self, rec) -> None:
+        """While a tracer session is live (the span tracer, or a
+        profiler session), publish what the histogram passes of a tree
+        multiplied (learner.hist_live_rows: live rows and K-sub-tiles a
+        pass): under ``global_metrics.meta["hist_live_rows"]``, the
+        newest trees, and as the args of a ``hist`` program span
+        (``lgbm/hist``). Computed from the tree's arrays as they reach
+        the host anyway: nothing is fetched from the device for it."""
+        if not self._use_waved() or self._sparse_shape is not None:
+            return
+        from .obs import trace as obs_trace
+        if not (global_tracer.enabled
+                or obs_trace._annotation().is_enabled()):
+            return
+        from .learner import hist_live_rows
+        kw = self._resolved_hist_shape()
+        step = (global_metrics.meta.get("hist_geometry") or [{}])[-1]
+        passes = hist_live_rows(
+            rec, num_data=kw["num_data"], num_leaves=kw["num_leaves"],
+            wave_max=kw["wave_max"],
+            subtract=bool(self.config.tpu_wave_subtract),
+            row_chunk=step.get("row_chunk", 0),
+            k_tile=step.get("k_tile", 0))
+        kept = global_metrics.meta.setdefault("hist_live_rows", [])
+        kept.append(passes)
+        del kept[:-self._LIVE_ROWS_KEPT]
+        with global_tracer.span("hist", args={"live_rows": passes}):
+            pass
+
     def _note_collective_traffic(self) -> None:
         """Publish the static per-iteration COLLECTIVE traffic model —
         the interconnect counterpart of ``_note_hist_traffic`` for mesh
@@ -1510,6 +1542,7 @@ class GBDT:
                        for f in host._fields}
                 tree = Tree.from_arrays(rec, self.train_set.mappers,
                                         self.train_set.used_features)
+                self._note_hist_live_rows(rec)
                 if tree.num_leaves > 1:
                     tree.apply_shrinkage(lrs[i])
                     if first_iter and abs(self.init_scores[k]) > K_EPSILON:

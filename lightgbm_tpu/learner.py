@@ -820,6 +820,62 @@ def _wave_schedule(num_leaves: int, wave_max: int, slots: int,
     return sizes
 
 
+def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
+                   subtract: bool = True, slots: int = HIST_SLOTS,
+                   row_chunk: int = 0, k_tile: int = 0):
+    """What the histogram passes of ONE grown tree multiplied, from the
+    tree's own child counts and the wave schedule, on the host.
+
+    `rec` holds a tree's arrays as NumPy (`split_leaf`, `leaf_count`,
+    `num_leaves` of TreeArrays). A pass multiplies the rows whose leaf
+    is one of its slot ids (ops/pallas_histogram._multi_step): every row
+    for the root, then a wave's smaller children (both children without
+    sibling subtraction); the last wave's pass is skipped. Child counts
+    are rebuilt by undoing the splits from the last to the first (split
+    s made leaf s + 1 out of leaf split_leaf[s]). They count the rows of
+    the bag: under bagging a leaf's rows outside the bag are live too
+    and are not in here. Splits are taken in schedule order, which is
+    how they were applied unless a wave held an invalid step before its
+    end. With the kernel's `row_chunk` and `k_tile` (hist_geometry) each
+    pass also gets its K-sub-tiles, multiplied and of the full pass,
+    for live rows spread evenly over the chunks (rows in input order).
+
+    Returns one dict a pass: pass ("root", "w00", ...), slots,
+    rows_live, rows_passed[, k_tiles, k_tiles_full]."""
+    import numpy as np
+    applied = max(int(rec["num_leaves"]) - 1, 0)
+    count = np.array(rec["leaf_count"], np.float64)
+    built = np.zeros(applied)   # rows of the children a split's pass builds
+    for s in range(applied - 1, -1, -1):
+        left, right = int(rec["split_leaf"][s]), s + 1
+        built[s] = (min(count[left], count[right]) if subtract
+                    else count[left] + count[right])
+        count[left] += count[right]
+    per_split = 1 if subtract else 2
+    sizes = _wave_schedule(num_leaves, wave_max, slots, per_split)
+    passes = [("root", 1, float(num_data))]
+    s0 = 0
+    for wi, w in enumerate(sizes[:-1]):
+        passes.append((f"w{wi:02d}", w * per_split,
+                       float(built[s0:s0 + w].sum())))
+        s0 += w
+    out = []
+    for name, w, live in passes:
+        one = {"pass": name, "slots": int(w), "rows_live": int(round(live)),
+               "rows_passed": int(num_data)}
+        if row_chunk and k_tile:
+            chunks = -(-num_data // row_chunk)
+            full = row_chunk // k_tile
+            a_chunk = live / chunks
+            # a chunk over 7/8 live is not squeezed
+            tiles = (full if a_chunk * 8 > row_chunk * 7 or name == "root"
+                     else -(-int(round(a_chunk)) // k_tile))
+            one["k_tiles"] = int(chunks * tiles)
+            one["k_tiles_full"] = int(chunks * full)
+        out.append(one)
+    return out
+
+
 def hist_traffic_model(*, num_data: int, storage_features: int,
                        max_bins: int, num_leaves: int, wave_max: int,
                        slots: int = HIST_SLOTS, pack_vpb=None,
@@ -1309,8 +1365,12 @@ def grow_tree_waved(bins_fm: jax.Array,
         # uint16 ids on either path, but no test trains through this one
         use_kernel_fused = (hist_impl == "pallas" and bundle is None
                             and shard_mesh is None and build_bins <= 256)
+    # every multi_raw(bins, ghT, row_leaf, ids, **step): `step` is what the
+    # caller knows of the pass (all_live: the root's), handed to the
+    # Mosaic kernels; the sparse and the per-shard builders have no use
+    # for it
     if sparse_shape is not None:
-        def multi_raw(bins, ghT_, row_leaf, ids):
+        def multi_raw(bins, ghT_, row_leaf, ids, **step):
             # O(nnz) segment-sum wave pass (the sparse row-wise
             # MultiValBin analog, multi_val_sparse_bin.hpp:70)
             return hist_ops.hist_multi_sparse(
@@ -1335,35 +1395,38 @@ def grow_tree_waved(bins_fm: jax.Array,
                 hist_reduce=hist_reduce,
                 deterministic=hist_deterministic)
 
-            def multi_raw(bins, ghT_unused, row_leaf, ids):
+            def multi_raw(bins, ghT_unused, row_leaf, ids, **step):
                 return _multi_i32(bins, ghT_i8, row_leaf,
                                   ids).astype(f32) * hscale_vec
         else:
             # default-capable on every backend: the pallas MXU kernel
             # where Mosaic runs, the exact-integer XLA contraction
             # elsewhere — identical int32 histograms either way
-            def multi_raw(bins, ghT_unused, row_leaf, ids):
+            def multi_raw(bins, ghT_unused, row_leaf, ids, **step):
                 hist_i = hist_multi_int8(bins, ghT_i8, row_leaf, ids,
                                          max_bins=build_bins,
                                          num_slots=ids.shape[0],
-                                         impl=hist_impl)
+                                         impl=hist_impl, **step)
                 return hist_i.astype(f32) * hscale_vec
     elif use_kernel_fused:
-        def multi_raw(bins, ghT_unused, row_leaf, ids):
+        def multi_raw(bins, ghT_unused, row_leaf, ids, **step):
             # gradient pass fused INTO the histogram kernel: reads
             # (score, label[, weight], mask) and computes gh in VMEM —
             # ghT never exists in HBM (see hist_pallas_multi_fused)
             return hist_pallas_multi_fused(
                 bins, fg_score, fg_label, fg_weight, sample_mask,
                 row_leaf, ids, grad_fn=fg_fn, max_bins=build_bins,
-                num_slots=ids.shape[0], precise=hist_precision)
+                num_slots=ids.shape[0], precise=hist_precision, **step)
     elif use_shard_hist:
-        multi_raw = _sharded_pallas_multi(
+        _multi_f32 = _sharded_pallas_multi(
             shard_mesh, max_bins=build_bins, precision=hist_precision,
             int8=False, impl=hist_impl, hist_reduce=hist_reduce,
             deterministic=hist_deterministic)
+
+        def multi_raw(bins, ghT_, row_leaf, ids, **step):
+            return _multi_f32(bins, ghT_, row_leaf, ids)
     else:
-        def multi_raw(bins, ghT_, row_leaf, ids):
+        def multi_raw(bins, ghT_, row_leaf, ids, **step):
             # num_slots = the wave's LIVE count: the pallas kernel's cost
             # is fixed (128 lanes) either way, but the XLA fallback loops
             # one build per slot, so early 1-8 split waves must not pay
@@ -1371,15 +1434,16 @@ def grow_tree_waved(bins_fm: jax.Array,
             return hist_multi(bins, ghT_, row_leaf, ids,
                               max_bins=build_bins, num_slots=ids.shape[0],
                               impl=hist_impl, precision=hist_precision,
-                              deterministic=hist_deterministic)
+                              deterministic=hist_deterministic, **step)
     if bundle is None:
         multi = multi_raw
     else:
         from .bundling import expand_bundle_hist
         group_of, offset_of, nb_arr = bundle
 
-        def multi(bins, ghT_, row_leaf, ids):
-            hg = multi_raw(bins, ghT_, row_leaf, ids)  # [S, G, B_tot, 3]
+        def multi(bins, ghT_, row_leaf, ids, **step):
+            hg = multi_raw(bins, ghT_, row_leaf, ids,
+                           **step)                     # [S, G, B_tot, 3]
             totals = jnp.sum(hg[:, 0], axis=1)  # [S, 3]
             return expand_bundle_hist(hg, group_of, offset_of, nb_arr,
                                       max_bins, totals)
@@ -1403,7 +1467,7 @@ def grow_tree_waved(bins_fm: jax.Array,
     with jax.named_scope("lgbm/hist/root"):
         root_ids = jnp.zeros((1,), jnp.int32)
         root_hist = multi(bins_fm, ghT, jnp.zeros((num_data,), jnp.int32),
-                          root_ids)[0].astype(f32)
+                          root_ids, all_live=True)[0].astype(f32)
     root_fmask = feature_mask if root_allowed is None else \
         feature_mask & root_allowed
     if hist_reduce == "scatter":

@@ -12,32 +12,50 @@ Layout: bins [F, N] (feature-major, or PackedBins), output hist
 block (TPU grids execute sequentially, minor-dim fastest).
 
 The multi-leaf kernels (every pass of the waved grower) share ONE step,
-`_accum_section_dots`, under three operand readers (float gh, int8 gh,
-gradient computed in the kernel), all through one `pallas_call`
-(`_multi_slabs`). What a step does:
+`_multi_step`, under three operand readers (float gh, int8 gh, gradient
+computed in the kernel), all through one `pallas_call` (`_multi_slabs`).
+A dot is issued only over what contributes to the histogram: statically
+over the real features, dynamically over the pass's live rows. What a
+step does with its row chunk of R rows:
 
-- The leaf operand ([128, R]: sublane 3 * slot + channel holds the
+- The chunk's row operands go into a row stack ([8, R] 32-bit rows: gh
+  or score / label / weight / mask, and the leaf ids). A row is live if
+  its leaf is one of the pass's slot ids (the root's pass, whose rows
+  are all live, is told apart at the call site and skips this). Unless
+  more than 7 of 8 are live, the live lanes of the bins (as packed
+  32-bit words, four features a word) and of the stack are squeezed to
+  the front of the chunk: a prefix count of the dead lanes gives each
+  live lane its destination, and a compress network of log2(R)
+  roll-and-select stages moves it there (`_squeeze_lanes`). No byte
+  moves in HBM.
+- A loop of ceil(live / k_tile) turns then multiplies one sub-tile of
+  `k_tile` lanes a turn (`_accum_section_dots`):
+- The leaf operand ([128, T]: sublane 3 * slot + channel holds the
   row's grad / hess / weight where the row is in that slot's leaf, the
-  chunk's R rows on lanes) is built once a row chunk, in the MXU's
+  sub-tile's T rows on lanes) is built once a sub-tile, in the MXU's
   operand type: int8, or bf16 (one pass by default; the float32 values
   split over two or three bf16 passes for tpu_hist_precision=high /
   highest, since the one-hot side is exact).
-- Every feature of the block gets a bin-aligned slab of `bp` one-hot
-  rows (max_bins rounded up to the operand's sublane tile: 64 at 63
-  bins), row b = bin b. A slab is built as packed 32-bit words, four
-  int8 (two bf16) one-hot rows a word: `bin_bits - 32 * word_row` is
-  the hit's bit offset or out of range, so a word vreg costs one
+- Every REAL feature of the block gets a bin-aligned slab of `bp`
+  one-hot rows (max_bins rounded up to the operand's sublane tile: 64
+  at 63 bins), row b = bin b. A slab is built as packed 32-bit words,
+  four int8 (two bf16) one-hot rows a word: `bin_bits - 32 * word_row`
+  is the hit's bit offset or out of range, so a word vreg costs one
   subtract, one unsigned compare, one shift and one select, and a
   bitcast gives the operand. No division, no select over features, no
   cast of a compare mask.
-- Dots are tall: [dot_feats * bp, R] x [128, R]^T with 512 one-hot
+- Dots are tall: [dot_feats * bp, T] x [128, T]^T with 512 one-hot
   rows or more, so a latched [128, 128] tile of the leaf operand serves
   hundreds of one-hot rows (A x B^T on the MXU, int32 or float32 sums).
+  The features a block is padded with get no dot, and the dot that
+  straddles the end of the real ones covers only those (28 features at
+  8 a dot: three dots of 8 and one of 4).
 - `_fb_geometry` sizes the step from shapes and the scoped-VMEM limit
-  alone: at 28-32 features one feature block and 4096-8192 rows a step,
+  alone: at 28-32 features one feature block and 16384 rows a step,
   at 2000 features as many features a block as the accumulator leaves
   room for. `global_metrics.meta["hist_geometry"]` says what each traced
-  kernel took.
+  kernel took, `meta["hist_live_rows"]` (learner.hist_live_rows, while
+  a tracer session is live) what the passes of a tree multiplied.
 
 Every per-row operand (gh channels, row->leaf ids, the fused kernel's
 score/label/weight/mask) enters the kernels LANE-DENSE, as ``[k, N]``
@@ -47,8 +65,8 @@ is padded to 128 lanes — 512 bytes per row instead of 4: at N = 10.5M
 each such operand took 5 GB of HBM and the v5e compiler refused the
 iteration program at 30.5 GB (asked without a chip, PR 21).
 
-PERF.md section 6 (PR 29) has the step's vector-operation counts from
-the compiler's own output and the chip's readings.
+PERF.md section 6 (PRs 29 and 33) has the step's vector-operation counts
+from the compiler's own output and the chip's readings.
 """
 
 from __future__ import annotations
@@ -118,12 +136,12 @@ def _hist_kernel(bins_ref, gh_ref, out_ref, *, f_blk: int, max_bins: int,
 
 
 def _leaf_bop(g, h, w, rl, leafsel_ref, int8: bool, parts: int = 1):
-    """The MXU's leaf-block-diagonal gh operand, transposed: [128, R]
-    with sublane k = (leaf k//3, channel k%3) and the chunk's R rows on
-    lanes, built ONCE a row chunk and latched for every feature of the
-    step. g/h/w/rl: [1, R] rows; leafsel_ref: [128, 1] leaf id of each
-    sublane. Returns the operand as a tuple of `parts` arrays in the
-    MXU's type: one int8, or the bf16 head of the float32 values
+    """The MXU's leaf-block-diagonal gh operand, transposed: [128, T]
+    with sublane k = (leaf k//3, channel k%3) and T rows of the chunk on
+    lanes, built ONCE a sub-tile of rows and latched for every feature
+    of the step. g/h/w/rl: [1, T] rows; leafsel_ref: [128, 1] leaf id of
+    each sublane. Returns the operand as a tuple of `parts` arrays in
+    the MXU's type: one int8, or the bf16 head of the float32 values
     followed by the bf16 heads of what each rounding left over
     (tpu_hist_precision=high / highest: the one-hot is exact in bf16, so
     only this side needs the extra passes)."""
@@ -146,19 +164,15 @@ def _leaf_bop(g, h, w, rl, leafsel_ref, int8: bool, parts: int = 1):
     return tuple(out)
 
 
-def _gh_rows(gh):
-    """A [3, R] (grad, hess, weight) block as its three [1, R] rows."""
-    return gh[0:1], gh[1:2], gh[2:3]
-
-
 # ---------------------------------------------------------------------------
-# the multi-leaf kernels: ONE step body (_accum_section_dots) under three
-# operand readers (pre-built float gh, pre-built int8 gh, the gradient
-# computed in the kernel). Raw bins go through it as a one-value-a-byte
-# "packed" layout; PackedBins bring vpb values a byte (bin_pack split-
-# section layout: byte j of a section-aligned block covers rows j,
-# j+section, ...; the v-th section's row operands are the same [k, N]
-# arrays blocked at section-strided offsets, so nothing is interleaved).
+# the multi-leaf kernels: ONE step (_multi_step, whose dots are
+# _accum_section_dots) under three operand readers (pre-built float gh,
+# pre-built int8 gh, the gradient computed in the kernel). Raw bins go
+# through it as a one-value-a-byte "packed" layout; PackedBins bring vpb
+# values a byte (bin_pack split-section layout: byte j of a
+# section-aligned block covers rows j, j+section, ...; the v-th section's
+# row operands are the same [k, N] arrays blocked at section-strided
+# offsets, so nothing is interleaved).
 # ---------------------------------------------------------------------------
 class HistGeometry(NamedTuple):
     """What one grid step of a multi-leaf kernel holds (_fb_geometry)."""
@@ -166,31 +180,47 @@ class HistGeometry(NamedTuple):
     f_blk: int       # features a step
     row_chunk: int   # rows a step and bit-section
     dot_feats: int   # features a dot: dot_feats * bp one-hot rows
+    k_tile: int      # rows a dot contracts: a sub-tile of the live rows
+    root_tile: int   # the same for the root's pass (every row live)
 
 
-# Mosaic's scoped-VMEM limit for one kernel on the chips this runs on
-# (16 MiB unless a kernel asks for more; none does)
+# the scoped VMEM one step's NAMED buffers may take (_step_vmem_bytes);
+# the kernels ask the compiler for twice that, the other half for the
+# squeeze network's temporaries, which only the compiler counts
 _VMEM_LIMIT = 16 * 1024 * 1024
 # one-hot rows a dot streams past each latched [128, 128] weight tile
 _DOT_ROWS = 512
-_ROW_CHUNKS = (8192, 4096, 2048)
+_ROW_CHUNKS = (16384, 8192, 4096, 2048)
+# rows (lanes) of a squeezed chunk that one turn of the step's loop
+# multiplies: a turn costs some 350 cycles of its own, a part-filled
+# last sub-tile half of this many rows a chunk (PERF.md section 6, PR 33)
+_K_TILE = 1024
+# sublanes of the row stack: a chunk's row operands as 32-bit rows, the
+# leaf ids and the squeeze's destinations: one (8, 128) tile deep
+_STACK_ROWS = 8
 
 
 def _step_vmem_bytes(g: HistGeometry, vpb: int, itemsize: int) -> int:
     """Bytes of VMEM one grid step of geometry `g` holds, by the
-    buffers it names: the pipelined blocks (two of each), the bins as
-    32-bit words, the leaf operands (three bf16 passes at the most), one
-    dot's one-hot and result, and the accumulator. A vector temporary
-    that is consumed as it is made lives in registers and is not
-    counted; tests/test_chip_compile.py asks the compiler."""
+    buffers it names: the pipelined blocks (two of each), the row stack
+    and the squeezed bins, the accumulator, and for one sub-tile of rows
+    (the wider of the root's and the other passes') the bins as 32-bit
+    words, the leaf operands (three bf16 passes at the most) and one
+    dot's one-hot and result. A vector temporary that is consumed as it
+    is made lives in registers and is not counted, nor are the squeeze
+    network's (see _VMEM_LIMIT); tests/test_chip_compile.py asks the
+    compiler."""
     rows = g.dot_feats * g.bp
-    bins = 2 * g.f_blk * g.row_chunk * (2 if g.bp > 256 else 1)
-    bins32 = g.f_blk * g.row_chunk * 4 * (1 if vpb == 1 else 2)
+    wide = 2 if g.bp > 256 else 1                   # uint16 ids
+    tile = max(g.k_tile, g.root_tile)
+    bins = 2 * g.f_blk * g.row_chunk * wide
     row_ops = vpb * 5 * 2 * 8 * g.row_chunk * 4     # <= 5 [1, R] rows
-    bops = vpb * 128 * g.row_chunk * (1 if itemsize == 1 else 6)
-    onehot = rows * g.row_chunk * itemsize
+    scratch = (_STACK_ROWS * 4 + g.f_blk * wide) * g.row_chunk
+    bins32 = g.f_blk * tile * 4 * (1 if vpb == 1 else 2)
+    bops = 128 * tile * (1 if itemsize == 1 else 6)
+    onehot = rows * tile * itemsize
     acc = (2 * g.f_blk * g.bp + rows) * 128 * 4
-    return bins + bins32 + row_ops + bops + onehot + acc
+    return bins + row_ops + scratch + bins32 + bops + onehot + acc
 
 
 def _fb_geometry(num_features: int, max_bins: int, vpb: int = 1,
@@ -207,8 +237,9 @@ def _fb_geometry(num_features: int, max_bins: int, vpb: int = 1,
     caller pads, to add under an eighth to them; the feature block is
     the largest that keeps the step under `vmem_limit`: at 28-32
     features one block, at 2000 as many as the accumulator leaves room
-    for. Of the chunks that fit, the one with the fewest grid steps
-    (leaf-operand builds) a row wins."""
+    for; the root's pass multiplies the largest part of the chunk a turn
+    that still fits. Of the chunks that fit, the one with the fewest
+    grid steps (squeezes of a chunk's live rows) a row wins."""
     bp = _round_up(max_bins, 32 // itemsize)
     k = 1
     while k * bp < _DOT_ROWS:
@@ -223,12 +254,15 @@ def _fb_geometry(num_features: int, max_bins: int, vpb: int = 1,
                     > rows // 8)):
             continue
         # fewest blocks that fit, split as evenly as the unit allows
-        for blocks in range(1, whole // unit + 1):
-            g = HistGeometry(
-                bp, _round_up(-(-num_features // blocks), unit), rc, k)
-            if _step_vmem_bytes(g, vpb, itemsize) <= vmem_limit:
-                break
-        else:
+        fits = (
+            g for blocks in range(1, whole // unit + 1)
+            for root in (rc, rc // 2, rc // 4, rc // 8)
+            for g in [HistGeometry(
+                bp, _round_up(-(-num_features // blocks), unit), rc, k,
+                min(_K_TILE, rc), max(root, min(_K_TILE, rc)))]
+            if _step_vmem_bytes(g, vpb, itemsize) <= vmem_limit)
+        g = next(fits, None)
+        if g is None:
             continue
         steps_a_row = -(-num_features // g.f_blk) / rc
         if best is None or steps_a_row < best[0]:
@@ -242,11 +276,11 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _slab_words(b_row, wiota, one: int):
-    """One feature's one-hot slab as packed 32-bit words: [bp/pack, R]
+    """One feature's one-hot slab as packed 32-bit words: [bp/pack, T]
     where word row s holds the slab's rows pack*s .. pack*s+pack-1, one
     a byte (int8, pack 4) or a half (bf16, pack 2): a row is hit where
-    the bin equals its index. b_row: [1, R] int32, the bins times the
-    operand's bits; wiota: [bp/pack, R] int32, 32 * the word row's
+    the bin equals its index. b_row: [1, T] int32, the bins times the
+    operand's bits; wiota: [bp/pack, T] int32, 32 * the word row's
     index: their difference is the hit's bit offset in the word, or
     outside [0, 32). One subtract, one unsigned compare, one shift and
     one select a word vreg, for pack one-hot vregs; the 8-bit compare
@@ -256,94 +290,231 @@ def _slab_words(b_row, wiota, one: int):
     return jnp.where(hit, jnp.int32(one) << d, 0)
 
 
-def _accum_section_dots(bins_ref, out_ref, bops, *, geom: HistGeometry,
-                        vpb: int, int8: bool):
-    """The step every multi-leaf kernel runs: for each bit-section of
-    the byte block and each `dot_feats` features, build the features'
-    bin-aligned one-hot slabs ([dot_feats * bp, R], in the MXU's operand
-    type) and contract them with the section's leaf operand. vpb=1 is
-    the unpacked layout (no shift, no mask: uint16 ids pass whole)."""
+def _accum_section_dots(btile, out_ref, bop, *, geom: HistGeometry,
+                        vpb: int, v: int, int8: bool, tail):
+    """The dots every multi-leaf kernel issues, on one sub-tile of a row
+    chunk: for each `dot_feats` REAL features of the block, build the
+    features' bin-aligned one-hot slabs ([dot_feats * bp, T], in the
+    MXU's operand type) from bit-section `v` of `btile` ([f_blk, T]
+    int32) and contract them with the section's leaf operand `bop`.
+    vpb=1 is the unpacked layout (no shift, no mask: uint16 ids pass
+    whole). `tail` = (feature blocks, real features of the last one,
+    whether this step's block is the last): no dot is issued past the real features and the one that straddles
+    their end covers the real ones only (28 features at 8 a dot: three
+    dots of 8 and one of 4), statically with one block, by the block's
+    grid index with several; a padded feature's slab keeps its zero
+    initialisation."""
     bits = 8 // vpb
     pack, one, optype = ((4, 1, jnp.int8) if int8
                          else (2, 0x3F80, jnp.bfloat16))  # bf16(1.0)
     acc_t = jnp.int32 if int8 else jnp.float32
-    ball = bins_ref[...].astype(jnp.int32)         # [f_blk, R]
-    cb = ball.shape[1]
-    wiota = 32 * lax.broadcasted_iota(jnp.int32, (geom.bp // pack, cb), 0)
-    rows = geom.dot_feats * geom.bp
+    wiota = 32 * lax.broadcasted_iota(
+        jnp.int32, (geom.bp // pack, btile.shape[1]), 0)
     obits = (32 // pack).bit_length() - 1          # log2 of the operand's bits
-    for v in range(vpb):
-        bsec = ball if vpb == 1 else (ball >> (bits * v)) & ((1 << bits) - 1)
-        bsec = bsec << obits
-        for q in range(geom.f_blk // geom.dot_feats):
-            f0 = q * geom.dot_feats
-            words = jnp.concatenate(
-                [_slab_words(bsec[f:f + 1], wiota, one)
-                 for f in range(f0, f0 + geom.dot_feats)], axis=0)
-            onehot_t = pltpu.bitcast(words, optype)   # [rows, R]
-            part = None
-            for bop in reversed(bops[v]):             # small parts first
-                d = lax.dot_general(onehot_t, bop, _CONTRACT_ROWS,
-                                    preferred_element_type=acc_t)
-                part = d if part is None else part + d
-            out_ref[0, q * rows:(q + 1) * rows, :] += part
+    bsec = btile if vpb == 1 else (btile >> (bits * v)) & ((1 << bits) - 1)
+    bsec = bsec << obits
+
+    def dot(f0, nf):
+        words = jnp.concatenate(
+            [_slab_words(bsec[f:f + 1], wiota, one)
+             for f in range(f0, f0 + nf)], axis=0)
+        onehot_t = pltpu.bitcast(words, optype)       # [nf * bp, T]
+        part = None
+        for b in reversed(bop):                       # small parts first
+            d = lax.dot_general(onehot_t, b, _CONTRACT_ROWS,
+                                preferred_element_type=acc_t)
+            part = d if part is None else part + d
+        out_ref[0, f0 * geom.bp:(f0 + nf) * geom.bp, :] += part
+
+    fblocks, last_feats, in_last = tail
+    for f0 in range(0, geom.f_blk, geom.dot_feats):
+        nf = min(max(last_feats - f0, 0), geom.dot_feats)
+        if nf < geom.dot_feats and fblocks > 1:
+            # cut in the last block only
+            pl.when(jnp.logical_not(in_last))(
+                functools.partial(dot, f0, geom.dot_feats))
+            if nf:
+                pl.when(in_last)(functools.partial(dot, f0, nf))
+        elif nf:
+            dot(f0, nf)
 
 
-def _multi_kernel_packed(bins_ref, *refs, geom: HistGeometry, vpb: int,
-                         parts: int):
-    """Pre-built gh operand, float32 [3, N] or int8 [3, N] (the MXU
-    shape of the reference's quantized histograms, ref:
-    gradient_discretizer.hpp:23 int8 packed gradients, bin.h:351-421
-    ConstructHistogramInt*: exact integer arithmetic at twice the bf16
-    rate): refs = (gh_0..gh_{vpb-1}, rl_0..rl_{vpb-1}, leafsel, out)."""
-    out_ref = refs[-1]
-    leafsel_ref = refs[-2]
-    gh_refs, rl_refs = refs[:vpb], refs[vpb:2 * vpb]
-    int8 = gh_refs[0].dtype == jnp.int8
+def _prefix_count(x):
+    """Inclusive prefix sum along the lanes of a [1, R] int32 row, and
+    its total (a scalar). The row is folded to [8, R/8], an eighth of
+    the lanes a sublane, so that a roll-and-add stage works on full
+    vregs: log2(R/8) stages along the lanes, three along the sublanes
+    for the eighths' offsets."""
+    w = x.shape[1] // 8
+    x = jnp.concatenate([x[:, j * w:(j + 1) * w] for j in range(8)], axis=0)
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    for k in range(w.bit_length() - 1):
+        x = x + jnp.where(lane >= 1 << k, pltpu.roll(x, 1 << k, 1), 0)
+    total = jnp.broadcast_to(x[:, w - 1:w], (8, 128))
+    sub = lax.broadcasted_iota(jnp.int32, total.shape, 0)
+    upto = total
+    for k in range(3):
+        upto = upto + jnp.where(sub >= 1 << k, pltpu.roll(upto, 1 << k, 0), 0)
+    x = x + (upto - total)[:, :1]
+    return (jnp.concatenate([x[j:j + 1] for j in range(8)], axis=1),
+            upto[7, 0])
+
+
+def _squeeze_lanes(words, stack, dest_row: int):
+    """Squeeze the live lanes of a row chunk to the front, in order.
+
+    Row `dest_row` of `stack` ([8, R] int32, the chunk's row operands)
+    holds each lane's destination: a live lane's count of live lanes
+    before it, a dead lane's own index; `words` ([k, R] int32, the
+    chunk's bins as packed words) rides along. A compress network of
+    log2(R) stages, lowest bit first: at stage k lane i takes lane
+    i + 2^k's values (a roll and a select; a roll by a multiple of 128
+    lanes renames vregs) if that lane has exactly 2^k left to go down
+    modulo 2^(k+1). Live lanes never meet (a later one has at least as
+    far to go), and what a lane leaves behind when it moves on has a
+    lower bit of its distance set at every later stage, so the stale
+    copy is never taken again: the destination needs no second array
+    and no clearing, and only two arrays are rolled, which is what the
+    network costs on the chip (the lane rotates, PERF.md section 6, PR
+    33). Afterwards lanes [0, live) hold the live lanes' values; the
+    lanes behind hold stale ones, which the caller marks dead."""
+    r = stack.shape[1]
+    lane = lax.broadcasted_iota(jnp.int32, (1, r), 1)
+    for k in range(r.bit_length() - 1):
+        s = 1 << k
+        stack_in = pltpu.roll(stack, r - s, 1)        # lane i sees i + s
+        to_go = lane + s - stack_in[dest_row:dest_row + 1]
+        take = (to_go & (2 * s - 1)) == s
+        words = jnp.where(take, pltpu.roll(words, r - s, 1), words)
+        stack = jnp.where(take, stack_in, stack)
+    return words, stack
+
+
+def _as_words(x):
+    """A row operand block as the row stack holds it: 32-bit integers
+    (int8 gradients widened, float32 bits kept)."""
+    if x.dtype == jnp.float32:
+        return pltpu.bitcast(x, jnp.int32)
+    return x.astype(jnp.int32)
+
+
+def _multi_step(bins_ref, refs, *, geom: HistGeometry, vpb: int,
+                int8: bool, parts: int, tail, squeeze: bool, gh_of):
+    """One grid step of every multi-leaf kernel: a dot is issued only
+    over what contributes to the histogram, statically over the real
+    features (`tail`, _accum_section_dots) and dynamically over the
+    chunk's live rows.
+
+    refs = (row operands, operand-major: operand k's bit-section v at
+    refs[k * vpb + v], the leaf ids last; leafsel [128, 1]; slot ids
+    [48, 1]; out; scratch: row stack [8, R], squeezed bins words).
+    `gh_of(rows)` (_gh_reader) turns the row operands' [1, T] rows (the
+    leaf ids left out) into the (grad, hess, weight) rows of the leaf
+    operand.
+
+    For each bit-section: the row operands go into the row stack as
+    32-bit rows. Where `squeeze` (every pass but the root's, whose rows
+    are all live), a row is live if its leaf is one of the pass's slot
+    ids; unless more than 7 of 8 are, the live lanes of the bins (as
+    packed 32-bit words) and of the stack are squeezed to the front
+    (_squeeze_lanes). Then a loop of ceil(live / k_tile) turns builds
+    the leaf operand of one sub-tile of lanes and issues its dots."""
+    out_ref, stack_ref, words_ref = refs[-3:]
+    leafsel_ref, slots_ref = refs[-5:-3]
+    row_refs = refs[:-5]
+    n_ops = len(row_refs) // vpb
+    cb = geom.row_chunk
+    t = geom.k_tile if squeeze else geom.root_tile
+    # read here, not in the loop's body, which interpret mode traces
+    # apart from the grid
+    tail = (*tail, pl.program_id(0) == tail[0] - 1)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bops = [_leaf_bop(*_gh_rows(gh_refs[v][...]), rl_refs[v][...],
-                      leafsel_ref, int8, parts) for v in range(vpb)]
-    _accum_section_dots(bins_ref, out_ref, bops, geom=geom, vpb=vpb,
-                        int8=int8)
-
-
-def _multi_kernel_fused(bins_ref, *refs, geom: HistGeometry, vpb: int,
-                        parts: int, grad_fn, has_weight: bool):
-    """Gradient-fused multi kernel: instead of reading a pre-built
-    [3, R] gh operand, read (score, label[, weight], mask) vectors and
-    compute grad/hess with the objective's pointwise function INSIDE the
-    kernel, once a row chunk (VPU math under the MXU's shadow). This
-    removes the standalone gradient/bagging element-wise pass — ghT is
-    never materialized in HBM. Works for packed (vpb>1) and raw uint8
-    (vpb=1) bins alike."""
-    out_ref = refs[-1]
-    leafsel_ref = refs[-2]
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    # row operands are laid out operand-major: operand k's section v
-    # lives at refs[k * vpb + v] (operands: score, label, [weight],
-    # mask, rl — matching _packed_multi_call's row_vecs order)
-    def op(k, v):
-        return refs[k * vpb + v][...]
-
-    iw = int(has_weight)
-    bops = []
     for v in range(vpb):
-        score, label = op(0, v), op(1, v)
-        weight = op(2, v) if has_weight else None
-        mask, rl = op(2 + iw, v), op(3 + iw, v)
-        g, h = grad_fn(score, label, weight)  # [1, cb] rows
-        bops.append(_leaf_bop(g * mask, h * mask, mask, rl, leafsel_ref,
-                              False, parts))
-    _accum_section_dots(bins_ref, out_ref, bops, geom=geom, vpb=vpb,
-                        int8=False)
+        ops = [row_refs[k * vpb + v] for k in range(n_ops)]
+        heights = [o.shape[0] for o in ops]
+        leaf_row = sum(heights) - 1
+        assert leaf_row + 1 < _STACK_ROWS, heights
+        at = 0
+        for o, hgt in zip(ops, heights):
+            stack_ref[at:at + hgt, :] = _as_words(o[...])
+            at += hgt
+        bins_src, n_tiles = bins_ref, cb // t
+        if squeeze:
+            rl = ops[-1][...]                                  # [1, R]
+            hit = slots_ref[0:8, :] == rl                      # [8, R]
+            for j in range(8, slots_ref.shape[0], 8):
+                hit |= slots_ref[j:j + 8, :] == rl
+            live = jnp.max(hit.astype(jnp.int32), axis=0, keepdims=True)
+            dead_before, n_dead = _prefix_count(1 - live)
+            n_live = cb - n_dead
+            sparse = n_live * 8 <= cb * 7
+            dest_row = leaf_row + 1
+
+            @pl.when(sparse)
+            def _squeeze():
+                lane = lax.broadcasted_iota(jnp.int32, (1, cb), 1)
+                stack_ref[dest_row:dest_row + 1, :] = lane - jnp.where(
+                    live != 0, dead_before, 0)
+                words, stack = _squeeze_lanes(
+                    bins_ref.bitcast(jnp.int32)[...], stack_ref[...],
+                    dest_row)
+                words_ref[...] = words
+                stack_ref[...] = stack
+                stack_ref[leaf_row:leaf_row + 1, :] = jnp.where(
+                    lane < n_live, stack[leaf_row:leaf_row + 1], -1)
+
+            @pl.when(jnp.logical_not(sparse))
+            def _keep():
+                words_ref[...] = bins_ref.bitcast(jnp.int32)[...]
+
+            bins_src = words_ref.bitcast(bins_ref.dtype)
+            n_tiles = jnp.where(sparse, (n_live + (t - 1)) // t, cb // t)
+
+        def tile(i, carry):
+            at = pl.ds(pl.multiple_of(i * t, t), t)
+            stack = stack_ref[:, at]
+            rows, r0 = [], 0
+            for o, hgt in zip(ops, heights):
+                x = stack[r0:r0 + hgt]
+                rows.append(pltpu.bitcast(x, jnp.float32)
+                            if o.dtype == jnp.float32 else x)
+                r0 += hgt
+            bop = _leaf_bop(*gh_of(rows[:-1]), rows[-1], leafsel_ref, int8,
+                            parts)
+            _accum_section_dots(bins_src[:, at].astype(jnp.int32), out_ref,
+                                bop, geom=geom, vpb=vpb, v=v, int8=int8,
+                                tail=tail)
+            return carry
+
+        lax.fori_loop(0, n_tiles, tile, 0)
+
+
+def _gh_reader(grad_fn, has_weight: bool):
+    """The `gh_of` of a kernel's operand reader. Without `grad_fn` the
+    row operands are (gh [3, R], leaf ids): a pre-built gh operand,
+    float32 or int8 (the MXU shape of the reference's quantized
+    histograms, ref: gradient_discretizer.hpp:23 int8 packed gradients,
+    bin.h:351-421 ConstructHistogramInt*: exact integer arithmetic at
+    twice the bf16 rate). With it they are (score, label, [weight], mask,
+    leaf ids) and grad/hess come from the objective's pointwise function
+    INSIDE the kernel, once a sub-tile of live rows (VPU math under the
+    MXU's shadow): the standalone gradient/bagging element-wise pass
+    goes and ghT is never materialized in HBM, for packed (vpb>1) and
+    raw uint8 (vpb=1) bins alike."""
+    if grad_fn is None:
+        return lambda rows: (rows[0][0:1], rows[0][1:2], rows[0][2:3])
+
+    def gh_of(rows):
+        score, label = rows[0], rows[1]
+        weight = rows[2] if has_weight else None
+        mask = rows[2 + int(has_weight)]
+        g, h = grad_fn(score, label, weight)          # [1, T] rows
+        return g * mask, h * mask, mask
+    return gh_of
 
 
 def _leafsel_col(leaf_ids, num_slots: int):
@@ -356,18 +527,38 @@ def _leafsel_col(leaf_ids, num_slots: int):
                      -2).astype(jnp.int32)[:, None]
 
 
+def _slots_col(leaf_ids, num_slots: int):
+    """[num_slots up to whole sublane tiles, 1] slot ids, -2 beyond
+    them: what a row's leaf is compared with to find the live rows."""
+    pad = (-num_slots) % 8
+    return jnp.pad(leaf_ids.astype(jnp.int32), (0, pad),
+                   constant_values=-2)[:, None]
+
+
 # bf16 passes over the leaf operand for each tpu_hist_precision: the
 # one-hot side is exact, so XLA's 3 and 6 passes come down to 2 and 3
 _PARTS = {lax.Precision.DEFAULT: 1, lax.Precision.HIGH: 2,
           lax.Precision.HIGHEST: 3}
 
 
-def _packed_multi_call(bins_fm, row_vecs, leaf_ids, kernel, *,
-                       max_bins: int, num_slots: int, **kw):
+# slots of a pass: the MXU's 128 output columns hold 42 x (grad, hess,
+# weight). Every pass runs the kernel at all 42, its slot ids padded with
+# -2, so that the passes of a tree share one traced and compiled kernel
+# (and the root's one more) whatever their slot counts
+_MAX_SLOTS = 128 // 3
+
+
+def _packed_multi_call(bins_fm, row_vecs, leaf_ids, *, max_bins: int,
+                       num_slots: int, **kw):
     """Histograms [num_slots, F, B, 3] of the multi-leaf kernels (int32
-    for int8 operands, else float32): `_multi_slabs` less the padding."""
-    out = _multi_slabs(bins_fm, row_vecs, leaf_ids, kernel,
-                       max_bins=max_bins, num_slots=num_slots, **kw)
+    for int8 operands, else float32): `_multi_slabs` less the padding.
+    row_vecs: list of ([N] or [N, k] array, pad_value) pairs."""
+    assert num_slots <= _MAX_SLOTS, "num_slots capped at 42 by MXU columns"
+    ids = jnp.pad(leaf_ids.astype(jnp.int32), (0, _MAX_SLOTS - num_slots),
+                  constant_values=-2)
+    out = _multi_slabs(bins_fm, tuple(v for v, _ in row_vecs), ids,
+                       pads=tuple(p for _, p in row_vecs),
+                       max_bins=max_bins, **kw)
     num_features = bins_fm.shape[0]
     # [F', bp, 128] -> [F, B, J, 3] -> [J, F, B, 3]: padded features,
     # a slab's rows at max_bins and beyond (never hit) and the columns
@@ -377,20 +568,27 @@ def _packed_multi_call(bins_fm, row_vecs, leaf_ids, kernel, *,
     return jnp.moveaxis(out, 2, 0)
 
 
-def _multi_slabs(bins_fm, row_vecs, leaf_ids, kernel, *, max_bins: int,
-                 num_slots: int, int8: bool, precise=None, interpret=None,
-                 name: str):
-    """The one pallas_call of the multi-leaf kernels.
+@functools.partial(jax.jit, static_argnames=(
+    "pads", "max_bins", "int8", "precise", "interpret", "all_live", "name",
+    "grad_fn", "has_weight"))
+def _multi_slabs(bins_fm, vecs, leaf_ids, *, pads, max_bins: int,
+                 int8: bool, precise=None, interpret=None,
+                 all_live: bool = False, name: str, grad_fn=None,
+                 has_weight: bool = False):
+    """The one pallas_call of the multi-leaf kernels, jitted on nothing
+    that differs between the passes of a tree but `all_live`.
 
     bins_fm: PackedBins, or raw [F, N] uint8/uint16 bins (padded here
-    to whole row chunks: bin 0 under a leaf id of -1). row_vecs: list of
-    ([N] or [N, k] array, pad_value) pairs; each becomes vpb lane-dense
-    operands ([1, N] or [k, N]) blocked at section-strided offsets, so
-    that grid step i sees the rows of byte block i's bit-sections.
-    Returns every feature's slab, [F padded to whole blocks, bp, 128]:
-    row b of a slab is bin b, column 3 * slot + channel.
+    to whole row chunks: bin 0 under a leaf id of -1). vecs: the row
+    operands, [N] or [N, k] arrays, the leaf ids last, and `pads` their
+    pad values; each becomes vpb lane-dense operands ([1, N] or [k, N])
+    blocked at section-strided offsets, so that grid step i sees the
+    rows of byte block i's bit-sections. leaf_ids: [_MAX_SLOTS] slot
+    ids, -2 where a slot is unused. `grad_fn` / `has_weight`: the
+    gradient-fused reader (_gh_reader). Returns every feature's slab,
+    [F padded to whole blocks, bp, 128]: row b of a slab is bin b,
+    column 3 * slot + channel.
     """
-    assert num_slots * 3 <= 128, "num_slots capped at 42 by MXU columns"
     itemsize = 1 if int8 else 2
     num_features, n = bins_fm.shape
     if isinstance(bins_fm, PackedBins):
@@ -414,8 +612,8 @@ def _multi_slabs(bins_fm, row_vecs, leaf_ids, kernel, *, max_bins: int,
                              memory_space=pltpu.VMEM)]
     operands = [data]
     # operand-major layout (all of operand k's sections consecutively) —
-    # the kernels index refs[k * vpb + v]
-    for vec, pad_val in row_vecs:
+    # the step indexes refs[k * vpb + v]
+    for vec, pad_val in zip(vecs, pads):
         # padded as the caller holds it ([N] or [N, k]), then turned
         # lane-dense: the pad of a 1-D vector reads as [1, N] for free,
         # where padding its [1, N] view costs a copy of the vector
@@ -427,25 +625,39 @@ def _multi_slabs(bins_fm, row_vecs, leaf_ids, kernel, *, max_bins: int,
                 (arr.shape[0], cb), lambda j, i, v=v: (0, i + v * nsb),
                 memory_space=pltpu.VMEM))
             operands.append(arr)
-    in_specs.append(pl.BlockSpec((128, 1), lambda j, i: (0, 0),
-                                 memory_space=pltpu.VMEM))
-    operands.append(_leafsel_col(leaf_ids, num_slots))
+    for col in (_leafsel_col(leaf_ids, _MAX_SLOTS),
+                _slots_col(leaf_ids, _MAX_SLOTS)):
+        in_specs.append(pl.BlockSpec(col.shape, lambda j, i: (0, 0),
+                                     memory_space=pltpu.VMEM))
+        operands.append(col)
 
     fblocks = fp // f_blk
     rows = f_blk * geom.bp
     grid = (fblocks, nsb)
+    tail = (fblocks, num_features - (fblocks - 1) * f_blk)
     _note_geometry(name, geom, vpb=vpb, grid=grid, int8=int8,
                    num_features=num_features, max_bins=max_bins,
-                   rows=n_rows)
+                   rows=n_rows, tail=tail)
     parts = 1 if int8 else _PARTS[resolve_precision(precise)]
+
+    def kernel(bins_ref, *refs):
+        _multi_step(bins_ref, refs, geom=geom, vpb=vpb, parts=parts,
+                    int8=int8, tail=tail, squeeze=not all_live,
+                    gh_of=_gh_reader(grad_fn, has_weight))
+
     out = pl.pallas_call(
-        functools.partial(kernel, geom=geom, vpb=vpb, parts=parts),
+        kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(
             (fblocks, rows, 128), jnp.int32 if int8 else jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((_STACK_ROWS, cb), jnp.int32),
+            pltpu.VMEM((f_blk * data.dtype.itemsize // 4, cb), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * _VMEM_LIMIT),
         interpret=_resolve_interpret(interpret),
         name=name,
     )(*operands)
@@ -453,18 +665,24 @@ def _multi_slabs(bins_fm, row_vecs, leaf_ids, kernel, *, max_bins: int,
 
 
 def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
-                   rows):
+                   rows, tail):
     """Publish a kernel's geometry as it is traced: in the list
     ``global_metrics.meta["hist_geometry"]`` beside ``hist_traffic``,
     and as the args of a ``hist`` program span (``lgbm/hist`` in a
     profiler session). It says whether the large step engaged at a
     shape, or fell back for VMEM or for a section no large chunk
-    divides."""
+    divides; `dots_per_step` counts the dots a sub-tile of the last
+    feature block issues and `tail_features` the features of its last
+    dot (those of a whole dot where nothing is cut)."""
     from ..obs.metrics import global_metrics
     from ..obs.trace import global_tracer
+    last_feats = tail[1]
     rec = {"kernel": name, "features": num_features, "max_bins": max_bins,
            "rows": rows, "bp": geom.bp, "features_per_step": geom.f_blk,
            "features_per_dot": geom.dot_feats, "row_chunk": geom.row_chunk,
+           "k_tile": geom.k_tile, "root_tile": geom.root_tile,
+           "dots_per_step": -(-last_feats // geom.dot_feats),
+           "tail_features": (last_feats - 1) % geom.dot_feats + 1,
            "grid_steps": grid[0] * grid[1], "pack_factor": vpb,
            "operand": "int8" if int8 else "bfloat16"}
     seen = global_metrics.meta.setdefault("hist_geometry", [])
@@ -476,10 +694,11 @@ def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
 
 @functools.partial(jax.jit,
                    static_argnames=("max_bins", "num_slots", "precise",
-                                    "interpret"))
+                                    "interpret", "all_live"))
 def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
                       leaf_ids: jax.Array, *, max_bins: int, num_slots: int,
-                      precise="highest", interpret=None) -> jax.Array:
+                      precise="highest", interpret=None,
+                      all_live: bool = False) -> jax.Array:
     """Histograms of up to `num_slots` leaves in ONE pass over the rows.
 
     The one-hot (bins) operand is leaf-independent, so packing the MXU's
@@ -496,18 +715,18 @@ def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
     packed = isinstance(bins_fm, PackedBins)
     return _packed_multi_call(
         bins_fm, [(ghT, 0.0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
-        _multi_kernel_packed,
         max_bins=max_bins, num_slots=num_slots, int8=False,
-        precise=precise, interpret=interpret,
+        precise=precise, interpret=interpret, all_live=all_live,
         name="lgbm_hist_multi_packed" if packed else "lgbm_hist_multi")
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("max_bins", "num_slots", "interpret"))
+                   static_argnames=("max_bins", "num_slots", "interpret",
+                                    "all_live"))
 def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
                            row_leaf: jax.Array, leaf_ids: jax.Array, *,
-                           max_bins: int, num_slots: int,
-                           interpret=None) -> jax.Array:
+                           max_bins: int, num_slots: int, interpret=None,
+                           all_live: bool = False) -> jax.Array:
     """Quantized multi-leaf histograms: one pass, int32 accumulation.
 
     ghT_i8: [N, 3] int8 (quantized grad, quantized hess, {0,1} weight),
@@ -519,19 +738,19 @@ def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
     packed = isinstance(bins_fm, PackedBins)
     return _packed_multi_call(
         bins_fm, [(ghT_i8, 0), (row_leaf.astype(jnp.int32), -1)], leaf_ids,
-        _multi_kernel_packed,
         max_bins=max_bins, num_slots=num_slots, int8=True,
-        interpret=interpret,
+        interpret=interpret, all_live=all_live,
         name="lgbm_hist_multi_packed" if packed else "lgbm_hist_multi_int8")
 
 
 @functools.partial(jax.jit,
                    static_argnames=("grad_fn", "max_bins", "num_slots",
-                                    "precise", "interpret"))
+                                    "precise", "interpret", "all_live"))
 def hist_pallas_multi_fused(bins_fm, score, label, weight, mask, row_leaf,
                             leaf_ids, *, grad_fn, max_bins: int,
                             num_slots: int, precise="highest",
-                            interpret=None) -> jax.Array:
+                            interpret=None,
+                            all_live: bool = False) -> jax.Array:
     """Multi-leaf histograms with the gradient pass fused in: operands
     are (score, label[, weight], mask) instead of a pre-built ghT, and
     grad_fn (the objective's pointwise gradient) runs inside the kernel.
@@ -544,11 +763,10 @@ def hist_pallas_multi_fused(bins_fm, score, label, weight, mask, row_leaf,
     vecs.append((mask.astype(jnp.float32), 0.0))
     vecs.append((row_leaf.astype(jnp.int32), -1))
     return _packed_multi_call(
-        bins_fm, vecs, leaf_ids,
-        functools.partial(_multi_kernel_fused, grad_fn=grad_fn,
-                          has_weight=has_weight),
-        max_bins=max_bins, num_slots=num_slots, int8=False,
-        precise=precise, interpret=interpret, name="lgbm_hist_multi_packed")
+        bins_fm, vecs, leaf_ids, max_bins=max_bins, num_slots=num_slots,
+        int8=False, precise=precise, interpret=interpret, all_live=all_live,
+        name="lgbm_hist_multi_packed", grad_fn=grad_fn,
+        has_weight=has_weight)
 
 
 def _hist_kernel_packed(bins_ref, *refs, f_blk: int, max_bins: int,
@@ -697,11 +915,14 @@ def hist_multi_xla(bins_fm, ghT, row_leaf, leaf_ids, *, max_bins: int,
 def hist_multi(bins_fm, ghT, row_leaf, leaf_ids, *, max_bins: int,
                num_slots: int, impl: str = "xla",
                precision: str = "highest",
-               deterministic: bool = False) -> jax.Array:
+               deterministic: bool = False,
+               all_live: bool = False) -> jax.Array:
+    """`all_live`: the caller knows every row to be in a slot's leaf (the
+    root's pass); the kernel's step then looks for no live rows."""
     if impl == "pallas" and not deterministic:
         return hist_pallas_multi(bins_fm, ghT, row_leaf, leaf_ids,
                                  max_bins=max_bins, num_slots=num_slots,
-                                 precise=precision)
+                                 precise=precision, all_live=all_live)
     # XLA path (CPU tests, deterministic_hist): f32 dots are exact
     # regardless of precision
     if isinstance(bins_fm, PackedBins):
@@ -746,7 +967,8 @@ def hist_multi_int8_xla(bins_fm, ghT_i8, row_leaf, leaf_ids, *,
 
 
 def hist_multi_int8(bins_fm, ghT_i8, row_leaf, leaf_ids, *, max_bins: int,
-                    num_slots: int, impl: str = "xla") -> jax.Array:
+                    num_slots: int, impl: str = "xla",
+                    all_live: bool = False) -> jax.Array:
     """Quantized multi-leaf histogram dispatch: the pallas MXU kernel on
     device backends, the exact-integer XLA contraction elsewhere. Both
     return identical int32 histograms (asserted in tests/test_waved.py),
@@ -755,7 +977,8 @@ def hist_multi_int8(bins_fm, ghT_i8, row_leaf, leaf_ids, *, max_bins: int,
     if impl == "pallas":
         return hist_pallas_multi_int8(bins_fm, ghT_i8, row_leaf, leaf_ids,
                                       max_bins=max_bins,
-                                      num_slots=num_slots)
+                                      num_slots=num_slots,
+                                      all_live=all_live)
     return hist_multi_int8_xla(bins_fm, ghT_i8, row_leaf, leaf_ids,
                                max_bins=max_bins, num_slots=num_slots)
 

@@ -43,6 +43,20 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+@pytest.fixture
+def release_executables():
+    """Drop the process's compiled programs after the test. An
+    interpret-mode run of the multi-leaf histogram kernels compiles to an
+    XLA:CPU executable of some 1,100 memory mappings, and a process may
+    hold 65,530 (`vm.max_map_count`): tests/test_waved.py's step cases
+    alone came to 46k, and the worker that had run them died in the next
+    file's compiles (a segmentation fault inside XLA's compile, serialize
+    or deserialize; PR 33). `jax.clear_caches()` gives the mappings back;
+    what is needed again comes from the persistent cache."""
+    yield
+    jax.clear_caches()
+
+
 # Quick tier (VERDICT r4 #9): `pytest -m quick` runs a <=15-min subset —
 # one config per family + the semantics/unit tests — so verification
 # stops competing with development; the full 2h+ grid stays the default

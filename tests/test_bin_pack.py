@@ -459,6 +459,10 @@ def test_published_geometry_at_the_benchmark_shape(kind):
     assert got["features_per_step"] == 32 and got["pack_factor"] == 1
     assert got["row_chunk"] >= 4096 and got["features_per_dot"] * 64 >= 512
     assert got["grid_steps"] == -(-n // got["row_chunk"]) < 20_000
+    # 28 real features of the block's 32: three dots of 8 and one of 4
+    assert (got["dots_per_step"], got["tail_features"]) == (4, 4)
+    assert got["row_chunk"] % got["k_tile"] == 0 == \
+        got["row_chunk"] % got["root_tile"]
 
 
 # ---------------------------------------------------------------------------

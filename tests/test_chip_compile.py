@@ -100,8 +100,10 @@ def _bins(s, n, max_bins, vpb=1, features=F):
     return PackedBins(s((features, section), jnp.uint8), n, vpb)
 
 
-def _kernel(kind, s, bins, n, max_bins, precise="default"):
-    """(function, operand shapes) of one Pallas entry point."""
+def _kernel(kind, s, bins, n, max_bins, precise="default", **multi):
+    """(function, operand shapes) of one Pallas entry point; `multi`:
+    further options of the multi-leaf ones (all_live: the root's pass,
+    whose step does not look for live rows)."""
     vec, rl = s((n,), jnp.float32), s((n,), jnp.int32)
     ids = s((SLOTS,), jnp.int32)
     if kind == "single":
@@ -111,17 +113,17 @@ def _kernel(kind, s, bins, n, max_bins, precise="default"):
     if kind == "multi":
         return (functools.partial(ph.hist_pallas_multi, max_bins=max_bins,
                                   num_slots=SLOTS, precise=precise,
-                                  interpret=False),
+                                  interpret=False, **multi),
                 (bins, s((n, 3), jnp.float32), rl, ids))
     if kind == "fused":  # the default TPU path for objective=binary
         return (functools.partial(ph.hist_pallas_multi_fused,
                                   grad_fn=_binary_grad, max_bins=max_bins,
                                   num_slots=SLOTS, precise=precise,
-                                  interpret=False),
+                                  interpret=False, **multi),
                 (bins, vec, vec, None, vec, rl, ids))
     assert kind == "int8"  # use_quantized_grad's kernel
     return (functools.partial(ph.hist_pallas_multi_int8, max_bins=max_bins,
-                              num_slots=SLOTS, interpret=False),
+                              num_slots=SLOTS, interpret=False, **multi),
             (bins, s((n, 3), jnp.int8), rl, ids))
 
 
@@ -145,6 +147,17 @@ def test_kernel_is_accepted(one_chip, kind, max_bins, vpb):
     int32) and fused-B4-2bit overran the 16 MiB of scoped VMEM."""
     s = _shapes(one_chip)
     fn, args = _kernel(kind, s, _bins(s, N, max_bins, vpb), N, max_bins)
+    _compiles_to_mosaic(fn, *args)
+
+
+@pytest.mark.parametrize("kind,max_bins,vpb", [
+    ("int8", 63, 1), ("fused", 63, 1), ("multi", 15, 2), ("int8", 3, 4)])
+def test_root_pass_is_accepted(one_chip, kind, max_bins, vpb):
+    """The root's pass (`all_live`, told at the call site): the same step
+    less the search for live rows and the squeeze."""
+    s = _shapes(one_chip)
+    fn, args = _kernel(kind, s, _bins(s, N, max_bins, vpb), N, max_bins,
+                       all_live=True)
     _compiles_to_mosaic(fn, *args)
 
 
@@ -233,21 +246,42 @@ def llo_counts(llo_dir, fn, *args):
         re.findall(r'"?llo\.(v[a-z_0-9]+)', dump.read_text()))
 
 
+def _alu(ops):
+    return sum(n for op, n in ops.items() if op not in _NOT_ALU)
+
+
 def test_int8_step_vector_alu_count(one_chip, llo_dir):
     """The int8 step at the benchmark's shape was bound by the VPU: 41k
     vector-ALU operations per 2048 rows x 32 features (three selects a
     one-hot vreg, the mask's cast to int8, the leaf operand rebuilt per 8
     features), beside 4.1k cycles of matmul. Bin-aligned slabs built as
-    packed words take 11.8k (PERF.md section 6, PR 29), of which 4.1k add
-    the matmuls' popped results; the count cannot creep back unseen."""
+    packed words took 11.8k (PERF.md section 6, PR 29), of which 4.1k add
+    the matmuls' popped results. Since PR 33 the step's program is a loop
+    whose body multiplies one sub-tile of rows over the 28 real features
+    (`root_tile` rows in the root's pass, which does nothing else:
+    10.4k per 2048 rows; `k_tile` rows of the squeezed chunk in the
+    others), and before the loop the squeeze of the chunk's live rows
+    (live mask, prefix count, compress network: under a third of the
+    step; PERF.md section 6, PR 33). Neither count can creep back
+    unseen."""
     s = _shapes(one_chip)
-    fn, args = _kernel("int8", s, _bins(s, N, 63), N, 63)
-    ops = llo_counts(llo_dir, fn, *args)
     geom = ph._fb_geometry(F, 63, 1, 1, rows=N)
-    per = 2048 * 32 / (geom.row_chunk * geom.f_blk)
-    alu = sum(n for op, n in ops.items() if op not in _NOT_ALU)
-    assert ops["vmatmul"] * per == 1024 and ops["vlatch"] * per <= 256
-    assert 4096 < alu * per < 20_000, ops
+    fn, args = _kernel("int8", s, _bins(s, N, 63), N, 63, all_live=True)
+    root = llo_counts(llo_dir, fn, *args)
+    turns = 2048 / geom.root_tile       # of the root's loop per 2048 rows
+    # three dots of 8 features and one of 4: 7/8 of the padded block's
+    assert root["vmatmul"] * turns == 1024 * 28 / 32
+    assert root["vlatch"] * turns <= 256
+    assert 4096 < _alu(root) * turns < 12_000, root
+    fn, args = _kernel("int8", s, _bins(s, N, 63), N, 63)
+    step = llo_counts(llo_dir, fn, *args)
+    assert step["vmatmul"] * 2048 / geom.k_tile == 1024 * 28 / 32
+    # the loop's body by the root's count a row (its own has one more add
+    # a popped result and sub-tile); the rest is the squeeze of one chunk
+    body = _alu(root) * geom.k_tile / geom.root_tile
+    squeeze = (_alu(step) - body) * 2048 / geom.row_chunk
+    assert 0 < squeeze < 3_900, step
+    assert _alu(root) * turns + squeeze < 16_000
 
 
 # ---------------------------------------------------------------------------

@@ -163,8 +163,9 @@ def test_waved_with_bagging_and_feature_fraction():
 # pallas_histogram._fb_geometry: a slab of one tile and of several (63 and
 # 64 bins give 64 rows, 255 and 256 give 256, 300 gives 304 or 320 and
 # uint16 ids; 15/16 and 3/4 bins the 4-bit and 2-bit PackedBins), a
-# padded feature block (5, 28, 70), more than one block (70 features at
-# 255 bins, 2000 at 63), a row count no chunk divides, a section only the smallest
+# feature block whose last dot is cut or not issued (5, 28, 70) or whole
+# (32), more than one block (70 features at 255 bins with a tail, 2000 at
+# 63 without), a row count no chunk divides, a section only the smallest
 # chunk divides (9000 rows at 2 a byte: 6144 bytes)
 STEP_CASES = [(63, 1, 28, 42, 5000), (63, 1, 5, 1, 2500),
               (64, 1, 70, 8, 2500), (255, 1, 5, 8, 2500),
@@ -172,9 +173,12 @@ STEP_CASES = [(63, 1, 28, 42, 5000), (63, 1, 5, 1, 2500),
               (300, 1, 5, 8, 2500), (300, 1, 28, 42, 2100),
               (15, 2, 28, 8, 9000), (16, 2, 5, 42, 4100),
               (3, 4, 28, 8, 9000), (4, 4, 70, 1, 8200),
+              (63, 1, 32, 8, 2500),
               # the wide cell's shape (PR 32): many feature blocks a row
               # chunk, the last one padded
               (63, 1, 2000, 42, 2100)]
+# the live-row cases of the same check (which rows of a pass are live) are
+# tests/test_hist_live_rows.py's: another file, so another worker
 
 
 def _binary_grad(score, label, weight):
@@ -182,15 +186,45 @@ def _binary_grad(score, label, weight):
     return p - label, p * (1.0 - p)
 
 
+def _step_rows(r, live, slots, n):
+    """(row_leaf [n], leaf_ids [slots], live slots) of a `live` case:
+    "some": a few leaves of many; "none": no slot matches; "one": one row
+    a 2048-row chunk; a share; "dense": over 7/8, the chunk that is not
+    squeezed; "root": every row in the one slot, told at the call site;
+    "end": the live rows at the end of each chunk."""
+    if live == "root":
+        return np.full(n, 9), np.array([9] + [-2] * (slots - 1)), 1
+    if live == "some":
+        k = min(slots, 4)
+        ids = list(r.permutation(slots + 3)[:k]) + [-2] * (slots - k)
+        return r.randint(0, slots + 3, n), np.array(ids), k
+    ids = np.arange(slots) + 5
+    row = np.arange(n)
+    on = {"none": np.zeros(n, bool), "one": row % 2048 == 7,
+          "dense": r.rand(n) < 0.95, "end": row % 2048 >= 1748}.get(
+              live, r.rand(n) < (live if isinstance(live, float) else 0))
+    return np.where(on, r.randint(5, slots + 5, n),
+                    r.randint(100, 120, n)), ids, slots
+
+
+@pytest.mark.usefixtures("release_executables")
 @pytest.mark.parametrize("kind", ["int8", "float", "fused"])
 @pytest.mark.parametrize("max_bins,vpb,f,slots,n", STEP_CASES)
 def test_shared_step_matches_xla_twins(kind, max_bins, vpb, f, slots, n):
-    """The one step every multi-leaf Pallas kernel runs
-    (`_accum_section_dots`, interpret mode), under each of its three
+    """The one step every multi-leaf Pallas kernel runs (`_multi_step`
+    with `_accum_section_dots`, interpret mode), under each of its three
     operand readers, against the XLA twins: bit for bit on int8, to
     float32 rounding on float (tpu_hist_precision=highest: three bf16
     passes over the leaf operand); padded slots stay empty, and so do a
-    slab's rows at max_bins and beyond."""
+    padded feature's slab and a slab's rows at max_bins and beyond. Rows
+    of a few leaves of many are live here (tests/test_hist_live_rows.py
+    has the other cases)."""
+    check_shared_step(kind, "some", max_bins, vpb, f, slots, n)
+
+
+def check_shared_step(kind, live, max_bins, vpb, f, slots, n):
+    """The multi-leaf kernels' step against the XLA twins with the rows
+    of a `live` case (_step_rows) live."""
     from lightgbm_tpu.ops import pallas_histogram as ph
     from lightgbm_tpu.ops.bin_pack import pack_bins_host, to_device
     r = np.random.RandomState(max_bins + f + n)
@@ -202,11 +236,11 @@ def test_shared_step_matches_xla_twins(kind, max_bins, vpb, f, slots, n):
            else jnp.asarray(bins))
     assert getattr(arg, "vpb", 1) == vpb
     mask = r.rand(n) < 0.8
-    row_leaf = jnp.asarray(r.randint(0, slots + 3, n), jnp.int32)
-    live = min(slots, 4)
-    leaf_ids = jnp.asarray(list(r.permutation(slots + 3)[:live])
-                           + [-2] * (slots - live), jnp.int32)
+    row_leaf, leaf_ids, n_live = _step_rows(r, live, slots, n)
+    row_leaf = jnp.asarray(row_leaf, jnp.int32)
+    leaf_ids = jnp.asarray(leaf_ids, jnp.int32)
     kw = dict(max_bins=max_bins, num_slots=slots)
+    run = dict(interpret=True, all_live=live == "root", **kw)
     m = jnp.asarray(mask, jnp.float32)
     if kind == "int8":
         gh = jnp.asarray(np.stack([r.randint(-63, 64, n) * mask,
@@ -214,9 +248,8 @@ def test_shared_step_matches_xla_twins(kind, max_bins, vpb, f, slots, n):
                          jnp.int8)
         want = ph.hist_multi_int8_xla(jnp.asarray(bins), gh, row_leaf,
                                       leaf_ids, **kw)
-        vecs, kernel = [(gh, 0)], ph._multi_kernel_packed
-        got = ph.hist_pallas_multi_int8(arg, gh, row_leaf, leaf_ids,
-                                        interpret=True, **kw)
+        vecs, reader = [(gh, 0)], {}
+        got = ph.hist_pallas_multi_int8(arg, gh, row_leaf, leaf_ids, **run)
         assert got.dtype == jnp.int32
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     else:
@@ -227,23 +260,28 @@ def test_shared_step_matches_xla_twins(kind, max_bins, vpb, f, slots, n):
         want = ph.hist_multi_xla(jnp.asarray(bins), gh, row_leaf, leaf_ids,
                                  **kw)
         if kind == "float":
-            vecs, kernel = [(gh, 0.0)], ph._multi_kernel_packed
-            got = ph.hist_pallas_multi(arg, gh, row_leaf, leaf_ids,
-                                       interpret=True, **kw)
+            vecs, reader = [(gh, 0.0)], {}
+            got = ph.hist_pallas_multi(arg, gh, row_leaf, leaf_ids, **run)
         else:
             vecs = [(score, 0.0), (label, 0.0), (m, 0.0)]
-            kernel = functools.partial(ph._multi_kernel_fused,
-                                       grad_fn=_binary_grad,
-                                       has_weight=False)
+            reader = dict(grad_fn=_binary_grad, has_weight=False)
             got = ph.hist_pallas_multi_fused(
                 arg, score, label, None, m, row_leaf, leaf_ids,
-                grad_fn=_binary_grad, interpret=True, **kw)
+                grad_fn=_binary_grad, **run)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-6, atol=2e-5)
-    assert np.all(np.asarray(got[live:]) == 0)
+    assert np.all(np.asarray(got[n_live:]) == 0)
+    if live == "none":
+        assert not np.asarray(got).any()
+    else:
+        assert np.asarray(got).any()
+    vecs.append((row_leaf, -1))
     slabs = np.asarray(ph._multi_slabs(
-        arg, vecs + [(row_leaf, -1)], leaf_ids, kernel, int8=kind == "int8",
-        precise="highest", interpret=True, name="lgbm_hist_multi", **kw))
+        arg, tuple(v for v, _ in vecs),
+        jnp.pad(leaf_ids, (0, ph._MAX_SLOTS - slots), constant_values=-2),
+        pads=tuple(p for _, p in vecs), max_bins=max_bins,
+        int8=kind == "int8", precise="highest", interpret=True,
+        all_live=live == "root", name="lgbm_hist_multi", **reader))
     from lightgbm_tpu.obs.metrics import global_metrics
     geom, = [g for g in global_metrics.meta["hist_geometry"]
              if (g["kernel"], g["features"], g["max_bins"], g["pack_factor"],
@@ -251,7 +289,13 @@ def test_shared_step_matches_xla_twins(kind, max_bins, vpb, f, slots, n):
                                              vpb, kind == "int8")]
     assert slabs.shape[0] % geom["features_per_step"] == 0
     assert slabs.shape[0] >= f and slabs.shape[1] == geom["bp"] >= max_bins
-    assert not slabs[:, max_bins:].any() and not slabs[f:, 1:].any()
+    # the dots cover the real features only: a padded feature's slab is
+    # never written, a real one's rows past max_bins never hit
+    assert not slabs[:, max_bins:].any() and not slabs[f:].any()
+    last = f - (-(-f // geom["features_per_step"]) - 1) \
+        * geom["features_per_step"]
+    assert geom["dots_per_step"] == -(-last // geom["features_per_dot"])
+    assert geom["tail_features"] == (last - 1) % geom["features_per_dot"] + 1
     np.testing.assert_array_equal(
         slabs[:f, :max_bins, :3 * slots].reshape(f, max_bins, slots, 3),
         np.moveaxis(np.asarray(got), 0, 2))
@@ -476,3 +520,72 @@ def test_batched_partition_through_grower(case):
         assert 3 in outs[True][2]
     for a, b in zip(outs[False], outs[True]):
         np.testing.assert_array_equal(a, b)
+
+
+def test_hist_live_rows_counter():
+    """`global_metrics.meta["hist_live_rows"]`: while a tracer session is
+    live each grown tree leaves, a histogram pass, the rows that pass
+    multiplied: every row for the root, then each wave's smaller children
+    by the wave schedule (the last wave's pass is skipped), counted here
+    from the model's own child pointers. Untraced, nothing is recorded,
+    and traced or not an iteration fetches nothing from the device for
+    it: the trees stay there until the model is asked for."""
+    from lightgbm_tpu.learner import HIST_SLOTS, _wave_schedule
+    from lightgbm_tpu.obs.metrics import global_metrics
+    from lightgbm_tpu.obs.trace import global_tracer
+    X, y = make_binary(3000)
+    params = {"objective": "binary", "num_leaves": 31, "verbosity": -1,
+              "min_data_in_leaf": 5}
+    global_metrics.meta.pop("hist_live_rows", None)
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y))
+    bst.update()
+    bst.update()
+    # the counter reads a tree as it reaches the host, and an iteration
+    # brings none there
+    assert not bst._gbdt._host_models and len(bst._gbdt._device_records) == 2
+    bst.model_to_string()
+    assert "hist_live_rows" not in global_metrics.meta
+
+    was = global_tracer.enabled
+    global_tracer.enable()
+    try:
+        bst = lgb.Booster(params, lgb.Dataset(X, label=y))
+        bst.update()
+        bst.update()
+        bst.model_to_string()
+    finally:
+        global_tracer.enabled = was
+    trees = [t for it in bst._gbdt._host_models for t in it]
+    recorded = global_metrics.meta["hist_live_rows"]
+    assert len(recorded) == len(trees) == 2
+    schedule = _wave_schedule(31, bst._gbdt._resolved_wave_max(),
+                              HIST_SLOTS)
+    for tree, passes in zip(trees, recorded):
+        assert tree.num_leaves == 31
+
+        def rows(child):
+            return (tree.leaf_count[~child] if child < 0
+                    else tree.internal_count[child])
+
+        small = [min(rows(tree.left_child[s]), rows(tree.right_child[s]))
+                 for s in range(tree.num_leaves - 1)]
+        assert [p["pass"] for p in passes] == ["root"] + [
+            f"w{i:02d}" for i in range(len(schedule) - 1)]
+        assert passes[0]["rows_live"] == passes[0]["rows_passed"] == 3000
+        at = 0
+        for p, w in zip(passes[1:], schedule):
+            assert p["slots"] == w and p["rows_passed"] == 3000
+            assert p["rows_live"] == sum(small[at:at + w]) <= 1500
+            at += w
+    # K-sub-tiles, with the kernel's chunk and sub-tile: a three-leaf tree
+    # of 100 rows (leaf 0 -> 50 + 50, then 10 + 40), chunks of 32 rows and
+    # sub-tiles of 8; only the first wave has a pass
+    from lightgbm_tpu.learner import hist_live_rows
+    rec = {"split_leaf": np.array([0, 0]), "num_leaves": 3,
+           "leaf_count": np.array([10.0, 50.0, 40.0])}
+    assert hist_live_rows(rec, num_data=100, num_leaves=3, wave_max=42,
+                          row_chunk=32, k_tile=8) == [
+        {"pass": "root", "slots": 1, "rows_live": 100, "rows_passed": 100,
+         "k_tiles": 16, "k_tiles_full": 16},
+        {"pass": "w00", "slots": 1, "rows_live": 50, "rows_passed": 100,
+         "k_tiles": 8, "k_tiles_full": 16}]
