@@ -393,9 +393,23 @@ class GBDT:
         if binned.sparse_coo is not None or binned.bundle_info is not None:
             return None
         from .ops import bin_pack as bp
-        host = bp.pack_bins_host(np.asarray(binned.bins_fm),
-                                 int(binned.max_bins))
-        return bp.to_device(host) if host is not None else None
+        if bp.pack_vpb(int(binned.max_bins)) == 1:
+            return None
+        # the host's pack and the upload, counted whether or not the
+        # tracer is on: one record a packed matrix under
+        # global_metrics.meta["bin_pack"]
+        t = time.perf_counter()
+        with global_tracer.span("data/pack_bins"):
+            raw = np.asarray(binned.bins_fm)
+            packed = bp.to_device(bp.pack_bins_host(raw,
+                                                    int(binned.max_bins)))
+            jax.block_until_ready(packed.data)
+        global_metrics.meta.setdefault("bin_pack", []).append({
+            "seconds": time.perf_counter() - t, "rows": packed.num_data,
+            "features": int(raw.shape[0]), "vpb": packed.vpb,
+            "section": packed.section, "bytes_raw": int(raw.nbytes),
+            "bytes_packed": packed.nbytes})
+        return packed
 
     def _stream_ineligible(self, train_set) -> Optional[str]:
         """Why out-of-core streaming cannot serve this configuration,
@@ -680,7 +694,7 @@ class GBDT:
             wave_max=kw["wave_max"],
             subtract=bool(self.config.tpu_wave_subtract),
             row_chunk=step.get("row_chunk", 0),
-            k_tile=step.get("k_tile", 0))
+            k_tile=step.get("k_tile", 0), rows_padded=step.get("rows", 0))
         kept = global_metrics.meta.setdefault("hist_live_rows", [])
         kept.append(passes)
         del kept[:-self._LIVE_ROWS_KEPT]

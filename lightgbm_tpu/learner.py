@@ -822,7 +822,8 @@ def _wave_schedule(num_leaves: int, wave_max: int, slots: int,
 
 def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
                    subtract: bool = True, slots: int = HIST_SLOTS,
-                   row_chunk: int = 0, k_tile: int = 0):
+                   row_chunk: int = 0, k_tile: int = 0,
+                   rows_padded: int = 0):
     """What the histogram passes of ONE grown tree multiplied, from the
     tree's own child counts and the wave schedule, on the host.
 
@@ -839,6 +840,11 @@ def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
     end. With the kernel's `row_chunk` and `k_tile` (hist_geometry) each
     pass also gets its K-sub-tiles, multiplied and of the full pass,
     for live rows spread evenly over the chunks (rows in input order).
+    A chunk is `row_chunk` rows of ONE bit-section, squeezed and
+    multiplied on its own: `rows_padded` (hist_geometry's `rows`: every
+    section's padded rows, vpb x section for PackedBins) over `row_chunk`
+    of them a pass, not one a byte block; without it, `num_data` rounded
+    up.
 
     Returns one dict a pass: pass ("root", "w00", ...), slots,
     rows_live, rows_passed[, k_tiles, k_tiles_full]."""
@@ -864,7 +870,7 @@ def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
         one = {"pass": name, "slots": int(w), "rows_live": int(round(live)),
                "rows_passed": int(num_data)}
         if row_chunk and k_tile:
-            chunks = -(-num_data // row_chunk)
+            chunks = -(-(rows_padded or num_data) // row_chunk)
             full = row_chunk // k_tile
             a_chunk = live / chunks
             # a chunk over 7/8 live is not squeezed

@@ -104,10 +104,8 @@ def packed_bin_bytes(num_data: int, num_features: int, max_bins: int,
     factor — uint8 (uint16 above 256 bins) unpacked; the split-section
     PACK_ALIGN-padded byte layout of ops/bin_pack.py when packed."""
     if pack_vpb > 1:
-        from ..ops.bin_pack import PACK_ALIGN
-        section = -(-num_data // pack_vpb)
-        section = -(-section // PACK_ALIGN) * PACK_ALIGN
-        return num_features * section
+        from ..ops.bin_pack import section_len
+        return num_features * section_len(num_data, pack_vpb)
     itemsize = 1 if max_bins <= 256 else 2
     return num_features * num_data * itemsize
 

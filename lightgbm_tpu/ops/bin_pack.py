@@ -90,6 +90,12 @@ def pack_vpb(max_bins: int) -> int:
     return 1
 
 
+def section_len(num_data: int, vpb: int) -> int:
+    """Bytes a feature's packed row takes (= rows of one bit-section):
+    ``num_data / vpb`` rounded up to whole PACK_ALIGNs."""
+    return -(-(-(-num_data // vpb)) // PACK_ALIGN) * PACK_ALIGN
+
+
 def pack_bins_host(bins_fm: np.ndarray, max_bins: int):
     """Host-side pack of a ``[F, N]`` uint8 matrix; returns a host
     ``PackedBins`` (numpy data — callers ship with ``to_device``) or
@@ -98,14 +104,18 @@ def pack_bins_host(bins_fm: np.ndarray, max_bins: int):
     if vpb == 1:
         return None
     f, n = bins_fm.shape
-    section = -(-n // vpb)
-    section = -(-section // PACK_ALIGN) * PACK_ALIGN
+    section = section_len(n, vpb)
     bits = 8 // vpb
-    padded = np.zeros((f, vpb * section), np.uint8)
-    padded[:, :n] = bins_fm
     data = np.zeros((f, section), np.uint8)
     for v in range(vpb):
-        data |= padded[:, v * section:(v + 1) * section] << (bits * v)
+        # section v as the matrix holds it: the last one is short (or
+        # empty) and the bytes past it keep bin 0
+        part = bins_fm[:, v * section:(v + 1) * section]
+        head = data[:, :part.shape[1]]
+        if v == 0:
+            head[...] = part
+        else:
+            np.bitwise_or(head, part << (bits * v), out=head)
     return PackedBins(data, n, vpb)
 
 
@@ -176,13 +186,18 @@ def unpack_rows(pb: PackedBins, feat):
     the F packed rows and not as a gather. Row r lives in byte
     ``r % section`` at bit position ``bits * (r // section)``, so the
     rows of section v compare against the same bytes and shift by
-    ``bits * v``."""
+    ``bits * v``. Section by section on [rows of the section] vectors
+    (the last one short): a [vpb, section] intermediate would have to be
+    padded on the way in and laid out anew on the way out, two more
+    passes over the rows a call (PERF.md section 6, PR 35)."""
     bits = pb.bits
     f, sec = pb.data.shape
-    feat = jnp.pad(feat, (0, pb.vpb * sec - pb.num_data))
-    ids = jnp.arange(f, dtype=jnp.int32)[:, None, None]
-    byte = jnp.sum(jnp.where(feat.reshape(1, pb.vpb, sec) == ids,
-                             pb.data[:, None, :], 0),
-                   axis=0, dtype=jnp.int32)                # [vpb, section]
-    shift = bits * jnp.arange(pb.vpb, dtype=jnp.int32)[:, None]
-    return ((byte >> shift) & ((1 << bits) - 1)).reshape(-1)[:pb.num_data]
+    ids = jnp.arange(f, dtype=jnp.int32)[:, None]
+    parts = []
+    for v in range(-(-pb.num_data // sec)):
+        mine = feat[v * sec:min((v + 1) * sec, pb.num_data)]
+        byte = jnp.sum(jnp.where(mine[None, :] == ids,
+                                 pb.data[:, :mine.shape[0]], 0),
+                       axis=0, dtype=jnp.int32)
+        parts.append((byte >> (bits * v)) & ((1 << bits) - 1))
+    return jnp.concatenate(parts)

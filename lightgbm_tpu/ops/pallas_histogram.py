@@ -671,14 +671,17 @@ def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
     and as the args of a ``hist`` program span (``lgbm/hist`` in a
     profiler session). It says whether the large step engaged at a
     shape, or fell back for VMEM or for a section no large chunk
-    divides; `dots_per_step` counts the dots a sub-tile of the last
+    divides (`rows` are the padded rows a pass covers, `section` those of
+    one bit-section: `pack_factor` sections of `section / row_chunk`
+    chunks each); `dots_per_step` counts the dots a sub-tile of the last
     feature block issues and `tail_features` the features of its last
     dot (those of a whole dot where nothing is cut)."""
     from ..obs.metrics import global_metrics
     from ..obs.trace import global_tracer
     last_feats = tail[1]
     rec = {"kernel": name, "features": num_features, "max_bins": max_bins,
-           "rows": rows, "bp": geom.bp, "features_per_step": geom.f_blk,
+           "rows": rows, "section": rows // vpb, "bp": geom.bp,
+           "features_per_step": geom.f_blk,
            "features_per_dot": geom.dot_feats, "row_chunk": geom.row_chunk,
            "k_tile": geom.k_tile, "root_tile": geom.root_tile,
            "dots_per_step": -(-last_feats // geom.dot_feats),

@@ -580,3 +580,79 @@ def test_quantized_waved_runs_int8_on_xla():
                                 tpu_bin_pack="off",
                                 tpu_hist_impl="pallas").model_to_string())
     assert m_xla == m_pal
+
+
+# ---------------------------------------------------------------------------
+# the packer and its record at the benchmark's 28 features (PR 35)
+# ---------------------------------------------------------------------------
+def test_pack_section_no_multiple_of_the_largest_chunk():
+    """`pack_bins_host` on [28, N] at an N whose section (24,576 bytes =
+    3 x 8,192) the kernels' 16,384-row chunk does not divide, as at the
+    benchmark's 84M rows (42,000,384 = 5,127 x 8,192): section 0 is whole,
+    the last one short, the bytes past it bin 0 in the high nibble; and
+    the step `_fb_geometry` gives that section: 8,192-row chunks, all 28
+    features in one dot of 28 x 16 one-hot rows. On the float path it is
+    VMEM and not the alignment that keeps the 16,384-row chunk out at two
+    values a byte (the row operands' blocks double): a section it divides
+    gets 8,192 too, so the packer's alignment stays PACK_ALIGN. (The
+    int8 operand's step would fit at 16,384, with a 2,048-row root tile:
+    not a benchmark cell, not measured.)"""
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    f, section = 28, 3 * 8192
+    n = 2 * section - 2048 - 7
+    r = np.random.RandomState(3)
+    bins = r.randint(0, 15, (f, n)).astype(np.uint8)
+    pb = pack_bins_host(bins, 15)
+    assert (pb.vpb, pb.section, pb.shape) == (2, section, (f, n))
+    assert pb.section % PACK_ALIGN == 0 and pb.section % 16384
+    short = n - section
+    np.testing.assert_array_equal(pb.data & 0xF, bins[:, :section])
+    np.testing.assert_array_equal(pb.data[:, :short] >> 4,
+                                  bins[:, section:])
+    assert not (pb.data[:, short:] >> 4).any()
+    np.testing.assert_array_equal(np.asarray(unpack_bins(to_device(pb))),
+                                  bins)
+    for itemsize in (1, 2):
+        g = ph._fb_geometry(f, 15, 2, itemsize, section=pb.section)
+        assert g.row_chunk == 8192 and pb.section % g.row_chunk == 0
+        assert g.f_blk == 32 and g.dot_feats * g.bp >= 512
+        assert ph._step_vmem_bytes(g, 2, itemsize) <= ph._VMEM_LIMIT
+    assert ph._fb_geometry(f, 15, 2, 2, section=4 * 16384).row_chunk == 8192
+    assert ph._fb_geometry(f, 15, 2, 2, section=pb.section).bp == 16
+
+
+@pytest.mark.parametrize("max_bin,packs", [(15, True), (63, False)])
+def test_bin_pack_record_and_span(max_bin, packs):
+    """``global_metrics.meta["bin_pack"]`` holds one record a packed
+    matrix with the tracer off (``benchmarks/metrics/bins_pack_s.py``
+    reads its seconds), and the pack and upload are the tracer's span
+    ``data/pack_bins`` when it is on; at 63 bins nothing is packed and
+    neither appears."""
+    from lightgbm_tpu.obs.metrics import global_metrics
+    from lightgbm_tpu.obs.trace import global_tracer
+    X, y = _binary(3000, f=28)
+    params = {**BASE, "max_bin": max_bin}
+    assert not global_tracer.enabled
+    before = len(global_metrics.meta.get("bin_pack", []))
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y))
+    records = global_metrics.meta.get("bin_pack", [])
+    assert len(records) == before + int(packs)
+    assert isinstance(bst._gbdt.bins_fm, PackedBins) == packs
+    if packs:
+        rec = records[-1]
+        assert rec["seconds"] > 0
+        assert (rec["rows"], rec["features"], rec["vpb"]) == (3000, 28, 2)
+        assert rec["section"] == 2048 == bst._gbdt.bins_fm.section
+        assert rec["bytes_raw"] == 28 * 3000
+        assert rec["bytes_packed"] == 28 * 2048 == bst._gbdt.bins_fm.nbytes
+    global_tracer.enable()
+    try:
+        global_tracer.reset()
+        lgb.Booster(params, lgb.Dataset(X, label=y))
+        spans = global_tracer.summary()
+    finally:
+        global_tracer.disable()
+        global_tracer.reset()
+    assert ("data/pack_bins" in spans) == packs
+    assert len(global_metrics.meta.get("bin_pack", [])) == \
+        before + 2 * int(packs)

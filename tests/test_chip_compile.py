@@ -25,7 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from lightgbm_tpu.ops import pallas_histogram as ph
-from lightgbm_tpu.ops.bin_pack import PACK_ALIGN, PackedBins
+from lightgbm_tpu.ops.bin_pack import PackedBins, section_len
 
 F, N, SLOTS = 28, 1 << 20, 42  # Higgs width, 2^20 rows, one full wave
 ITER_ROWS = 1 << 14            # rows of the whole iteration program below
@@ -95,9 +95,7 @@ def _bins(s, n, max_bins, vpb=1, features=F):
     if vpb == 1:
         return s((features, n),
                  jnp.uint8 if max_bins <= 256 else jnp.uint16)
-    section = -(-n // vpb)
-    section = -(-section // PACK_ALIGN) * PACK_ALIGN
-    return PackedBins(s((features, section), jnp.uint8), n, vpb)
+    return PackedBins(s((features, section_len(n, vpb)), jnp.uint8), n, vpb)
 
 
 def _kernel(kind, s, bins, n, max_bins, precise="default", **multi):
@@ -288,12 +286,15 @@ def test_int8_step_vector_alu_count(one_chip, llo_dir):
 # the whole iteration program, for its layer table (ISSUE 26): what the
 # chip's compiler leaves of the lgbm/<layer> scopes is what the benchmark's
 # per-layer seconds are read through
-# the benchmark's two configurations: higgs-gpu63-int8 (quantized
-# gradients, the int8 kernel) and higgs-gpu63 (every tpu_* parameter at
-# its default: float histograms, the gradient computed inside the kernel)
+# the benchmark's narrow configurations: higgs-gpu63-int8 (quantized
+# gradients, the int8 kernel), higgs-gpu63 (every tpu_* parameter at its
+# default: float histograms, the gradient computed inside the kernel) and
+# higgs-gpu15 (the same at 15 bins, where the default storage is
+# PackedBins, two rows a byte: PR 35)
 PATHS = {"int8": {"use_quantized_grad": True, "num_grad_quant_bins": 126},
-         "float": {}}
-KERNEL = {"int8": "%lgbm_hist_multi_int8", "float": "%lgbm_hist_multi_packed"}
+         "float": {}, "packed": {"max_bin": 15}}
+KERNEL = {"int8": "%lgbm_hist_multi_int8", "float": "%lgbm_hist_multi_packed",
+          "packed": "%lgbm_hist_multi_packed"}
 
 
 @pytest.fixture(scope="module", params=sorted(PATHS))
@@ -321,7 +322,8 @@ def fused_iter_text(one_chip, path):
 def _compile_fused_iter(one_chip, path, features=F, rows=None):
     """``boosting/fused_iter`` compiled for the described chip, from a
     Booster built on ITER_ROWS rows and lowered at ``rows`` of them (at
-    ITER_ROWS if None)."""
+    ITER_ROWS if None; PackedBins at the section the packer gives that
+    many rows)."""
     import numpy as np
 
     import lightgbm_tpu as lgb
@@ -346,6 +348,12 @@ def _compile_fused_iter(one_chip, path, features=F, rows=None):
             lambda a: jax.ShapeDtypeStruct(
                 tuple(rows if rows and d == ITER_ROWS else d
                       for d in a.shape), a.dtype, sharding=one_chip), args)
+        if rows and isinstance(g.bins_fm, PackedBins):
+            vpb = g.bins_fm.vpb
+            section = section_len(rows, vpb)
+            shapes = (PackedBins(jax.ShapeDtypeStruct(
+                (features, section), jnp.uint8, sharding=one_chip), rows,
+                vpb),) + shapes[1:]
         return g._make_fused().lower(*shapes).compile()
     finally:
         mp.undo()
@@ -366,7 +374,7 @@ def test_compiled_iteration_keeps_each_layer(fused_iter_table, path, layer):
     is left under ``lgbm/gradient``: ``layer_gradient_s`` reads 0 there
     and ``layer_hist_s`` holds the gradient's arithmetic."""
     kept = layer in fused_iter_table.values()
-    assert kept == ((path, layer) != ("float", "gradient"))
+    assert kept == (layer != "gradient" or path == "int8")
 
 
 def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table,
@@ -387,14 +395,19 @@ def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table,
 @pytest.mark.parametrize("path,shape,layer", [
     ("int8", "s32[16384]", "partition"), ("int8", "s8[3,16384]", "hist"),
     ("int8", "f32[16384]", "score"), ("float", "s32[16384]", "partition"),
-    ("float", "f32[16384]", "score")], indirect=["path"])
+    ("float", "f32[16384]", "score"), ("packed", "s32[16384]", "partition"),
+    ("packed", "s32[8192]", "partition"), ("packed", "f32[16384]", "score")],
+    indirect=["path"])
 def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
     """The row-sized fusions each fall under one layer: ``s32[N]`` the
     wave partition's compare-and-select passes (PR 27; the ``u8[N]`` bin
     gather it replaced is gone), ``s8[3, N]`` the int8 kernel's
     operand, ``f32[N]`` the score update: its selects, written as the
     vector they make before the add takes them (PR 31; the gather they
-    replaced is gone; PERF.md section 5). The ``layer_*_s`` metrics are
+    replaced is gone; PERF.md section 5); on PackedBins ``s32[N / 2]``
+    too, a bit-section's rows in ``bin_pack.unpack_rows`` (PR 35: the
+    whole [2, section] it made before left a fusion and a layout copy a
+    wave with no layer at all). The ``layer_*_s`` metrics are
     defined by the scopes in the program: a change that moves a
     ``named_scope`` moves seconds between them, and shows here first."""
     got = {lay for head, lay in fused_iter_table.items()
@@ -503,6 +516,63 @@ def test_wide_float_iteration_fits_the_chip(one_chip):
                if "fusion" in head.split(" = ")[0]
                and head.split(" = ")[1].startswith(shape + "{")}
         assert got == {layer}, (shape, got)
+
+
+def test_packed_iteration_places_every_row_sized_operation(one_chip):
+    """higgs-gpu15.train's program at its own size, 84,000,000 x 28 at 15
+    bins (PR 35): PackedBins of 42,000,384 bytes a feature go in, the
+    program fits the chip, its kernel is the byte-sectioned call, and
+    every operation with a row-sized result (all the rows, or one
+    bit-section's) carries an ``op_name`` under a layer, so the
+    benchmark's ``layer_*_s`` place its seconds
+    (``layer_unattributed_pct``)."""
+    import re
+
+    from lightgbm_tpu.obs.profile import parse_layer_table
+    rows, section = 84_000_000, 42_000_384
+    compiled = _compile_fused_iter(one_chip, "packed", rows=rows)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes >= F * section + 3 * 4 * rows
+    assert 2 * 2 ** 30 <= held < 16e9, mem
+    text = compiled.as_text()
+    assert f"u8[{F},{section}]" in text and f"u8[{F},{rows}]" not in text
+    table = parse_layer_table(text)
+    kernels = {head.split(" = ")[0].split(".")[0] for head in table
+               if head.startswith("%lgbm_hist_multi")}
+    assert kernels == {KERNEL["packed"]}
+    assert "gradient" not in table.values()
+    for shape, layer in ((f"s32[{rows}]", "partition"),
+                         (f"s32[{section}]", "partition"),
+                         (f"s32[{rows - section}]", "partition"),
+                         (f"f32[{rows}]", "score")):
+        got = {lay for head, lay in table.items()
+               if "fusion" in head.split(" = ")[0]
+               and head.split(" = ")[1].startswith(shape + "{")}
+        assert got == {layer}, (shape, got)
+    # outside the fused computations, whatever writes a row-sized result
+    # and is no view, argument or asynchronous copy's half has a layer
+    moves_nothing = (" bitcast(", " parameter(", " get-tuple-element(",
+                     " tuple(", " copy-start(", " copy-done(", " constant(")
+    unplaced, comp = [], ""
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            comp = line.removeprefix("ENTRY ").split(" ", 1)[0]
+            continue
+        m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]", line)
+        if (not m or "fused_computation" in comp
+                or any(op in line for op in moves_nothing)):
+            continue
+        size = 1
+        for d in filter(None, m.group(1).split(",")):
+            size *= int(d)
+        if size >= rows - section and "lgbm/" not in line:
+            unplaced.append(line.strip()[:160])
+    # the label average's reduce and an iota run before the tree (outside
+    # any layer in every cell's program: 0.15% of the float cell's busy
+    # seconds, PERF.md section 5)
+    assert len(unplaced) <= 2, unplaced
 
 
 @pytest.mark.parametrize("rows,features", [(1 << 20, 28), (1 << 17, 2000)])
