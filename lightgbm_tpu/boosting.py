@@ -694,7 +694,8 @@ class GBDT:
             wave_max=kw["wave_max"],
             subtract=bool(self.config.tpu_wave_subtract),
             row_chunk=step.get("row_chunk", 0),
-            k_tile=step.get("k_tile", 0), rows_padded=step.get("rows", 0))
+            k_tile=step.get("k_tile", 0), rows_padded=step.get("rows", 0),
+            squeeze_stage=step.get("squeeze_stage", 0))
         kept = global_metrics.meta.setdefault("hist_live_rows", [])
         kept.append(passes)
         del kept[:-self._LIVE_ROWS_KEPT]

@@ -823,7 +823,7 @@ def _wave_schedule(num_leaves: int, wave_max: int, slots: int,
 def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
                    subtract: bool = True, slots: int = HIST_SLOTS,
                    row_chunk: int = 0, k_tile: int = 0,
-                   rows_padded: int = 0):
+                   rows_padded: int = 0, squeeze_stage: int = 0):
     """What the histogram passes of ONE grown tree multiplied, from the
     tree's own child counts and the wave schedule, on the host.
 
@@ -837,9 +837,14 @@ def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
     the bag: under bagging a leaf's rows outside the bag are live too
     and are not in here. Splits are taken in schedule order, which is
     how they were applied unless a wave held an invalid step before its
-    end. With the kernel's `row_chunk` and `k_tile` (hist_geometry) each
-    pass also gets its K-sub-tiles, multiplied and of the full pass,
-    for live rows spread evenly over the chunks (rows in input order).
+    end. With the kernel's `row_chunk`, `k_tile` and `squeeze_stage`
+    (hist_geometry) each pass also gets its K-sub-tiles, multiplied and
+    of the full pass, for live rows spread evenly over the chunks, and
+    the squeeze that decides them: "lanes" (stage 0: a chunk's live rows
+    at its front, ceil(live / k_tile) sub-tiles), "columns" (a later
+    stage: each of the 2^stage lane columns squeezed on its own and the
+    loop run to the tallest, reckoned for rows in random order,
+    `ops.pallas_histogram.squeeze_tiles`), or "none" (the root's pass).
     A chunk is `row_chunk` rows of ONE bit-section, squeezed and
     multiplied on its own: `rows_padded` (hist_geometry's `rows`: every
     section's padded rows, vpb x section for PackedBins) over `row_chunk`
@@ -847,8 +852,10 @@ def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
     up.
 
     Returns one dict a pass: pass ("root", "w00", ...), slots,
-    rows_live, rows_passed[, k_tiles, k_tiles_full]."""
+    rows_live, rows_passed[, k_tiles, k_tiles_full, squeeze]."""
     import numpy as np
+
+    from .ops.pallas_histogram import squeeze_tiles
     applied = max(int(rec["num_leaves"]) - 1, 0)
     count = np.array(rec["leaf_count"], np.float64)
     built = np.zeros(applied)   # rows of the children a split's pass builds
@@ -872,12 +879,18 @@ def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
         if row_chunk and k_tile:
             chunks = -(-(rows_padded or num_data) // row_chunk)
             full = row_chunk // k_tile
-            a_chunk = live / chunks
-            # a chunk over 7/8 live is not squeezed
-            tiles = (full if a_chunk * 8 > row_chunk * 7 or name == "root"
-                     else -(-int(round(a_chunk)) // k_tile))
-            one["k_tiles"] = int(chunks * tiles)
+            a_chunk = min(live / chunks, row_chunk)
+            if name == "root":
+                tiles = full
+            elif squeeze_stage:
+                tiles = squeeze_tiles(a_chunk / row_chunk, row_chunk, k_tile,
+                                      squeeze_stage)
+            else:
+                tiles = -(-int(round(a_chunk)) // k_tile)
+            one["k_tiles"] = int(round(chunks * tiles))
             one["k_tiles_full"] = int(chunks * full)
+            one["squeeze"] = ("none" if name == "root" else
+                              "columns" if squeeze_stage else "lanes")
         out.append(one)
     return out
 

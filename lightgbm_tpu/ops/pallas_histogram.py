@@ -21,15 +21,23 @@ step does with its row chunk of R rows:
 - The chunk's row operands go into a row stack ([8, R] 32-bit rows: gh
   or score / label / weight / mask, and the leaf ids). A row is live if
   its leaf is one of the pass's slot ids (the root's pass, whose rows
-  are all live, is told apart at the call site and skips this). Unless
-  more than 7 of 8 are live, the live lanes of the bins (as packed
-  32-bit words, four features a word) and of the stack are squeezed to
-  the front of the chunk: a prefix count of the dead lanes gives each
-  live lane its destination, and a compress network of log2(R)
-  roll-and-select stages moves it there (`_squeeze_lanes`). No byte
-  moves in HBM.
-- A loop of ceil(live / k_tile) turns then multiplies one sub-tile of
-  `k_tile` lanes a turn (`_accum_section_dots`):
+  are all live, is told apart at the call site and skips this). The
+  live lanes of the bins (as packed 32-bit words, four features a word)
+  and of the stack are then squeezed together by a compress network of
+  roll-and-select stages (`_squeeze_lanes`), whose first stage k the
+  geometry chooses from the step's shape (`_squeeze_stage`): lane i
+  belongs to lane column i mod 2^k, and each column's live lanes go to
+  its own front. From stage 0 that is the chunk's front, log2(R) stages
+  of which the first seven rotate lanes within every vreg; from stage 7
+  each of a vreg's 128 lanes is a column, every move is by whole vregs,
+  and the columns end at different heights; in between some of each. A
+  prefix count of the dead lanes of each column says how far a live
+  lane has to go (`_prefix_count`). A chunk whose tallest column needs
+  every sub-tile anyway is left as it is. No byte moves in HBM.
+- A loop of ceil(tallest column x columns / k_tile) turns then
+  multiplies one sub-tile of `k_tile` lanes a turn
+  (`_accum_section_dots`); a lane that holds what the squeeze moved on
+  is dead:
 - The leaf operand ([128, T]: sublane 3 * slot + channel holds the
   row's grad / hess / weight where the row is in that slot's leaf, the
   sub-tile's T rows on lanes) is built once a sub-tile, in the MXU's
@@ -65,8 +73,8 @@ is padded to 128 lanes — 512 bytes per row instead of 4: at N = 10.5M
 each such operand took 5 GB of HBM and the v5e compiler refused the
 iteration program at 30.5 GB (asked without a chip, PR 21).
 
-PERF.md section 6 (PRs 29 and 33) has the step's vector-operation counts
-from the compiler's own output and the chip's readings.
+PERF.md section 6 (PRs 29, 33 and 36) has the step's vector-operation
+counts from the compiler's own output and the chip's readings.
 """
 
 from __future__ import annotations
@@ -182,6 +190,10 @@ class HistGeometry(NamedTuple):
     dot_feats: int   # features a dot: dot_feats * bp one-hot rows
     k_tile: int      # rows a dot contracts: a sub-tile of the live rows
     root_tile: int   # the same for the root's pass (every row live)
+    # first stage of the squeeze network (_squeeze_stage), 0 to 7: the
+    # live rows go to the front of 2^stage lane columns, each on its own
+    # (0: of the chunk; 7: no stage rotates lanes within a vreg)
+    squeeze_stage: int = 0
 
 
 # the scoped VMEM one step's NAMED buffers may take (_step_vmem_bytes);
@@ -192,11 +204,13 @@ _VMEM_LIMIT = 16 * 1024 * 1024
 _DOT_ROWS = 512
 _ROW_CHUNKS = (16384, 8192, 4096, 2048)
 # rows (lanes) of a squeezed chunk that one turn of the step's loop
-# multiplies: a turn costs some 350 cycles of its own, a part-filled
-# last sub-tile half of this many rows a chunk (PERF.md section 6, PR 33)
+# multiplies: a turn costs some 350 cycles of its own, a part-filled last
+# sub-tile half of this many rows a chunk (PERF.md section 6, PR 33);
+# where the squeeze leaves several lane columns the loop runs to the
+# tallest in whole sub-tiles (PR 36)
 _K_TILE = 1024
 # sublanes of the row stack: a chunk's row operands as 32-bit rows, the
-# leaf ids and the squeeze's destinations: one (8, 128) tile deep
+# leaf ids and the squeeze's distances to go: one (8, 128) tile deep
 _STACK_ROWS = 8
 
 
@@ -221,6 +235,67 @@ def _step_vmem_bytes(g: HistGeometry, vpb: int, itemsize: int) -> int:
     onehot = rows * tile * itemsize
     acc = (2 * g.f_blk * g.bp + rows) * 128 * 4
     return bins + row_ops + scratch + bins32 + bops + onehot + acc
+
+
+def squeeze_tiles(share: float, row_chunk: int, k_tile: int,
+                  stage: int) -> float:
+    """Expected sub-tiles of `k_tile` lanes that the step's loop runs
+    over a chunk squeezed from network stage `stage`, when each of the
+    chunk's rows is live with probability `share`, one apart from
+    another (rows in random order): the chunk's 2^stage lane columns
+    hold Binomial(row_chunk / 2^stage, share) live rows each and the
+    loop runs to the tallest, k_tile / 2^stage rows of a column a turn:
+    the sum over j of P(tallest > j turns' worth). One column (stage 0)
+    has nothing ragged: that is ceil(live / k_tile) on average."""
+    import numpy as np
+    cols = 1 << stage
+    depth, per = row_chunk // cols, max(k_tile // cols, 1)
+    share = min(max(float(share), 0.0), 1.0)
+    if share in (0.0, 1.0):
+        return share * (row_chunk // k_tile)
+    k = np.arange(depth + 1)
+    logfact = np.concatenate([[0.0], np.cumsum(np.log(k[1:]))])
+    logp = (logfact[depth] - logfact - logfact[::-1]
+            + k * np.log(share) + (depth - k) * np.log1p(-share))
+    upto = np.minimum(np.cumsum(np.exp(logp)), 1.0)
+    return float(np.sum(1.0 - upto[0:depth:per] ** cols))
+
+
+# live shares of the twelve wave passes of a 255-leaf tree (a wave's
+# smaller children over the rows; the benchmark's generator, three trees
+# at 400,000 rows): what `_squeeze_stage` weighs the stages by
+_WAVE_SHARES = (0.49, 0.34, 0.26, 0.24, 0.22, 0.16, 0.16, 0.15, 0.12, 0.11,
+                0.10, 0.09)
+
+
+@functools.lru_cache(maxsize=None)
+def _squeeze_stage(onehot_rows: int, word_rows: int, row_chunk: int,
+                   k_tile: int, itemsize: int) -> int:
+    """First stage of a geometry's squeeze network (HistGeometry.
+    squeeze_stage), from its shapes alone: the stage that makes a pass
+    cheapest by a model of two costs, in cycles of the chip's vector
+    unit. A stage under 7 rotates the lanes of the network's two arrays
+    (8 + `word_rows` sublane rows up to whole vregs, `row_chunk` lanes):
+    800 cycles for 256 vregs, bound by the lane-rotate unit. Every stage
+    left out makes the lane columns twice as many and the tallest of
+    them higher above their mean (`squeeze_tiles`), and a turn of the
+    loop costs 770 cycles and 0.8 for each of the step's real one-hot
+    rows and operand bytes (`onehot_rows`, `itemsize`). Both from the
+    compiler's schedule of the step for the chip and held against the
+    chip's readings (PERF.md section 6, PR 36: a pass read within a
+    tenth of the model). Narrow steps with cheap turns start late (6 at
+    28 features and 63 bins on int8, and at 15 bins packed); dear turns
+    start early (4 on the float operand at 28 features, 1 at 2,000; 0,
+    the whole network, at 255 bins on the float operand)."""
+    vregs = (8 + _round_up(word_rows, 8)) * row_chunk // 1024
+    lane_stage = 800.0 * vregs / 256
+    turn = 770.0 + 0.8 * onehot_rows * itemsize
+
+    def cost(stage):
+        return (7 - stage) * lane_stage + turn * sum(
+            squeeze_tiles(p, row_chunk, k_tile, stage)
+            for p in _WAVE_SHARES) / len(_WAVE_SHARES)
+    return min(range(8), key=cost)
 
 
 def _fb_geometry(num_features: int, max_bins: int, vpb: int = 1,
@@ -268,7 +343,11 @@ def _fb_geometry(num_features: int, max_bins: int, vpb: int = 1,
         if best is None or steps_a_row < best[0]:
             best = (steps_a_row, g)
     assert best is not None, (num_features, max_bins, vpb, itemsize)
-    return best[1]
+    g = best[1]
+    wide = 2 if g.bp > 256 else 1                       # uint16 ids
+    return g._replace(squeeze_stage=_squeeze_stage(
+        min(num_features, g.f_blk) * g.bp, g.f_blk * wide // 4, g.row_chunk,
+        g.k_tile, itemsize))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -339,55 +418,91 @@ def _accum_section_dots(btile, out_ref, bop, *, geom: HistGeometry,
             dot(f0, nf)
 
 
-def _prefix_count(x):
-    """Inclusive prefix sum along the lanes of a [1, R] int32 row, and
-    its total (a scalar). The row is folded to [8, R/8], an eighth of
-    the lanes a sublane, so that a roll-and-add stage works on full
-    vregs: log2(R/8) stages along the lanes, three along the sublanes
-    for the eighths' offsets."""
+def _prefix_count(x, first_stage: int):
+    """Inclusive prefix sums of a [1, R] int32 row within its lane
+    columns (lane i's column: i mod c, c = 2^first_stage), and the
+    columns' totals as a [1, 128] row (lane l: column l mod c's). The
+    row is folded to [8, R/8], an eighth of the lanes a sublane, so that
+    a roll-and-add stage works on full vregs: stages along the lanes
+    from the columns' stride up (a stride of 128 lanes or more moves
+    whole vregs), then three along the sublanes for the eighths'
+    offsets, on the eighths' totals spread over their columns' lanes."""
+    c = 1 << first_stage
     w = x.shape[1] // 8
     x = jnp.concatenate([x[:, j * w:(j + 1) * w] for j in range(8)], axis=0)
     lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    for k in range(w.bit_length() - 1):
+    for k in range(first_stage, w.bit_length() - 1):
         x = x + jnp.where(lane >= 1 << k, pltpu.roll(x, 1 << k, 1), 0)
-    total = jnp.broadcast_to(x[:, w - 1:w], (8, 128))
+    # an eighth's column totals are in its last c lanes: to every lane
+    # of the column, by rolls that keep a lane's column
+    total = jnp.where(lane[:, :128] >= 128 - c, x[:, w - 128:], 0)
+    for k in range(first_stage, 7):
+        total = total + pltpu.roll(total, 1 << k, 1)
     sub = lax.broadcasted_iota(jnp.int32, total.shape, 0)
     upto = total
     for k in range(3):
         upto = upto + jnp.where(sub >= 1 << k, pltpu.roll(upto, 1 << k, 0), 0)
-    x = x + (upto - total)[:, :1]
+    x = x + jnp.concatenate([upto - total] * (w // 128), axis=1)
     return (jnp.concatenate([x[j:j + 1] for j in range(8)], axis=1),
-            upto[7, 0])
+            upto[7:8])
 
 
-def _squeeze_lanes(words, stack, dest_row: int):
-    """Squeeze the live lanes of a row chunk to the front, in order.
+def _squeeze_lanes(words_ref, stack_ref, togo_row: int, first_stage: int):
+    """Squeeze the live lanes of a row chunk to the front of their lane
+    columns (lane i's column: i mod 2^first_stage), in order and in
+    place.
 
-    Row `dest_row` of `stack` ([8, R] int32, the chunk's row operands)
-    holds each lane's destination: a live lane's count of live lanes
-    before it, a dead lane's own index; `words` ([k, R] int32, the
-    chunk's bins as packed words) rides along. A compress network of
-    log2(R) stages, lowest bit first: at stage k lane i takes lane
-    i + 2^k's values (a roll and a select; a roll by a multiple of 128
-    lanes renames vregs) if that lane has exactly 2^k left to go down
-    modulo 2^(k+1). Live lanes never meet (a later one has at least as
-    far to go), and what a lane leaves behind when it moves on has a
-    lower bit of its distance set at every later stage, so the stale
-    copy is never taken again: the destination needs no second array
-    and no clearing, and only two arrays are rolled, which is what the
-    network costs on the chip (the lane rotates, PERF.md section 6, PR
-    33). Afterwards lanes [0, live) hold the live lanes' values; the
-    lanes behind hold stale ones, which the caller marks dead."""
-    r = stack.shape[1]
-    lane = lax.broadcasted_iota(jnp.int32, (1, r), 1)
-    for k in range(r.bit_length() - 1):
+    Row `togo_row` of `stack_ref` ([8, R] int32, the chunk's row
+    operands) holds how far down each lane has to go: for a live lane
+    2^first_stage times the dead lanes before it in its column, for a
+    dead lane 0; `words_ref` ([k, R] int32, the chunk's bins as packed
+    words) rides along. A compress network of stages from `first_stage`
+    to log2(R) - 1, lowest bit first: at stage k lane i takes lane
+    i + 2^k's values if that lane has exactly 2^k left to go modulo
+    2^(k+1), and what it takes has 2^k less to go (every distance is a
+    multiple of 2^first_stage, so no earlier stage would move anything).
+    Live lanes never meet (a later one has at least as far to go), and
+    what a lane leaves behind when it moves on keeps the bit it moved
+    by, a lower bit than any later stage's, so the stale copy is never
+    taken again: the distances need no second array and no clearing.
+
+    A stage reads and writes the two scratch arrays in place: a lane's
+    source lies behind it, so what a stage has yet to read it has not
+    written, and the compiler keeps a few vregs in flight. (As two
+    whole-chunk values the 256 vregs were spilled and filled at every
+    stage and the one vector-store slot bound the network: PERF.md
+    section 6, PR 36.) From stage 7 on a source is whole vregs further
+    on: a plain load, and the last 2^k lanes have no source and are left
+    alone. Stages 0 to 6 rotate the lanes of every vreg, which is what
+    they cost on the chip beside the others; the rotation wraps onto the
+    chunk's first 2^k lanes, which have less than 2^k to go. The
+    distance is read on all eight sublanes at once, so that the compare
+    is the selects' mask as it stands. Afterwards a column's live lanes
+    are its first ones and have nothing left to go; behind them lie dead
+    lanes and stale copies, which still have: the step's loop takes a
+    lane with a distance left for dead."""
+    rows, r = stack_ref.shape
+    sub = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    for k in range(first_stage, r.bit_length() - 1):
         s = 1 << k
-        stack_in = pltpu.roll(stack, r - s, 1)        # lane i sees i + s
-        to_go = lane + s - stack_in[dest_row:dest_row + 1]
+        n = r - s if s >= 128 else r                  # lanes with a source
+
+        def source(ref, g0=0, g1=None):
+            """Lanes s .. s + n of `ref`'s rows g0:g1."""
+            if s >= 128:
+                return ref[g0:g1, s:]
+            return pltpu.roll(ref[g0:g1, :], r - s, 1)
+
+        stack_in = source(stack_ref)
+        to_go = jnp.broadcast_to(stack_in[togo_row:togo_row + 1], (rows, n))
         take = (to_go & (2 * s - 1)) == s
-        words = jnp.where(take, pltpu.roll(words, r - s, 1), words)
-        stack = jnp.where(take, stack_in, stack)
-    return words, stack
+        stack_ref[:, :n] = jnp.where(
+            take, stack_in - jnp.where(sub == togo_row, s, 0),
+            stack_ref[:, :n])
+        for g in range(0, words_ref.shape[0], rows):
+            g1 = min(g + rows, words_ref.shape[0])
+            words_ref[g:g1, :n] = jnp.where(
+                take[:g1 - g], source(words_ref, g, g1), words_ref[g:g1, :n])
 
 
 def _as_words(x):
@@ -415,10 +530,17 @@ def _multi_step(bins_ref, refs, *, geom: HistGeometry, vpb: int,
     For each bit-section: the row operands go into the row stack as
     32-bit rows. Where `squeeze` (every pass but the root's, whose rows
     are all live), a row is live if its leaf is one of the pass's slot
-    ids; unless more than 7 of 8 are, the live lanes of the bins (as
-    packed 32-bit words) and of the stack are squeezed to the front
-    (_squeeze_lanes). Then a loop of ceil(live / k_tile) turns builds
-    the leaf operand of one sub-tile of lanes and issues its dots."""
+    ids, and a lane's column is its index modulo 2^`geom.squeeze_stage`
+    (stage 0: the chunk is one column; 7: a vreg's 128 lanes are one
+    each). The dead lanes' prefix count in each column says how far
+    down its column each live lane has to go and how high the tallest
+    column stands (_prefix_count); the loop needs
+    ceil(tallest x columns / k_tile) turns, and unless those are all the
+    chunk has, the live lanes of the bins (as packed 32-bit words) and
+    of the stack are squeezed to the front of their columns, in place
+    (_squeeze_lanes). Then the loop: a turn builds the leaf operand of
+    one sub-tile of lanes, a lane dead where the squeeze left a stale
+    copy, and issues its dots."""
     out_ref, stack_ref, words_ref = refs[-3:]
     leafsel_ref, slots_ref = refs[-5:-3]
     row_refs = refs[:-5]
@@ -444,35 +566,25 @@ def _multi_step(bins_ref, refs, *, geom: HistGeometry, vpb: int,
             at += hgt
         bins_src, n_tiles = bins_ref, cb // t
         if squeeze:
+            fs = geom.squeeze_stage
+            togo_row = leaf_row + 1
             rl = ops[-1][...]                                  # [1, R]
             hit = slots_ref[0:8, :] == rl                      # [8, R]
             for j in range(8, slots_ref.shape[0], 8):
                 hit |= slots_ref[j:j + 8, :] == rl
             live = jnp.max(hit.astype(jnp.int32), axis=0, keepdims=True)
-            dead_before, n_dead = _prefix_count(1 - live)
-            n_live = cb - n_dead
-            sparse = n_live * 8 <= cb * 7
-            dest_row = leaf_row + 1
-
-            @pl.when(sparse)
-            def _squeeze():
-                lane = lax.broadcasted_iota(jnp.int32, (1, cb), 1)
-                stack_ref[dest_row:dest_row + 1, :] = lane - jnp.where(
-                    live != 0, dead_before, 0)
-                words, stack = _squeeze_lanes(
-                    bins_ref.bitcast(jnp.int32)[...], stack_ref[...],
-                    dest_row)
-                words_ref[...] = words
-                stack_ref[...] = stack
-                stack_ref[leaf_row:leaf_row + 1, :] = jnp.where(
-                    lane < n_live, stack[leaf_row:leaf_row + 1], -1)
-
-            @pl.when(jnp.logical_not(sparse))
-            def _keep():
-                words_ref[...] = bins_ref.bitcast(jnp.int32)[...]
-
+            dead_before, dead = _prefix_count(1 - live, fs)
+            stack_ref[togo_row:togo_row + 1, :] = jnp.where(
+                live != 0, dead_before << fs, 0)
+            # the tallest column's live lanes
+            tallest = (cb >> fs) - jnp.min(dead, axis=1, keepdims=True)[0, 0]
+            n_tiles = ((tallest << fs) + (t - 1)) // t
+            sparse = n_tiles < cb // t
+            # the bins as 32-bit words in the scratch the loop reads
+            words_ref[...] = bins_ref.bitcast(jnp.int32)[...]
+            pl.when(sparse)(functools.partial(
+                _squeeze_lanes, words_ref, stack_ref, togo_row, fs))
             bins_src = words_ref.bitcast(bins_ref.dtype)
-            n_tiles = jnp.where(sparse, (n_live + (t - 1)) // t, cb // t)
 
         def tile(i, carry):
             at = pl.ds(pl.multiple_of(i * t, t), t)
@@ -483,8 +595,14 @@ def _multi_step(bins_ref, refs, *, geom: HistGeometry, vpb: int,
                 rows.append(pltpu.bitcast(x, jnp.float32)
                             if o.dtype == jnp.float32 else x)
                 r0 += hgt
-            bop = _leaf_bop(*gh_of(rows[:-1]), rows[-1], leafsel_ref, int8,
-                            parts)
+            leaf = rows[-1]
+            if squeeze:
+                # what the squeeze moved on left a copy behind with the
+                # bit it moved by; what arrived, and a dead lane, have
+                # nothing left to go
+                leaf = jnp.where(
+                    (stack[togo_row:togo_row + 1] != 0) & sparse, -1, leaf)
+            bop = _leaf_bop(*gh_of(rows[:-1]), leaf, leafsel_ref, int8, parts)
             _accum_section_dots(bins_src[:, at].astype(jnp.int32), out_ref,
                                 bop, geom=geom, vpb=vpb, v=v, int8=int8,
                                 tail=tail)
@@ -684,6 +802,7 @@ def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
            "features_per_step": geom.f_blk,
            "features_per_dot": geom.dot_feats, "row_chunk": geom.row_chunk,
            "k_tile": geom.k_tile, "root_tile": geom.root_tile,
+           "squeeze_stage": geom.squeeze_stage,
            "dots_per_step": -(-last_feats // geom.dot_feats),
            "tail_features": (last_feats - 1) % geom.dot_feats + 1,
            "grid_steps": grid[0] * grid[1], "pack_factor": vpb,

@@ -233,7 +233,9 @@ _NOT_ALU = ("vector_load", "vector_store", "vmatmul", "vlatch", "vmatres",
 def llo_counts(llo_dir, fn, *args):
     """{op: count} of one kernel's last Mosaic stage (``post-finalize-
     llo``: the step's vector program, unrolled), compiled for the
-    described chip. The count is per grid step."""
+    described chip. The count is per grid step. A rotate is counted by
+    its axis: `vrot.lane` moves lanes within a vreg, `vrot.slane`
+    sublanes."""
     import collections
     import re
     for old in llo_dir.glob("*"):
@@ -241,7 +243,7 @@ def llo_counts(llo_dir, fn, *args):
     jax.jit(fn).lower(*args).compile()
     dump, = llo_dir.glob("*post-finalize-llo*")
     return collections.Counter(
-        re.findall(r'"?llo\.(v[a-z_0-9]+)', dump.read_text()))
+        re.findall(r'"?llo\.(vrot\.s?lane|v[a-z_0-9]+)', dump.read_text()))
 
 
 def _alu(ops):
@@ -259,9 +261,13 @@ def test_int8_step_vector_alu_count(one_chip, llo_dir):
     (`root_tile` rows in the root's pass, which does nothing else:
     10.4k per 2048 rows; `k_tile` rows of the squeezed chunk in the
     others), and before the loop the squeeze of the chunk's live rows
-    (live mask, prefix count, compress network: under a third of the
-    step; PERF.md section 6, PR 33). Neither count can creep back
-    unseen."""
+    (live mask, prefix count, compress network: 3.2k per 2048 rows with
+    the whole network of PR 33, 1.9k lane rotates a chunk among them).
+    At this shape the geometry starts the network at stage 6 (PR 36):
+    one stage that rotates lanes, the others move whole vregs, all in
+    place: two fifths of the count. Neither count can creep
+    back unseen; what binds the squeeze on the chip, the one vector-store
+    slot, these counts do not show (PERF.md section 6, PR 36)."""
     s = _shapes(one_chip)
     geom = ph._fb_geometry(F, 63, 1, 1, rows=N)
     fn, args = _kernel("int8", s, _bins(s, N, 63), N, 63, all_live=True)
@@ -278,7 +284,11 @@ def test_int8_step_vector_alu_count(one_chip, llo_dir):
     # a popped result and sub-tile); the rest is the squeeze of one chunk
     body = _alu(root) * geom.k_tile / geom.root_tile
     squeeze = (_alu(step) - body) * 2048 / geom.row_chunk
-    assert 0 < squeeze < 3_900, step
+    assert geom.squeeze_stage == 6
+    assert 0 < squeeze < 1_400, step
+    # one lane stage of the network (its two arrays' vregs) and the
+    # prefix count's: the whole network had 1,904
+    assert root["vrot.lane"] == 0 < step["vrot.lane"] < 340
     assert _alu(root) * turns + squeeze < 16_000
 
 

@@ -8,6 +8,7 @@ agreement (tests/python_package_test/test_dual.py:19); waved-vs-exact is
 the analogous gate for the batched TPU grower.
 """
 
+import contextlib
 import functools
 
 import numpy as np
@@ -191,7 +192,14 @@ def _step_rows(r, live, slots, n):
     "some": a few leaves of many; "none": no slot matches; "one": one row
     a 2048-row chunk; a share; "dense": over 7/8, the chunk that is not
     squeezed; "root": every row in the one slot, told at the call site;
-    "end": the live rows at the end of each chunk."""
+    "end": the live rows at the end of each chunk. What the column
+    squeeze (squeeze stage 7) can get wrong and the full network could
+    not, in 2048-row chunks of 16 vregs: "stride": live rows 128 apart,
+    one lane column full, so the chunk is not squeezed; "column": the
+    same in every other chunk, and between them 127 columns full and
+    one empty; "last_vreg": live rows in a chunk's last 128 lanes only;
+    "tile_edge": the tallest column exactly one sub-tile of 8 vregs
+    high, the others 3 or none."""
     if live == "root":
         return np.full(n, 9), np.array([9] + [-2] * (slots - 1)), 1
     if live == "some":
@@ -200,8 +208,14 @@ def _step_rows(r, live, slots, n):
         return r.randint(0, slots + 3, n), np.array(ids), k
     ids = np.arange(slots) + 5
     row = np.arange(n)
+    col, vreg = row % 128, row % 2048 // 128
     on = {"none": np.zeros(n, bool), "one": row % 2048 == 7,
-          "dense": r.rand(n) < 0.95, "end": row % 2048 >= 1748}.get(
+          "dense": r.rand(n) < 0.95, "end": row % 2048 >= 1748,
+          "stride": col == 5,
+          "column": (col == 3) ^ (row // 2048 % 2 == 1),
+          "last_vreg": vreg == 15,
+          "tile_edge": ((col == 77) & (vreg % 2 == 1))
+          | ((col % 3 == 1) & np.isin(vreg, (2, 9, 12)))}.get(
               live, r.rand(n) < (live if isinstance(live, float) else 0))
     return np.where(on, r.randint(5, slots + 5, n),
                     r.randint(100, 120, n)), ids, slots
@@ -222,9 +236,32 @@ def test_shared_step_matches_xla_twins(kind, max_bins, vpb, f, slots, n):
     check_shared_step(kind, "some", max_bins, vpb, f, slots, n)
 
 
-def check_shared_step(kind, live, max_bins, vpb, f, slots, n):
+@contextlib.contextmanager
+def squeeze_stage(stage):
+    """`stage` in the place of the geometry's rule
+    (pallas_histogram._squeeze_stage), for the kernels traced inside."""
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    jitted = (ph._multi_slabs, ph.hist_pallas_multi,
+              ph.hist_pallas_multi_int8, ph.hist_pallas_multi_fused)
+    was = ph._squeeze_stage
+    ph._squeeze_stage = lambda *a: stage
+    try:
+        for fn in jitted:
+            fn.clear_cache()
+        yield
+    finally:
+        ph._squeeze_stage = was
+        for fn in jitted:
+            fn.clear_cache()
+
+
+def check_shared_step(kind, live, max_bins, vpb, f, slots, n, stage=None):
     """The multi-leaf kernels' step against the XLA twins with the rows
-    of a `live` case (_step_rows) live."""
+    of a `live` case (_step_rows) live; with `stage`, under that squeeze
+    whatever the geometry's rule says. Returns the histograms."""
+    if stage is not None:
+        with squeeze_stage(stage):
+            return check_shared_step(kind, live, max_bins, vpb, f, slots, n)
     from lightgbm_tpu.ops import pallas_histogram as ph
     from lightgbm_tpu.ops.bin_pack import pack_bins_host, to_device
     r = np.random.RandomState(max_bins + f + n)
@@ -283,10 +320,12 @@ def check_shared_step(kind, live, max_bins, vpb, f, slots, n):
         int8=kind == "int8", precise="highest", interpret=True,
         all_live=live == "root", name="lgbm_hist_multi", **reader))
     from lightgbm_tpu.obs.metrics import global_metrics
-    geom, = [g for g in global_metrics.meta["hist_geometry"]
-             if (g["kernel"], g["features"], g["max_bins"], g["pack_factor"],
-                 g["operand"] == "int8") == ("lgbm_hist_multi", f, max_bins,
-                                             vpb, kind == "int8")]
+    # one record a squeeze stage this shape was traced with: the step's
+    # other numbers are the same in each
+    geom = [g for g in global_metrics.meta["hist_geometry"]
+            if (g["kernel"], g["features"], g["max_bins"], g["pack_factor"],
+                g["operand"] == "int8") == ("lgbm_hist_multi", f, max_bins,
+                                            vpb, kind == "int8")][-1]
     assert slabs.shape[0] % geom["features_per_step"] == 0
     assert slabs.shape[0] >= f and slabs.shape[1] == geom["bp"] >= max_bins
     # the dots cover the real features only: a padded feature's slab is
@@ -299,6 +338,7 @@ def check_shared_step(kind, live, max_bins, vpb, f, slots, n):
     np.testing.assert_array_equal(
         slabs[:f, :max_bins, :3 * slots].reshape(f, max_bins, slots, 3),
         np.moveaxis(np.asarray(got), 0, 2))
+    return np.asarray(got)
 
 
 def test_waved_quantized_grad_trains():
@@ -586,6 +626,6 @@ def test_hist_live_rows_counter():
     assert hist_live_rows(rec, num_data=100, num_leaves=3, wave_max=42,
                           row_chunk=32, k_tile=8) == [
         {"pass": "root", "slots": 1, "rows_live": 100, "rows_passed": 100,
-         "k_tiles": 16, "k_tiles_full": 16},
+         "k_tiles": 16, "k_tiles_full": 16, "squeeze": "none"},
         {"pass": "w00", "slots": 1, "rows_live": 50, "rows_passed": 100,
-         "k_tiles": 8, "k_tiles_full": 16}]
+         "k_tiles": 8, "k_tiles_full": 16, "squeeze": "lanes"}]

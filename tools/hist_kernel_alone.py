@@ -2,7 +2,7 @@
 
     chiprun -- python3 tools/hist_kernel_alone.py [--tree DIR] [--rows N]
         [--features F] [--kinds int8,fused] [--shares 1.0,0.5,0.25,0.1]
-        [--max-bin B] [--pack auto|off]
+        [--max-bin B] [--pack auto|off] [--squeeze-stage rule|0..7]
 
 One pass of the int8 kernel and of the gradient-fused float kernel over
 synthetic bins (63 bins unless `--max-bin`, 42 slots) at the benchmark's
@@ -12,7 +12,12 @@ pass (every row live, told at the call site). At 15 bins and fewer the
 bins are bit-packed as the booster packs them (`ops/bin_pack`, on the
 host, its seconds printed) unless `--pack off`. A reading is the
 kernel's own device seconds from a profiler trace (the median of three
-calls) beside the call's wall seconds (pads included). `--tree` points at another checkout
+calls) beside the call's wall seconds (pads included), with the squeeze
+the geometry chose (`squeeze_stage`, the network's first stage: 0 the
+live rows to the chunk's front, 7 each of a vreg's 128 lane columns to
+its own top; `--squeeze-stage` puts another in the rule's place, which is
+how the rule's model was held against the chip: PERF.md section 6, PR
+36). `--tree` points at another checkout
 (a `git archive` of the parent, say) so that both sides of a change are
 read on one machine, one process after the other; a checkout from before
 PR 33 has one reading a kind (no live rows to tell). Prints one JSON line
@@ -36,6 +41,8 @@ ap.add_argument("--kinds", default="int8,fused")
 ap.add_argument("--shares", default="1.0,0.5,0.25,0.1")
 ap.add_argument("--max-bin", type=int, default=63)
 ap.add_argument("--pack", choices=("auto", "off"), default="auto")
+ap.add_argument("--squeeze-stage", default="rule",
+                choices=("rule", *"01234567"))
 args = ap.parse_args()
 sys.path.insert(0, os.path.abspath(args.tree))
 
@@ -49,6 +56,8 @@ N, F, B, SLOTS = args.rows, args.features, args.max_bin, 42
 OUT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                    "chiprun_out")
 SQUEEZES = hasattr(ph, "_squeeze_lanes")
+if args.squeeze_stage != "rule":
+    ph._squeeze_stage = lambda *a, **k: int(args.squeeze_stage)
 
 
 def binary_grad(score, label, weight):
@@ -159,12 +168,14 @@ def main():
                                         (*data, leaves[shares[-1]], ids))
             emit(**base, live="any", kernel_s=s, wall_s=wall)
             continue
+        geom = ph._fb_geometry(F, B, vpb, 1 if kind == "int8" else 2,
+                               **({"rows": N} if vpb == 1
+                                  else {"section": bins.section}))
+        base["squeeze_stage"] = getattr(geom, "squeeze_stage", 0)
         s, wall, _ = kernel_seconds(entry(kind, all_live=True),
                                     (*data, jnp.zeros((N,), jnp.int32), ids))
-        emit(**base, live="root", kernel_s=s, wall_s=wall, geometry=list(
-            ph._fb_geometry(F, B, vpb, 1 if kind == "int8" else 2,
-                            **({"rows": N} if vpb == 1
-                               else {"section": bins.section}))))
+        emit(**base, live="root", kernel_s=s, wall_s=wall,
+             geometry=geom._asdict())
         fn = entry(kind)
         for share in shares:
             s, wall, _ = kernel_seconds(fn, (*data, leaves[share], ids))
