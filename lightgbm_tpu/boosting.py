@@ -673,34 +673,26 @@ class GBDT:
     _LIVE_ROWS_KEPT = 8
 
     def _note_hist_live_rows(self, rec) -> None:
-        """While a tracer session is live (the span tracer, or a
-        profiler session), publish what the histogram passes of a tree
-        multiplied (learner.hist_live_rows: live rows and K-sub-tiles a
-        pass): under ``global_metrics.meta["hist_live_rows"]``, the
-        newest trees, and as the args of a ``hist`` program span
-        (``lgbm/hist``). Computed from the tree's arrays as they reach
-        the host anyway: nothing is fetched from the device for it."""
-        if not self._use_waved() or self._sparse_shape is not None:
-            return
-        from .obs import trace as obs_trace
-        if not (global_tracer.enabled
-                or obs_trace._annotation().is_enabled()):
+        """Publish what the histogram passes of a tree multiplied, as
+        the kernels counted it (learner.hist_live_rows: live rows,
+        sub-tile turns and chunks squeezed a pass), under
+        ``global_metrics.meta["hist_live_rows"]``: the newest trees.
+        Always on: the counts arrive with the tree's arrays, which reach
+        the host anyway; nothing is fetched from the device for them. A
+        tree whose passes no kernel counted (``hist_counts`` None) adds
+        nothing."""
+        counts = rec.get("hist_counts")
+        if counts is None:
             return
         from .learner import hist_live_rows
         kw = self._resolved_hist_shape()
-        step = (global_metrics.meta.get("hist_geometry") or [{}])[-1]
         passes = hist_live_rows(
-            rec, num_data=kw["num_data"], num_leaves=kw["num_leaves"],
+            counts, num_data=kw["num_data"], num_leaves=kw["num_leaves"],
             wave_max=kw["wave_max"],
-            subtract=bool(self.config.tpu_wave_subtract),
-            row_chunk=step.get("row_chunk", 0),
-            k_tile=step.get("k_tile", 0), rows_padded=step.get("rows", 0),
-            squeeze_stage=step.get("squeeze_stage", 0))
+            subtract=bool(self.config.tpu_wave_subtract))
         kept = global_metrics.meta.setdefault("hist_live_rows", [])
         kept.append(passes)
         del kept[:-self._LIVE_ROWS_KEPT]
-        with global_tracer.span("hist", args={"live_rows": passes}):
-            pass
 
     def _note_collective_traffic(self) -> None:
         """Publish the static per-iteration COLLECTIVE traffic model —
@@ -1554,7 +1546,8 @@ class GBDT:
             iter_trees = []
             for k in range(k_per):
                 rec = {f: np.asarray(getattr(host, f)[i][k])
-                       for f in host._fields}
+                       for f in host._fields
+                       if getattr(host, f) is not None}
                 tree = Tree.from_arrays(rec, self.train_set.mappers,
                                         self.train_set.used_features)
                 self._note_hist_live_rows(rec)
@@ -2630,7 +2623,8 @@ class DART(GBDT):
             for i in range(len(recs)):
                 self._dart_unshrunk.append(
                     {f: np.asarray(getattr(host, f)[i])
-                     for f in host._fields})
+                     for f in host._fields
+                     if getattr(host, f) is not None})
         factors = np.asarray(jax.device_get(self._dart["factors"]))
         # leaf values come from the history buffer (unshrunk + the first
         # iteration's bias/creation_factor term) x current factor — the

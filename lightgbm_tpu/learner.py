@@ -64,6 +64,13 @@ class TreeArrays(NamedTuple):
     leaf_weight: jax.Array         # [L] f32
     leaf_count: jax.Array          # [L] f32
     num_leaves: jax.Array          # scalar int32
+    # [passes, len(PASS_COUNTS)] int32: what the multi-leaf kernels
+    # counted in each histogram pass of grow_tree_waved (the root's, then
+    # a wave's; ops/pallas_histogram.PASS_COUNTS); None where the passes
+    # ran elsewhere (the XLA twins, the sparse builder, the per-shard
+    # kernels of a mesh, the streamed grower's slabs) or no grower
+    # counted (grow_tree, the feature- and voting-parallel learners)
+    hist_counts: Optional[jax.Array] = None
 
 
 class _LeafSplits(NamedTuple):
@@ -318,25 +325,27 @@ def _sharded_pallas_multi(shard_mesh, *, max_bins: int,
     scatter = hist_reduce == "scatter"
 
     def local(b_l, ghT_l, rl_l, ids):
+        # a shard's pass counts are left where they were made: the
+        # sharded learners keep no hist_counts
         if impl == "pallas":
             if int8:
-                h = hist_pallas_multi_int8(b_l, ghT_l, rl_l, ids,
-                                           max_bins=max_bins,
-                                           num_slots=ids.shape[0])
+                h, _ = hist_pallas_multi_int8(b_l, ghT_l, rl_l, ids,
+                                              max_bins=max_bins,
+                                              num_slots=ids.shape[0])
             else:
-                h = hist_pallas_multi(b_l, ghT_l, rl_l, ids,
-                                      max_bins=max_bins,
-                                      num_slots=ids.shape[0],
-                                      precise=precision)
+                h, _ = hist_pallas_multi(b_l, ghT_l, rl_l, ids,
+                                         max_bins=max_bins,
+                                         num_slots=ids.shape[0],
+                                         precise=precision)
         elif int8:
             # per-shard exact-integer XLA twin of the MXU kernel
-            h = hist_multi_int8(b_l, ghT_l, rl_l, ids, max_bins=max_bins,
-                                num_slots=ids.shape[0], impl=impl)
+            h, _ = hist_multi_int8(b_l, ghT_l, rl_l, ids, max_bins=max_bins,
+                                   num_slots=ids.shape[0], impl=impl)
         else:
-            h = hist_multi(b_l, ghT_l, rl_l, ids, max_bins=max_bins,
-                           num_slots=ids.shape[0], impl=impl,
-                           precision=precision,
-                           deterministic=deterministic)
+            h, _ = hist_multi(b_l, ghT_l, rl_l, ids, max_bins=max_bins,
+                              num_slots=ids.shape[0], impl=impl,
+                              precision=precision,
+                              deterministic=deterministic)
         if not scatter:
             return obs_health.psum(h, row_axes, tag="hist/psum_wave")
         fpad = (-h.shape[1]) % width
@@ -820,77 +829,39 @@ def _wave_schedule(num_leaves: int, wave_max: int, slots: int,
     return sizes
 
 
-def hist_live_rows(rec, *, num_data: int, num_leaves: int, wave_max: int,
-                   subtract: bool = True, slots: int = HIST_SLOTS,
-                   row_chunk: int = 0, k_tile: int = 0,
-                   rows_padded: int = 0, squeeze_stage: int = 0):
-    """What the histogram passes of ONE grown tree multiplied, from the
-    tree's own child counts and the wave schedule, on the host.
+def hist_live_rows(counts, *, num_data: int, num_leaves: int,
+                   wave_max: int, subtract: bool = True,
+                   slots: int = HIST_SLOTS):
+    """What the histogram passes of ONE grown tree multiplied, as the
+    multi-leaf kernels counted it in their steps.
 
-    `rec` holds a tree's arrays as NumPy (`split_leaf`, `leaf_count`,
-    `num_leaves` of TreeArrays). A pass multiplies the rows whose leaf
-    is one of its slot ids (ops/pallas_histogram._multi_step): every row
-    for the root, then a wave's smaller children (both children without
-    sibling subtraction); the last wave's pass is skipped. Child counts
-    are rebuilt by undoing the splits from the last to the first (split
-    s made leaf s + 1 out of leaf split_leaf[s]). They count the rows of
-    the bag: under bagging a leaf's rows outside the bag are live too
-    and are not in here. Splits are taken in schedule order, which is
-    how they were applied unless a wave held an invalid step before its
-    end. With the kernel's `row_chunk`, `k_tile` and `squeeze_stage`
-    (hist_geometry) each pass also gets its K-sub-tiles, multiplied and
-    of the full pass, for live rows spread evenly over the chunks, and
-    the squeeze that decides them: "lanes" (stage 0: a chunk's live rows
-    at its front, ceil(live / k_tile) sub-tiles), "columns" (a later
-    stage: each of the 2^stage lane columns squeezed on its own and the
-    loop run to the tallest, reckoned for rows in random order,
-    `ops.pallas_histogram.squeeze_tiles`), or "none" (the root's pass).
-    A chunk is `row_chunk` rows of ONE bit-section, squeezed and
-    multiplied on its own: `rows_padded` (hist_geometry's `rows`: every
-    section's padded rows, vpb x section for PackedBins) over `row_chunk`
-    of them a pass, not one a byte block; without it, `num_data` rounded
-    up.
+    `counts` is the tree's `TreeArrays.hist_counts` as NumPy: one row a
+    pass, the root's and then a wave's (the last wave's pass is
+    skipped), in ops/pallas_histogram.PASS_COUNTS' order. The wave
+    schedule names the passes and gives their slots (a wave's smaller
+    children; both children without sibling subtraction). A wave pass's
+    `rows_live` are the rows whose leaf is one of its slot ids, bagged
+    or not; the root's pass looks for none, and multiplies every row.
+    `k_tiles` are the turns its steps' loops ran over every row chunk,
+    bit-section and feature block, `k_tiles_full` the turns with nothing
+    squeezed, `chunks_squeezed` the chunks (a bit-section's, a feature
+    block's) whose live lanes were squeezed: fewer than `k_tiles_full`
+    turns needed.
 
     Returns one dict a pass: pass ("root", "w00", ...), slots,
-    rows_live, rows_passed[, k_tiles, k_tiles_full, squeeze]."""
-    import numpy as np
-
-    from .ops.pallas_histogram import squeeze_tiles
-    applied = max(int(rec["num_leaves"]) - 1, 0)
-    count = np.array(rec["leaf_count"], np.float64)
-    built = np.zeros(applied)   # rows of the children a split's pass builds
-    for s in range(applied - 1, -1, -1):
-        left, right = int(rec["split_leaf"][s]), s + 1
-        built[s] = (min(count[left], count[right]) if subtract
-                    else count[left] + count[right])
-        count[left] += count[right]
+    rows_passed and PASS_COUNTS' fields."""
+    from .ops.pallas_histogram import PASS_COUNTS
     per_split = 1 if subtract else 2
     sizes = _wave_schedule(num_leaves, wave_max, slots, per_split)
-    passes = [("root", 1, float(num_data))]
-    s0 = 0
-    for wi, w in enumerate(sizes[:-1]):
-        passes.append((f"w{wi:02d}", w * per_split,
-                       float(built[s0:s0 + w].sum())))
-        s0 += w
+    names = [("root", 1)] + [(f"w{wi:02d}", w * per_split)
+                             for wi, w in enumerate(sizes[:-1])]
+    assert len(names) == len(counts), (len(names), len(counts))
     out = []
-    for name, w, live in passes:
-        one = {"pass": name, "slots": int(w), "rows_live": int(round(live)),
-               "rows_passed": int(num_data)}
-        if row_chunk and k_tile:
-            chunks = -(-(rows_padded or num_data) // row_chunk)
-            full = row_chunk // k_tile
-            a_chunk = min(live / chunks, row_chunk)
-            if name == "root":
-                tiles = full
-            elif squeeze_stage:
-                tiles = squeeze_tiles(a_chunk / row_chunk, row_chunk, k_tile,
-                                      squeeze_stage)
-            else:
-                tiles = -(-int(round(a_chunk)) // k_tile)
-            one["k_tiles"] = int(round(chunks * tiles))
-            one["k_tiles_full"] = int(chunks * full)
-            one["squeeze"] = ("none" if name == "root" else
-                              "columns" if squeeze_stage else "lanes")
+    for (name, w), row in zip(names, counts):
+        one = {"pass": name, "slots": int(w), "rows_passed": int(num_data),
+               **{k: int(v) for k, v in zip(PASS_COUNTS, row)}}
+        if name == "root":
+            one["rows_live"] = int(num_data)
         out.append(one)
     return out
 
@@ -1334,6 +1305,14 @@ def grow_tree_waved(bins_fm: jax.Array,
     Forced splits are not supported (the caller falls back to
     `grow_tree`).
 
+    Each pass's counts, as the multi-leaf Mosaic kernel counted them in
+    its steps (live rows, sub-tile turns, chunks squeezed:
+    ops/pallas_histogram.PASS_COUNTS), ride with the tree as
+    `TreeArrays.hist_counts`, [passes, len(PASS_COUNTS)] int32: no
+    fetch and no host work while training; they reach the host with the
+    records. None where any pass ran elsewhere (XLA twins, sparse,
+    per-shard).
+
     quant: optional (g_int [N] int-valued f32, h_int [N] int-valued f32,
     g_scale, h_scale) from the gradient discretizer. The histogram
     passes then run the int8 x int8 -> int32 kernel — the MXU pallas
@@ -1384,7 +1363,9 @@ def grow_tree_waved(bins_fm: jax.Array,
         # uint16 ids on either path, but no test trains through this one
         use_kernel_fused = (hist_impl == "pallas" and bundle is None
                             and shard_mesh is None and build_bins <= 256)
-    # every multi_raw(bins, ghT, row_leaf, ids, **step): `step` is what the
+    # every multi_raw(bins, ghT, row_leaf, ids, **step) returns (the
+    # histograms, the pass's counts: ops/pallas_histogram.PASS_COUNTS
+    # where a Mosaic kernel ran the pass, else None). `step` is what the
     # caller knows of the pass (all_live: the root's), handed to the
     # Mosaic kernels; the sparse and the per-shard builders have no use
     # for it
@@ -1394,7 +1375,7 @@ def grow_tree_waved(bins_fm: jax.Array,
             # MultiValBin analog, multi_val_sparse_bin.hpp:70)
             return hist_ops.hist_multi_sparse(
                 bins, ghT_, row_leaf, ids, num_features=num_features,
-                max_bins=max_bins, num_slots=ids.shape[0])
+                max_bins=max_bins, num_slots=ids.shape[0]), None
     elif quant is not None:
         g_int, h_int, g_scale, h_scale = quant
         with jax.named_scope("lgbm/gradient"):
@@ -1416,17 +1397,17 @@ def grow_tree_waved(bins_fm: jax.Array,
 
             def multi_raw(bins, ghT_unused, row_leaf, ids, **step):
                 return _multi_i32(bins, ghT_i8, row_leaf,
-                                  ids).astype(f32) * hscale_vec
+                                  ids).astype(f32) * hscale_vec, None
         else:
             # default-capable on every backend: the pallas MXU kernel
             # where Mosaic runs, the exact-integer XLA contraction
             # elsewhere — identical int32 histograms either way
             def multi_raw(bins, ghT_unused, row_leaf, ids, **step):
-                hist_i = hist_multi_int8(bins, ghT_i8, row_leaf, ids,
-                                         max_bins=build_bins,
-                                         num_slots=ids.shape[0],
-                                         impl=hist_impl, **step)
-                return hist_i.astype(f32) * hscale_vec
+                hist_i, counts = hist_multi_int8(bins, ghT_i8, row_leaf,
+                                                 ids, max_bins=build_bins,
+                                                 num_slots=ids.shape[0],
+                                                 impl=hist_impl, **step)
+                return hist_i.astype(f32) * hscale_vec, counts
     elif use_kernel_fused:
         def multi_raw(bins, ghT_unused, row_leaf, ids, **step):
             # gradient pass fused INTO the histogram kernel: reads
@@ -1443,7 +1424,7 @@ def grow_tree_waved(bins_fm: jax.Array,
             deterministic=hist_deterministic)
 
         def multi_raw(bins, ghT_, row_leaf, ids, **step):
-            return _multi_f32(bins, ghT_, row_leaf, ids)
+            return _multi_f32(bins, ghT_, row_leaf, ids), None
     else:
         def multi_raw(bins, ghT_, row_leaf, ids, **step):
             # num_slots = the wave's LIVE count: the pallas kernel's cost
@@ -1461,11 +1442,11 @@ def grow_tree_waved(bins_fm: jax.Array,
         group_of, offset_of, nb_arr = bundle
 
         def multi(bins, ghT_, row_leaf, ids, **step):
-            hg = multi_raw(bins, ghT_, row_leaf, ids,
-                           **step)                     # [S, G, B_tot, 3]
+            hg, counts = multi_raw(bins, ghT_, row_leaf, ids,
+                                   **step)             # [S, G, B_tot, 3]
             totals = jnp.sum(hg[:, 0], axis=1)  # [S, 3]
             return expand_bundle_hist(hg, group_of, offset_of, nb_arr,
-                                      max_bins, totals)
+                                      max_bins, totals), counts
     # the gradient/bagging element-wise product: skipped entirely when
     # the kernel computes gh in-place (fused_grad on the pallas path)
     with jax.named_scope("lgbm/gradient"):
@@ -1485,8 +1466,11 @@ def grow_tree_waved(bins_fm: jax.Array,
     # efficient one, so the root rides it too.
     with jax.named_scope("lgbm/hist/root"):
         root_ids = jnp.zeros((1,), jnp.int32)
-        root_hist = multi(bins_fm, ghT, jnp.zeros((num_data,), jnp.int32),
-                          root_ids, all_live=True)[0].astype(f32)
+        root_hist, root_counts = multi(
+            bins_fm, ghT, jnp.zeros((num_data,), jnp.int32), root_ids,
+            all_live=True)
+        root_hist = root_hist[0].astype(f32)
+    pass_counts = [root_counts]
     root_fmask = feature_mask if root_allowed is None else \
         feature_mask & root_allowed
     if hist_reduce == "scatter":
@@ -1599,8 +1583,8 @@ def grow_tree_waved(bins_fm: jax.Array,
         with jax.named_scope(f"lgbm/hist/w{wi:02d}"):
             if subtract_siblings:
                 small_ids = jnp.where(ys["valid"], ys["small_id"], -2)
-                wave_hists = multi(bins_fm, ghT, row_leaf,
-                                   small_ids)          # [W, F, B, 3]
+                wave_hists, counts = multi(bins_fm, ghT, row_leaf,
+                                           small_ids)  # [W, F, B, 3]
             else:
                 # no-subtraction ORACLE (tpu_wave_subtract=False): build
                 # BOTH children directly. Two slots per split — the
@@ -1610,8 +1594,9 @@ def grow_tree_waved(bins_fm: jax.Array,
                 # parity/traffic baseline.
                 lids = jnp.where(ys["valid"], ys["left_id"], -2)
                 rids = jnp.where(ys["valid"], ys["right_id"], -2)
-                wave_hists = multi(bins_fm, ghT, row_leaf,
-                                   jnp.concatenate([lids, rids]))
+                wave_hists, counts = multi(bins_fm, ghT, row_leaf,
+                                           jnp.concatenate([lids, rids]))
+            pass_counts.append(counts)
         with jax.named_scope(f"lgbm/split/w{wi:02d}"):
             pool, leaves = _wave_boundary_core(
                 pool, leaves, used_features, ys, wave_hists,
@@ -1635,6 +1620,8 @@ def grow_tree_waved(bins_fm: jax.Array,
         steps = jnp.arange(L - 1, dtype=jnp.int32)
         order = jnp.argsort(jnp.where(valid_all, steps, steps + L))
         records = jax.tree_util.tree_map(lambda a: a[order], records)
+        hist_counts = (None if any(c is None for c in pass_counts)
+                       else jnp.stack(pass_counts))
     num_leaves_out = 1 + n_applied
 
     tree_arrays = TreeArrays(
@@ -1651,6 +1638,7 @@ def grow_tree_waved(bins_fm: jax.Array,
         leaf_weight=leaves.sum_hess,
         leaf_count=leaves.count,
         num_leaves=num_leaves_out,
+        hist_counts=hist_counts,
     )
     return tree_arrays, row_leaf
 
@@ -1723,17 +1711,19 @@ class StreamTreeGrower:
 
     @jax.named_scope("lgbm/hist")
     def _multi(self, slab, gh_slab, rl_slab, ids):
+        """One slab's histograms; the kernel's counts of a slab are
+        left out (the streamed grower keeps no hist_counts)."""
         from .ops.pallas_histogram import hist_multi, hist_multi_int8
         if gh_slab.dtype == jnp.int8:
             return hist_multi_int8(slab, gh_slab, rl_slab, ids,
                                    max_bins=self.max_bins,
                                    num_slots=ids.shape[0],
-                                   impl=self._impl)
+                                   impl=self._impl)[0]
         return hist_multi(slab, gh_slab, rl_slab, ids,
                           max_bins=self.max_bins,
                           num_slots=ids.shape[0], impl=self._impl,
                           precision=self._precision,
-                          deterministic=self._deterministic)
+                          deterministic=self._deterministic)[0]
 
     @staticmethod
     def _scaled(acc, hscale):

@@ -115,28 +115,54 @@ def instruction_head(text: str) -> Optional[str]:
 def layer_of(op_name: str) -> Optional[str]:
     """The path component right after the last ``lgbm`` component of an
     ``op_name`` (innermost scope wins), or None without one."""
+    scope = scope_of(op_name)
+    return None if scope is None else scope.split("/", 1)[0]
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The scopes of an ``op_name`` below its last ``lgbm`` component:
+    the layer and the named scopes inside it, up to the first
+    transformation (``jit(...)``, ``vmap(...)``) or the operation's own
+    name (``lgbm/hist/w03/jit(f)/.../pallas_call``: ``hist/w03``), or
+    None without an ``lgbm`` scope."""
     parts = op_name.split("/")
     for i in range(len(parts) - 2, -1, -1):
         if parts[i] == "lgbm":
-            return parts[i + 1]
+            scope = [parts[i + 1]]
+            for part in parts[i + 2:-1]:
+                if "(" in part:
+                    break
+                scope.append(part)
+            return "/".join(scope)
     return None
 
 
-def parse_layer_table(hlo_text: str) -> Dict[str, str]:
-    """{``instruction_head``: layer} for every instruction of every
-    computation of an optimised HLO module (fusion roots, ``while``
-    bodies, custom calls, what sits inside a fused computation) whose
-    ``op_name`` lies under an ``lgbm/<layer>`` scope. Instructions
-    without ``op_name`` or without a scope are absent."""
-    table: Dict[str, str] = {}
+def parse_scope_tables(hlo_text: str
+                       ) -> Tuple[Dict[str, str], Dict[str, str]]:
+    """({``instruction_head``: layer}, {``instruction_head``: scope})
+    in one pass over an optimised HLO module: every instruction of every
+    computation (fusion roots, ``while`` bodies, custom calls, what sits
+    inside a fused computation) whose ``op_name`` lies under an
+    ``lgbm/<layer>`` scope; ``scope_of`` names its scope below the
+    layer. Instructions without ``op_name`` or without a scope are
+    absent."""
+    layers: Dict[str, str] = {}
+    scopes: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         op = _OP_NAME_RE.search(line)
-        layer = layer_of(op.group(1)) if op else None
-        if layer is not None:
+        scope = scope_of(op.group(1)) if op else None
+        if scope is not None:
             head = instruction_head(line)
             if head is not None:
-                table[head] = layer
-    return table
+                layers[head] = scope.split("/", 1)[0]
+                scopes[head] = scope
+    return layers, scopes
+
+
+def parse_layer_table(hlo_text: str) -> Dict[str, str]:
+    """{``instruction_head``: layer} of an optimised HLO module
+    (``parse_scope_tables``' first table)."""
+    return parse_scope_tables(hlo_text)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -289,7 +315,8 @@ class ProfileRegistry:
         self._dropped_slices = 0
         self._entries: Dict[str, Tuple[Any, tuple, dict]] = {}
         self._name_to_tag: Dict[str, str] = {}
-        # tag -> [latest executable dispatched, its layer table or None]
+        # tag -> [latest executable dispatched, its (layer table, scope
+        # table) or None]
         self._programs: Dict[str, list] = {}
         self._layer_s: Dict[str, float] = {}
         self._unattributed_s = 0.0
@@ -318,19 +345,30 @@ class ProfileRegistry:
         it may outlive its Booster) for ``layer_table``."""
         self._programs[tag] = [compiled, None]
 
-    def layer_table(self, tag: str) -> Optional[Dict[str, str]]:
-        """{``instruction_head``: layer} of the executable last
-        acquired under `tag` (see ``parse_layer_table``); None when
-        the tag has run no program or its text cannot be had."""
+    def _tables(self, tag: str):
         held = self._programs.get(tag)
         if held is None:
             return None
         if held[1] is None:
             try:
-                held[1] = parse_layer_table(held[0].as_text())
+                held[1] = parse_scope_tables(held[0].as_text())
             except Exception:
                 return None
         return held[1]
+
+    def layer_table(self, tag: str) -> Optional[Dict[str, str]]:
+        """{``instruction_head``: layer} of the executable last
+        acquired under `tag` (see ``parse_scope_tables``); None when
+        the tag has run no program or its text cannot be had."""
+        tables = self._tables(tag)
+        return None if tables is None else tables[0]
+
+    def scope_table(self, tag: str) -> Optional[Dict[str, str]]:
+        """{``instruction_head``: scope below the layer} of the same
+        executable (``hist/w03``, ``split/apply/w00``, ...; see
+        ``scope_of``), read in the same pass as ``layer_table``."""
+        tables = self._tables(tag)
+        return None if tables is None else tables[1]
 
     # -- window lifecycle ----------------------------------------------
     def start_window(self, source: str = "window",
@@ -653,3 +691,9 @@ def layer_table(tag: str) -> Optional[Dict[str, str]]:
     """``global_profile.layer_table``: what the benchmark's per-layer
     readers join a device trace with."""
     return global_profile.layer_table(tag)
+
+
+def scope_table(tag: str) -> Optional[Dict[str, str]]:
+    """``global_profile.scope_table``: the scope of each instruction
+    below its layer, for readers that tell a layer's passes apart."""
+    return global_profile.scope_table(tag)

@@ -62,8 +62,13 @@ step does with its row chunk of R rows:
   alone: at 28-32 features one feature block and 16384 rows a step,
   at 2000 features as many features a block as the accumulator leaves
   room for. `global_metrics.meta["hist_geometry"]` says what each traced
-  kernel took, `meta["hist_live_rows"]` (learner.hist_live_rows, while
-  a tracer session is live) what the passes of a tree multiplied.
+  kernel took.
+- Every step also counts what it did, into a small int32 output beside
+  the histograms: the live lanes it found, its loop's turns, and whether
+  it squeezed. A pass returns them summed (`PASS_COUNTS`); the grower
+  keeps them with the tree (`learner.TreeArrays.hist_counts`) and
+  `meta["hist_live_rows"]` publishes the newest trees' (learner.
+  hist_live_rows).
 
 Every per-row operand (gh channels, row->leaf ids, the fused kernel's
 score/label/weight/mask) enters the kernels LANE-DENSE, as ``[k, N]``
@@ -212,6 +217,19 @@ _K_TILE = 1024
 # sublanes of the row stack: a chunk's row operands as 32-bit rows, the
 # leaf ids and the squeeze's distances to go: one (8, 128) tile deep
 _STACK_ROWS = 8
+# what a multi-leaf pass returns beside its histograms, one int32 each
+# (_multi_slabs): the rows in a slot's leaf (counted by the steps; every
+# row in the root's pass, which looks for none, is 0 here), the loop's
+# turns and the chunks squeezed over every grid step and bit-section of
+# every feature block, the turns with nothing squeezed, the lanes a turn
+# multiplies and the feature blocks. 100 x rows_live x feature_blocks /
+# (k_tiles x k_tile) is the share of the lanes multiplied that were live
+PASS_COUNTS = ("rows_live", "k_tiles", "chunks_squeezed", "k_tiles_full",
+               "k_tile", "feature_blocks")
+# sublanes of a step's count block ([8, 128] int32 of its feature block):
+# the dead lanes of each lane column (lane l: column l mod 2^stage's),
+# the loop's turns and 1 where the chunk was squeezed (on every lane)
+_COUNT_DEAD, _COUNT_TURNS, _COUNT_SQUEEZED = 0, 1, 2
 
 
 def _step_vmem_bytes(g: HistGeometry, vpb: int, itemsize: int) -> int:
@@ -222,8 +240,10 @@ def _step_vmem_bytes(g: HistGeometry, vpb: int, itemsize: int) -> int:
     words, the leaf operands (three bf16 passes at the most) and one
     dot's one-hot and result. A vector temporary that is consumed as it
     is made lives in registers and is not counted, nor are the squeeze
-    network's (see _VMEM_LIMIT); tests/test_chip_compile.py asks the
-    compiler."""
+    network's (see _VMEM_LIMIT), nor the two 4 KiB buffers of the step's
+    count block (_COUNT_DEAD), which lie in that other half too:
+    counted here they would move geometries that sit at the limit;
+    tests/test_chip_compile.py asks the compiler."""
     rows = g.dot_feats * g.bp
     wide = 2 if g.bp > 256 else 1                   # uint16 ids
     tile = max(g.k_tile, g.root_tile)
@@ -540,20 +560,25 @@ def _multi_step(bins_ref, refs, *, geom: HistGeometry, vpb: int,
     of the stack are squeezed to the front of their columns, in place
     (_squeeze_lanes). Then the loop: a turn builds the leaf operand of
     one sub-tile of lanes, a lane dead where the squeeze left a stale
-    copy, and issues its dots."""
-    out_ref, stack_ref, words_ref = refs[-3:]
-    leafsel_ref, slots_ref = refs[-5:-3]
-    row_refs = refs[:-5]
+    copy, and issues its dots. Each bit-section adds to the feature
+    block's count block (`counts_ref`, _COUNT_DEAD) its columns' dead
+    lanes, its loop's turns and whether it squeezed; the root's pass
+    adds its turns only."""
+    out_ref, counts_ref, stack_ref, words_ref = refs[-4:]
+    leafsel_ref, slots_ref = refs[-6:-4]
+    row_refs = refs[:-6]
     n_ops = len(row_refs) // vpb
     cb = geom.row_chunk
     t = geom.k_tile if squeeze else geom.root_tile
     # read here, not in the loop's body, which interpret mode traces
     # apart from the grid
     tail = (*tail, pl.program_id(0) == tail[0] - 1)
+    count_row = lax.broadcasted_iota(jnp.int32, counts_ref.shape[1:], 0)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
+        counts_ref[...] = jnp.zeros_like(counts_ref)
 
     for v in range(vpb):
         ops = [row_refs[k * vpb + v] for k in range(n_ops)]
@@ -580,11 +605,18 @@ def _multi_step(bins_ref, refs, *, geom: HistGeometry, vpb: int,
             tallest = (cb >> fs) - jnp.min(dead, axis=1, keepdims=True)[0, 0]
             n_tiles = ((tallest << fs) + (t - 1)) // t
             sparse = n_tiles < cb // t
+            counts_ref[0] += jnp.where(
+                count_row == _COUNT_DEAD, dead,
+                jnp.where(count_row == _COUNT_TURNS, n_tiles,
+                          jnp.where((count_row == _COUNT_SQUEEZED) & sparse,
+                                    1, 0)))
             # the bins as 32-bit words in the scratch the loop reads
             words_ref[...] = bins_ref.bitcast(jnp.int32)[...]
             pl.when(sparse)(functools.partial(
                 _squeeze_lanes, words_ref, stack_ref, togo_row, fs))
             bins_src = words_ref.bitcast(bins_ref.dtype)
+        else:
+            counts_ref[0] += jnp.where(count_row == _COUNT_TURNS, n_tiles, 0)
 
         def tile(i, carry):
             at = pl.ds(pl.multiple_of(i * t, t), t)
@@ -668,22 +700,23 @@ _MAX_SLOTS = 128 // 3
 
 def _packed_multi_call(bins_fm, row_vecs, leaf_ids, *, max_bins: int,
                        num_slots: int, **kw):
-    """Histograms [num_slots, F, B, 3] of the multi-leaf kernels (int32
-    for int8 operands, else float32): `_multi_slabs` less the padding.
-    row_vecs: list of ([N] or [N, k] array, pad_value) pairs."""
+    """(histograms [num_slots, F, B, 3] of the multi-leaf kernels, int32
+    for int8 operands, else float32; the pass's counts, [PASS_COUNTS]
+    int32): `_multi_slabs` less the padding. row_vecs: list of ([N] or
+    [N, k] array, pad_value) pairs."""
     assert num_slots <= _MAX_SLOTS, "num_slots capped at 42 by MXU columns"
     ids = jnp.pad(leaf_ids.astype(jnp.int32), (0, _MAX_SLOTS - num_slots),
                   constant_values=-2)
-    out = _multi_slabs(bins_fm, tuple(v for v, _ in row_vecs), ids,
-                       pads=tuple(p for _, p in row_vecs),
-                       max_bins=max_bins, **kw)
+    out, counts = _multi_slabs(bins_fm, tuple(v for v, _ in row_vecs), ids,
+                               pads=tuple(p for _, p in row_vecs),
+                               max_bins=max_bins, **kw)
     num_features = bins_fm.shape[0]
     # [F', bp, 128] -> [F, B, J, 3] -> [J, F, B, 3]: padded features,
     # a slab's rows at max_bins and beyond (never hit) and the columns
     # past the last slot go
     out = out[:num_features, :max_bins, :3 * num_slots]
     out = out.reshape(num_features, max_bins, num_slots, 3)
-    return jnp.moveaxis(out, 2, 0)
+    return jnp.moveaxis(out, 2, 0), counts
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -705,7 +738,8 @@ def _multi_slabs(bins_fm, vecs, leaf_ids, *, pads, max_bins: int,
     ids, -2 where a slot is unused. `grad_fn` / `has_weight`: the
     gradient-fused reader (_gh_reader). Returns every feature's slab,
     [F padded to whole blocks, bp, 128]: row b of a slab is bin b,
-    column 3 * slot + channel.
+    column 3 * slot + channel; and what the steps counted, as the
+    [PASS_COUNTS] int32 vector of the pass.
     """
     itemsize = 1 if int8 else 2
     num_features, n = bins_fm.shape
@@ -763,14 +797,19 @@ def _multi_slabs(bins_fm, vecs, leaf_ids, *, pads, max_bins: int,
                     int8=int8, tail=tail, squeeze=not all_live,
                     gh_of=_gh_reader(grad_fn, has_weight))
 
-    out = pl.pallas_call(
+    # the histograms' block and the step's count block stay resident
+    # across the row chunks and are written back once a feature block
+    out, counts = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(
+        out_specs=[pl.BlockSpec((1, rows, 128), lambda j, i: (j, 0, 0),
+                                memory_space=pltpu.VMEM),
+                   pl.BlockSpec((1, 8, 128), lambda j, i: (j, 0, 0),
+                                memory_space=pltpu.VMEM)],
+        out_shape=[jax.ShapeDtypeStruct(
             (fblocks, rows, 128), jnp.int32 if int8 else jnp.float32),
+            jax.ShapeDtypeStruct((fblocks, 8, 128), jnp.int32)],
         scratch_shapes=[
             pltpu.VMEM((_STACK_ROWS, cb), jnp.int32),
             pltpu.VMEM((f_blk * data.dtype.itemsize // 4, cb), jnp.int32)],
@@ -779,23 +818,31 @@ def _multi_slabs(bins_fm, vecs, leaf_ids, *, pads, max_bins: int,
         interpret=_resolve_interpret(interpret),
         name=name,
     )(*operands)
-    return out.reshape(fp, geom.bp, 128)
+    # lanes 0 .. 2^stage - 1 of the dead row are the lane columns, each
+    # once; every feature block sees the same rows
+    t = geom.root_tile if all_live else geom.k_tile
+    dead = jnp.sum(counts[:, _COUNT_DEAD, :1 << geom.squeeze_stage])
+    live = 0 if all_live else n_rows - dead // fblocks
+    pass_counts = jnp.stack([
+        jnp.asarray(live, jnp.int32),
+        jnp.sum(counts[:, _COUNT_TURNS, 0]),
+        jnp.sum(counts[:, _COUNT_SQUEEZED, 0]),
+        *(jnp.int32(x) for x in (fblocks * n_rows // t, t, fblocks))])
+    return out.reshape(fp, geom.bp, 128), pass_counts
 
 
 def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
                    rows, tail):
     """Publish a kernel's geometry as it is traced: in the list
-    ``global_metrics.meta["hist_geometry"]`` beside ``hist_traffic``,
-    and as the args of a ``hist`` program span (``lgbm/hist`` in a
-    profiler session). It says whether the large step engaged at a
-    shape, or fell back for VMEM or for a section no large chunk
-    divides (`rows` are the padded rows a pass covers, `section` those of
-    one bit-section: `pack_factor` sections of `section / row_chunk`
-    chunks each); `dots_per_step` counts the dots a sub-tile of the last
-    feature block issues and `tail_features` the features of its last
-    dot (those of a whole dot where nothing is cut)."""
+    ``global_metrics.meta["hist_geometry"]`` beside ``hist_traffic``. It
+    says whether the large step engaged at a shape, or fell back for
+    VMEM or for a section no large chunk divides (`rows` are the padded
+    rows a pass covers, `section` those of one bit-section:
+    `pack_factor` sections of `section / row_chunk` chunks each);
+    `dots_per_step` counts the dots a sub-tile of the last feature block
+    issues and `tail_features` the features of its last dot (those of a
+    whole dot where nothing is cut)."""
     from ..obs.metrics import global_metrics
-    from ..obs.trace import global_tracer
     last_feats = tail[1]
     rec = {"kernel": name, "features": num_features, "max_bins": max_bins,
            "rows": rows, "section": rows // vpb, "bp": geom.bp,
@@ -810,8 +857,6 @@ def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
     seen = global_metrics.meta.setdefault("hist_geometry", [])
     if rec not in seen:
         seen.append(rec)
-    with global_tracer.span("hist", args=rec):
-        pass
 
 
 @functools.partial(jax.jit,
@@ -820,7 +865,7 @@ def _note_geometry(name, geom, *, vpb, grid, int8, num_features, max_bins,
 def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
                       leaf_ids: jax.Array, *, max_bins: int, num_slots: int,
                       precise="highest", interpret=None,
-                      all_live: bool = False) -> jax.Array:
+                      all_live: bool = False):
     """Histograms of up to `num_slots` leaves in ONE pass over the rows.
 
     The one-hot (bins) operand is leaf-independent, so packing the MXU's
@@ -832,7 +877,8 @@ def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
 
     bins_fm: [F, N] uint8/16 (or PackedBins); ghT: [N, 3] f32 pre-masked
     (grad, hess, w); row_leaf: [N] int32; leaf_ids: [num_slots] int32
-    (pad with -2). Returns hist [num_slots, F, B, 3] f32.
+    (pad with -2). Returns (hist [num_slots, F, B, 3] f32, the pass's
+    counts [PASS_COUNTS] int32).
     """
     packed = isinstance(bins_fm, PackedBins)
     return _packed_multi_call(
@@ -848,12 +894,13 @@ def hist_pallas_multi(bins_fm: jax.Array, ghT: jax.Array, row_leaf: jax.Array,
 def hist_pallas_multi_int8(bins_fm: jax.Array, ghT_i8: jax.Array,
                            row_leaf: jax.Array, leaf_ids: jax.Array, *,
                            max_bins: int, num_slots: int, interpret=None,
-                           all_live: bool = False) -> jax.Array:
+                           all_live: bool = False):
     """Quantized multi-leaf histograms: one pass, int32 accumulation.
 
     ghT_i8: [N, 3] int8 (quantized grad, quantized hess, {0,1} weight),
-    pre-masked. Returns [num_slots, F, B, 3] int32 — callers scale by
-    (g_scale, h_scale, 1) to recover the f32 statistics. Safe for
+    pre-masked. Returns ([num_slots, F, B, 3] int32, the pass's counts
+    [PASS_COUNTS]) — callers scale the histograms by (g_scale, h_scale,
+    1) to recover the f32 statistics. Safe for
     N < 2^31 / (num_grad_quant_bins): |g_int| <= bins/2, so per-bin int32
     sums cannot overflow at any realistic scale.
     """
@@ -872,11 +919,12 @@ def hist_pallas_multi_fused(bins_fm, score, label, weight, mask, row_leaf,
                             leaf_ids, *, grad_fn, max_bins: int,
                             num_slots: int, precise="highest",
                             interpret=None,
-                            all_live: bool = False) -> jax.Array:
+                            all_live: bool = False):
     """Multi-leaf histograms with the gradient pass fused in: operands
     are (score, label[, weight], mask) instead of a pre-built ghT, and
     grad_fn (the objective's pointwise gradient) runs inside the kernel.
-    Accepts PackedBins or raw [F, N] bins. Returns [S, F, B, 3]."""
+    Accepts PackedBins or raw [F, N] bins. Returns ([S, F, B, 3], the
+    pass's counts [PASS_COUNTS])."""
     has_weight = weight is not None
     vecs = [(score.astype(jnp.float32), 0.0),
             (label.astype(jnp.float32), 0.0)]
@@ -1038,8 +1086,10 @@ def hist_multi(bins_fm, ghT, row_leaf, leaf_ids, *, max_bins: int,
                num_slots: int, impl: str = "xla",
                precision: str = "highest",
                deterministic: bool = False,
-               all_live: bool = False) -> jax.Array:
-    """`all_live`: the caller knows every row to be in a slot's leaf (the
+               all_live: bool = False):
+    """(histograms, the pass's counts or None): the pallas kernel's
+    (hist_pallas_multi), or the XLA twin's, which counts nothing.
+    `all_live`: the caller knows every row to be in a slot's leaf (the
     root's pass); the kernel's step then looks for no live rows."""
     if impl == "pallas" and not deterministic:
         return hist_pallas_multi(bins_fm, ghT, row_leaf, leaf_ids,
@@ -1052,7 +1102,7 @@ def hist_multi(bins_fm, ghT, row_leaf, leaf_ids, *, max_bins: int,
         bins_fm = unpack_bins(bins_fm).astype(jnp.uint8)
     return hist_multi_xla(bins_fm, ghT, row_leaf, leaf_ids,
                           max_bins=max_bins, num_slots=num_slots,
-                          deterministic=deterministic)
+                          deterministic=deterministic), None
 
 
 def hist_multi_int8_xla(bins_fm, ghT_i8, row_leaf, leaf_ids, *,
@@ -1090,19 +1140,20 @@ def hist_multi_int8_xla(bins_fm, ghT_i8, row_leaf, leaf_ids, *,
 
 def hist_multi_int8(bins_fm, ghT_i8, row_leaf, leaf_ids, *, max_bins: int,
                     num_slots: int, impl: str = "xla",
-                    all_live: bool = False) -> jax.Array:
+                    all_live: bool = False):
     """Quantized multi-leaf histogram dispatch: the pallas MXU kernel on
     device backends, the exact-integer XLA contraction elsewhere. Both
     return identical int32 histograms (asserted in tests/test_waved.py),
     which is what lets the waved grower run quantized training on any
-    backend — ROADMAP item 3's "promote int8 to default-capable"."""
+    backend — ROADMAP item 3's "promote int8 to default-capable". Returns
+    (histograms, the kernel's counts, or None from the XLA twin)."""
     if impl == "pallas":
         return hist_pallas_multi_int8(bins_fm, ghT_i8, row_leaf, leaf_ids,
                                       max_bins=max_bins,
                                       num_slots=num_slots,
                                       all_live=all_live)
     return hist_multi_int8_xla(bins_fm, ghT_i8, row_leaf, leaf_ids,
-                               max_bins=max_bins, num_slots=num_slots)
+                               max_bins=max_bins, num_slots=num_slots), None
 
 
 @functools.partial(jax.jit,

@@ -252,7 +252,9 @@ def make_sharded_feature_grow(mesh, *, num_leaves: int, max_bins: int,
     rep = P()
     meta_spec = FeatureMeta(*([rep] * len(FeatureMeta._fields)))
     hp_spec = SplitHyperParams(*([rep] * len(SplitHyperParams._fields)))
-    tree_spec = TreeArrays(*([rep] * len(TreeArrays._fields)))
+    # no histogram pass of this learner is counted (hist_counts None)
+    tree_spec = TreeArrays(*([rep] * len(TreeArrays._fields)))._replace(
+        hist_counts=None)
     from .mesh import shard_map as _shard_map
     sharded = _shard_map(
         grow, mesh=mesh,

@@ -329,7 +329,9 @@ def make_sharded_voting_grow(mesh, *, num_leaves: int, max_bins: int,
     rep = P()
     meta_spec = FeatureMeta(*([rep] * len(FeatureMeta._fields)))
     hp_spec = SplitHyperParams(*([rep] * len(SplitHyperParams._fields)))
-    tree_spec = TreeArrays(*([rep] * len(TreeArrays._fields)))
+    # no histogram pass of this learner is counted (hist_counts None)
+    tree_spec = TreeArrays(*([rep] * len(TreeArrays._fields)))._replace(
+        hist_counts=None)
     sharded = mesh_lib.shard_map(
         grow, mesh=mesh,
         in_specs=(data, rows, rows, rows, rep, meta_spec, hp_spec, rep),

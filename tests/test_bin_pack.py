@@ -111,17 +111,17 @@ def test_packed_hist_bit_parity_quantized():
     ref = hist_multi_xla(jnp.asarray(bins), ghT, rl, ids,
                          max_bins=b, num_slots=slots)
     # f32 multi kernel, packed: exact integer sums -> bitwise
-    pal = hist_pallas_multi(pb, ghT, rl, ids, max_bins=b, num_slots=slots,
-                            interpret=True)
+    pal, _ = hist_pallas_multi(pb, ghT, rl, ids, max_bins=b,
+                               num_slots=slots, interpret=True)
     np.testing.assert_array_equal(np.asarray(pal), np.asarray(ref))
     # int8 kernel: packed pallas == unpacked pallas == XLA int32 twin
     ghT_i8 = jnp.asarray(np.stack([g_int, h_int, mask], axis=1), jnp.int8)
     ref_i = hist_multi_int8_xla(jnp.asarray(bins), ghT_i8, rl, ids,
                                 max_bins=b, num_slots=slots)
     for bins_arg in (pb, jnp.asarray(bins)):
-        pal_i = hist_pallas_multi_int8(bins_arg, ghT_i8, rl, ids,
-                                       max_bins=b, num_slots=slots,
-                                       interpret=True)
+        pal_i, _ = hist_pallas_multi_int8(bins_arg, ghT_i8, rl, ids,
+                                          max_bins=b, num_slots=slots,
+                                          interpret=True)
         assert pal_i.dtype == jnp.int32
         np.testing.assert_array_equal(np.asarray(pal_i), np.asarray(ref_i))
     # single-leaf kernel + XLA build path on PackedBins
@@ -559,12 +559,14 @@ def test_int8_xla_matches_pallas_bitwise():
     ids = jnp.asarray([0, 3, 5, 1] + [-2] * (slots - 4), jnp.int32)
     x = hist_multi_int8_xla(jnp.asarray(bins), ghT_i8, rl, ids,
                             max_bins=b, num_slots=slots)
-    p = hist_pallas_multi_int8(jnp.asarray(bins), ghT_i8, rl, ids,
-                               max_bins=b, num_slots=slots, interpret=True)
+    p, _ = hist_pallas_multi_int8(jnp.asarray(bins), ghT_i8, rl, ids,
+                                  max_bins=b, num_slots=slots,
+                                  interpret=True)
     assert x.dtype == p.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(x), np.asarray(p))
-    d = hist_multi_int8(jnp.asarray(bins), ghT_i8, rl, ids, max_bins=b,
-                        num_slots=slots, impl="xla")
+    d, counts = hist_multi_int8(jnp.asarray(bins), ghT_i8, rl, ids,
+                                max_bins=b, num_slots=slots, impl="xla")
+    assert counts is None      # the XLA twin counts nothing
     np.testing.assert_array_equal(np.asarray(d), np.asarray(x))
 
 

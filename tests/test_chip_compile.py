@@ -402,6 +402,20 @@ def test_mosaic_kernel_is_named_and_in_the_hist_layer(fused_iter_table,
     assert names == {KERNEL[path]}, kernels
 
 
+def test_each_pass_kernel_keeps_its_own_scope(fused_iter_text, path):
+    """The nested jits of the kernel's call leave each of a tree's
+    thirteen kernel instructions the scope of the pass that made it
+    (``lgbm/hist/root``, ``lgbm/hist/w00`` ... ``w11``), so the
+    benchmark's ``hist_turn_us`` pairs a pass's counts with its kernel
+    event by scope, not only by order."""
+    from lightgbm_tpu.obs.profile import parse_scope_tables
+    _, scopes = parse_scope_tables(fused_iter_text)
+    kernels = [scope for head, scope in scopes.items()
+               if head.startswith(KERNEL[path] + ".")]
+    assert sorted(kernels) == ["hist/root"] + [f"hist/w{i:02d}"
+                                               for i in range(12)]
+
+
 @pytest.mark.parametrize("path,shape,layer", [
     ("int8", "s32[16384]", "partition"), ("int8", "s8[3,16384]", "hist"),
     ("int8", "f32[16384]", "score"), ("float", "s32[16384]", "partition"),

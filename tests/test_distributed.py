@@ -323,9 +323,9 @@ def test_int8_mesh_psum_exact_parity():
     sharded = _sharded_pallas_multi(mesh, max_bins=b, precision="highest",
                                     int8=True)
     out_mesh = sharded(bins, ghT_i8, row_leaf, ids)
-    out_single = hist_pallas_multi_int8(bins, ghT_i8, row_leaf, ids,
-                                        max_bins=b, num_slots=slots,
-                                        interpret=True)
+    out_single, _ = hist_pallas_multi_int8(bins, ghT_i8, row_leaf, ids,
+                                           max_bins=b, num_slots=slots,
+                                           interpret=True)
     out_xla = hist_multi_int8_xla(bins, ghT_i8, row_leaf, ids,
                                   max_bins=b, num_slots=slots)
     assert out_mesh.dtype == jnp.int32

@@ -81,12 +81,14 @@ def test_every_squeeze_gives_the_same_int8_histograms(max_bins, vpb, n):
 
 def test_squeeze_tiles_model_against_counted_tallest_columns():
     """`squeeze_tiles` (the expected tallest of 2^stage binomial lane
-    columns, in sub-tiles: what the geometry's rule weighs the stages by
-    and `learner.hist_live_rows` reckons a pass's K-sub-tiles with)
-    against a count on random rows, at the cells' two chunk sizes; and
-    the counter names the squeeze it reckoned with."""
+    columns, in sub-tiles: what the geometry's rule weighs the stages
+    by) against a count on random rows, at the cells' two chunk sizes;
+    and the counts of a pass (what the kernel's steps count, by the
+    NumPy count the step is held to exactly: test_waved.step_counts)
+    against the model, and as `learner.hist_live_rows` publishes them."""
     from lightgbm_tpu.learner import hist_live_rows
-    from lightgbm_tpu.ops.pallas_histogram import squeeze_tiles
+    from lightgbm_tpu.ops.pallas_histogram import PASS_COUNTS, squeeze_tiles
+    from tests.test_waved import step_counts
     r = np.random.RandomState(36)
     for row_chunk in (16384, 8192):
         for share in (0.5, 0.1, 0.01):
@@ -103,78 +105,102 @@ def test_squeeze_tiles_model_against_counted_tallest_columns():
                 assert model >= lanes - 0.03 * lanes
     assert squeeze_tiles(0.0, 16384, 1024, 7) == 0.0
     assert squeeze_tiles(1.0, 16384, 1024, 7) == 16.0
-    # a three-leaf tree of 65,536 rows in four 16,384-row chunks: the
-    # wave's pass has a quarter of the rows live
-    rec = {"split_leaf": np.array([0, 0]), "num_leaves": 3,
-           "leaf_count": np.array([8192.0, 16384.0, 40960.0])}
-    kw = dict(num_data=65536, num_leaves=3, wave_max=42, row_chunk=16384,
-              k_tile=1024)
-    lanes = hist_live_rows(rec, **kw)
-    columns = hist_live_rows(rec, **kw, squeeze_stage=7)
-    assert [p["squeeze"] for p in lanes] == ["none", "lanes"]
-    assert [p["squeeze"] for p in columns] == ["none", "columns"]
-    assert lanes[0] == columns[0] and lanes[0]["k_tiles"] == 64
-    assert (lanes[1]["k_tiles"], columns[1]["k_tiles"]) == (
-        16, round(4 * squeeze_tiles(0.25, 16384, 1024, 7))) == (16, 24)
-    assert hist_live_rows(rec, **kw, squeeze_stage=4)[1]["k_tiles"] == 20
+    # a three-leaf tree of 262,144 rows in sixteen 16,384-row chunks, in
+    # random order: the wave's pass has a quarter of them live
+    n = 1 << 18
+    row_leaf = np.where(r.rand(n) < 0.25, 1, 2)
+    geom = dict(row_chunk=16384, k_tile=1024, root_tile=2048, rows=n,
+                features=28, features_per_step=32, pack_factor=1)
+    root = step_counts(row_leaf, np.array([0]), dict(geom, squeeze_stage=0),
+                       all_live=True)
+    assert (root["k_tiles"], root["k_tiles_full"]) == (128, 128)
+    turns = []
+    for stage in (0, 4, 7):
+        wave = step_counts(row_leaf, np.array([1, -2]),
+                           dict(geom, squeeze_stage=stage))
+        assert wave["rows_live"] == (row_leaf == 1).sum()
+        turns.append(wave["k_tiles"])
+        # the model's expectation, counted on one tree's rows
+        assert abs(wave["k_tiles"] - 16 * squeeze_tiles(
+            0.25, 16384, 1024, stage)) <= 0.1 * wave["k_tiles"]
+        assert wave["chunks_squeezed"] == 16 and wave["k_tiles_full"] == 256
+        passes = hist_live_rows(
+            np.array([[root[k] for k in PASS_COUNTS],
+                      [wave[k] for k in PASS_COUNTS]]),
+            num_data=n, num_leaves=3, wave_max=42)
+        assert [p["pass"] for p in passes] == ["root", "w00"]
+        assert passes[0]["rows_live"] == n
+        assert {k: passes[1][k] for k in PASS_COUNTS} == wave
+    # one column: a chunk's live rows in whole sub-tiles; more columns,
+    # a taller tallest
+    live = (row_leaf == 1).reshape(16, -1).sum(1)
+    assert turns[0] == np.ceil(live / 1024).sum()
+    assert turns == sorted(turns)
 
 
 def test_live_rows_of_a_packed_pass_count_bit_sections():
-    """`learner.hist_live_rows` on PackedBins: a pass squeezes and
-    multiplies each bit-section's chunk on its own, so its K-sub-tiles
-    are counted over `pack_factor` x (section / row_chunk) chunks
-    (hist_geometry's padded `rows` over `row_chunk`), not over the byte
-    blocks and not over `num_data` rounded up. 9,000 rows at two values a
-    byte: sections of 6,144 bytes (three 2,048-row chunks each, six
-    chunk-sections where 9,000 rows rounded up give five). And through a
-    traced booster: the record takes the padded rows of the kernel the
-    booster traced."""
-    import numpy as np
-
+    """The counts of a pass on PackedBins: each bit-section's chunk is
+    squeezed and multiplied on its own, so the turns of a pass are
+    counted over `pack_factor` x (section / row_chunk) chunks, not over
+    the byte blocks and not over `num_data` rounded up. 9,000 rows at
+    two values a byte: sections of 6,144 bytes (three 2,048-row chunks
+    each, six chunk-sections where 9,000 rows rounded up give five), two
+    sub-tiles a chunk. Through an untraced booster on the Mosaic kernels
+    (interpret mode): the wave passes' live rows are the smaller
+    children's of the tree (no bagging)."""
     import lightgbm_tpu as lgb
-    from lightgbm_tpu.learner import hist_live_rows
+    from lightgbm_tpu.learner import HIST_SLOTS, _wave_schedule
     from lightgbm_tpu.obs.metrics import global_metrics
-    from lightgbm_tpu.obs.trace import global_tracer
     from lightgbm_tpu.ops import pallas_histogram as ph
     from lightgbm_tpu.ops.bin_pack import pack_bins_host
+    from tests.test_waved import small_children_rows
     n = 9000
     pb = pack_bins_host(np.zeros((28, n), np.uint8), 15)
     geom = ph._fb_geometry(28, 15, pb.vpb, 2, section=pb.section)
     assert (pb.section, geom.row_chunk, geom.k_tile) == (6144, 2048, 1024)
-    # a three-leaf tree: leaf 0 -> 4,500 + 4,500, then 900 + 3,600
-    rec = {"split_leaf": np.array([0, 0]), "num_leaves": 3,
-           "leaf_count": np.array([900.0, 4500.0, 3600.0])}
-    kw = dict(num_data=n, num_leaves=3, wave_max=42,
-              row_chunk=geom.row_chunk, k_tile=geom.k_tile)
-    packed = hist_live_rows(rec, **kw, rows_padded=pb.vpb * pb.section)
-    assert packed == [
-        {"pass": "root", "slots": 1, "rows_live": n, "rows_passed": n,
-         "k_tiles": 12, "k_tiles_full": 12, "squeeze": "none"},
-        # 4,500 live rows over six chunk-sections: 750 each, one sub-tile
-        {"pass": "w00", "slots": 1, "rows_live": 4500, "rows_passed": n,
-         "k_tiles": 6, "k_tiles_full": 12, "squeeze": "lanes"}]
-    by_rows = hist_live_rows(rec, **kw)
-    assert [p["k_tiles_full"] for p in by_rows] == [10, 10]
 
     r = np.random.RandomState(0)
     x = r.randn(n, 28)
     y = (x[:, 0] + 0.5 * x[:, 1] > 0).astype(np.float64)
-    was = global_tracer.enabled
-    global_tracer.enable()
-    try:
-        global_metrics.meta.pop("hist_geometry", None)
-        bst = lgb.Booster({"objective": "binary", "num_leaves": 7,
-                           "max_bin": 15, "min_data_in_leaf": 5,
-                           "verbosity": -1, "tpu_hist_impl": "pallas"},
-                          lgb.Dataset(x, label=y))
-        bst.update()
-        bst.model_to_string()
-    finally:
-        global_tracer.enabled = was
+    global_metrics.meta.pop("hist_geometry", None)
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 7,
+                       "max_bin": 15, "min_data_in_leaf": 5,
+                       "verbosity": -1, "tpu_hist_impl": "pallas"},
+                      lgb.Dataset(x, label=y))
+    bst.update()
+    bst.model_to_string()
     step = global_metrics.meta["hist_geometry"][-1]
     assert (step["pack_factor"], step["section"], step["rows"]) == \
         (2, 6144, 12288)
     passes = global_metrics.meta["hist_live_rows"][-1]
-    full = step["rows"] // step["row_chunk"] * (
-        step["row_chunk"] // step["k_tile"])
-    assert {p["k_tiles_full"] for p in passes} == {full} == {12}
+    assert {p["k_tiles_full"] for p in passes if p["pass"] != "root"} == {12}
+    tree, = bst._gbdt._host_models[-1]
+    schedule = _wave_schedule(7, 42, HIST_SLOTS)
+    assert [p["rows_live"] for p in passes[1:]] == \
+        small_children_rows(tree, schedule)
+    # the root's pass multiplies whole chunks: `root_tile` rows a turn
+    assert passes[0]["k_tiles"] == passes[0]["k_tiles_full"] == \
+        step["rows"] // step["root_tile"]
+
+
+@pytest.mark.usefixtures("release_executables")
+def test_counting_kernel_leaves_the_model_alone():
+    """The kernel counts beside its histograms and changes none of
+    them: int8 gradients sum integers, so a booster on the Mosaic
+    kernels (interpret mode) writes the model text of one on the XLA
+    twins, bit for bit, and its passes' counts are published."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs.metrics import global_metrics
+    r = np.random.RandomState(5)
+    x = r.randn(4000, 8)
+    y = (x[:, 0] - x[:, 2] ** 2 + 0.3 * r.randn(4000) > 0).astype(float)
+    texts = {}
+    for impl in ("xla", "pallas"):
+        global_metrics.meta.pop("hist_live_rows", None)
+        bst = lgb.train({"objective": "binary", "num_leaves": 15,
+                         "use_quantized_grad": True, "verbosity": -1,
+                         "tpu_hist_impl": impl},
+                        lgb.Dataset(x, label=y), num_boost_round=3)
+        texts[impl] = bst.model_to_string().split("parameters:")[0]
+        assert ("hist_live_rows" in global_metrics.meta) == (impl == "pallas")
+    assert texts["pallas"] == texts["xla"]

@@ -154,6 +154,32 @@ def test_layer_table_places(head):
     assert obs_profile.parse_layer_table(HLO)[head] == TABLE_CASES[head]
 
 
+# the scope below the layer, read in the same pass: a wave's kernel
+# keeps its pass (the nested jit and the kernel's own name end it), a
+# while its scopes (the while itself is the operation's name)
+SCOPE_CASES = {
+    "%lgbm_hist_multi_int8.1 = s32[4,504,128]{2,1,0:T(8,128)S(1)}":
+        "hist/root",
+    "%while.3 = (s32[], f32[8]{0})": "split/apply/w03",
+    "%fusion.7 = f32[8]{0}": "partition",
+    "%all-reduce.2 = f32[8]{0}": "collective",
+    "%fusion.9 = f32[8]{0:T(256)}": "score",
+}
+
+
+@pytest.mark.parametrize("head", sorted(SCOPE_CASES))
+def test_scope_table_names_the_scope_below_the_layer(head):
+    layers, scopes = obs_profile.parse_scope_tables(HLO)
+    assert scopes[head] == SCOPE_CASES[head]
+    assert layers == obs_profile.parse_layer_table(HLO)
+    assert set(scopes) == set(layers)
+    assert all(scopes[h].split("/")[0] == layers[h] for h in layers)
+    assert obs_profile.scope_of(
+        "jit(f)/lgbm/hist/w03/jit(g)/jit(_multi_slabs)/k/pallas_call") \
+        == "hist/w03"
+    assert obs_profile.scope_of("jit(f)/copy") is None
+
+
 def test_layer_table_leaves_out_what_no_scope_claims():
     table = obs_profile.parse_layer_table(HLO)
     for head in ("%copy.4 = f32[8]{0}",            # op_name, no lgbm/
@@ -336,6 +362,8 @@ def test_taking_the_compiled_lowers_and_compiles_nothing_twice():
     assert buf.is_deleted() and float(out[0]) == 64.0
     assert reg.n_programs == 1
     assert set(obs_profile.layer_table("test/taken").values()) == {"score"}
+    assert set(obs_profile.scope_table("test/taken").values()) == {"score"}
+    assert obs_profile.scope_table("boosting/never_ran") is None
 
 
 @pytest.mark.parametrize("phase,root", [("train", "train"),

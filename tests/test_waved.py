@@ -286,7 +286,8 @@ def check_shared_step(kind, live, max_bins, vpb, f, slots, n, stage=None):
         want = ph.hist_multi_int8_xla(jnp.asarray(bins), gh, row_leaf,
                                       leaf_ids, **kw)
         vecs, reader = [(gh, 0)], {}
-        got = ph.hist_pallas_multi_int8(arg, gh, row_leaf, leaf_ids, **run)
+        got, counts = ph.hist_pallas_multi_int8(arg, gh, row_leaf, leaf_ids,
+                                                **run)
         assert got.dtype == jnp.int32
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     else:
@@ -298,11 +299,12 @@ def check_shared_step(kind, live, max_bins, vpb, f, slots, n, stage=None):
                                  **kw)
         if kind == "float":
             vecs, reader = [(gh, 0.0)], {}
-            got = ph.hist_pallas_multi(arg, gh, row_leaf, leaf_ids, **run)
+            got, counts = ph.hist_pallas_multi(arg, gh, row_leaf, leaf_ids,
+                                               **run)
         else:
             vecs = [(score, 0.0), (label, 0.0), (m, 0.0)]
             reader = dict(grad_fn=_binary_grad, has_weight=False)
-            got = ph.hist_pallas_multi_fused(
+            got, counts = ph.hist_pallas_multi_fused(
                 arg, score, label, None, m, row_leaf, leaf_ids,
                 grad_fn=_binary_grad, **run)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -313,19 +315,26 @@ def check_shared_step(kind, live, max_bins, vpb, f, slots, n, stage=None):
     else:
         assert np.asarray(got).any()
     vecs.append((row_leaf, -1))
-    slabs = np.asarray(ph._multi_slabs(
+    slabs, slab_counts = ph._multi_slabs(
         arg, tuple(v for v, _ in vecs),
         jnp.pad(leaf_ids, (0, ph._MAX_SLOTS - slots), constant_values=-2),
         pads=tuple(p for _, p in vecs), max_bins=max_bins,
         int8=kind == "int8", precise="highest", interpret=True,
-        all_live=live == "root", name="lgbm_hist_multi", **reader))
+        all_live=live == "root", name="lgbm_hist_multi", **reader)
+    slabs = np.asarray(slabs)
     from lightgbm_tpu.obs.metrics import global_metrics
-    # one record a squeeze stage this shape was traced with: the step's
-    # other numbers are the same in each
-    geom = [g for g in global_metrics.meta["hist_geometry"]
-            if (g["kernel"], g["features"], g["max_bins"], g["pack_factor"],
-                g["operand"] == "int8") == ("lgbm_hist_multi", f, max_bins,
-                                            vpb, kind == "int8")][-1]
+    # one record a squeeze stage this shape was traced with: the one the
+    # kernels ran with here
+    ran = ph._fb_geometry(f, max_bins, vpb, 1 if kind == "int8" else 2,
+                          **({"section": arg.section} if vpb > 1
+                             else {"rows": n}))
+    rows = vpb * arg.section if vpb > 1 else -(-n // ran.row_chunk) \
+        * ran.row_chunk
+    geom, = [g for g in global_metrics.meta["hist_geometry"]
+             if (g["kernel"], g["features"], g["max_bins"], g["pack_factor"],
+                 g["operand"] == "int8", g["squeeze_stage"], g["rows"]) == (
+                 "lgbm_hist_multi", f, max_bins, vpb, kind == "int8",
+                 ran.squeeze_stage, rows)]
     assert slabs.shape[0] % geom["features_per_step"] == 0
     assert slabs.shape[0] >= f and slabs.shape[1] == geom["bp"] >= max_bins
     # the dots cover the real features only: a padded feature's slab is
@@ -338,7 +347,40 @@ def check_shared_step(kind, live, max_bins, vpb, f, slots, n, stage=None):
     np.testing.assert_array_equal(
         slabs[:f, :max_bins, :3 * slots].reshape(f, max_bins, slots, 3),
         np.moveaxis(np.asarray(got), 0, 2))
+    want = step_counts(np.asarray(row_leaf), np.asarray(leaf_ids), geom,
+                       all_live=live == "root")
+    assert dict(zip(ph.PASS_COUNTS, np.asarray(counts).tolist())) == want
+    np.testing.assert_array_equal(np.asarray(slab_counts), np.asarray(counts))
     return np.asarray(got)
+
+
+def step_counts(row_leaf, leaf_ids, geom, *, all_live=False):
+    """What the steps of one multi-leaf pass count (ops/pallas_histogram.
+    PASS_COUNTS), counted here in NumPy on the same rows: a chunk is
+    `row_chunk` rows of ONE bit-section (byte j of a PackedBins feature
+    holds rows j, j + section, ...), padded rows (leaf -1) are dead, the
+    lane columns of a chunk are its lanes modulo 2^`squeeze_stage`, the
+    loop runs ceil(tallest column x columns / k_tile) turns and the chunk
+    is squeezed when that is fewer than all of them; every feature block
+    runs the same."""
+    cb, stage = geom["row_chunk"], geom["squeeze_stage"]
+    t = geom["root_tile"] if all_live else geom["k_tile"]
+    blocks = -(-geom["features"] // geom["features_per_step"])
+    leaf = np.full(geom["rows"], -1)
+    leaf[:len(row_leaf)] = row_leaf
+    turns = squeezed = 0
+    # [sections, chunks a section, lanes a column, columns]
+    live = np.isin(leaf, leaf_ids[leaf_ids >= 0]).reshape(
+        geom["pack_factor"], -1, cb >> stage, 1 << stage)
+    for chunk in live.reshape(-1, cb >> stage, 1 << stage):
+        n = (cb // t if all_live else
+             -(-int(chunk.sum(0).max()) * (1 << stage) // t))
+        turns += n
+        squeezed += n < cb // t
+    return {"rows_live": 0 if all_live else int(live.sum()),
+            "k_tiles": blocks * turns, "chunks_squeezed": blocks * squeezed,
+            "k_tiles_full": blocks * geom["rows"] // t, "k_tile": t,
+            "feature_blocks": blocks}
 
 
 def test_waved_quantized_grad_trains():
@@ -562,39 +604,54 @@ def test_batched_partition_through_grower(case):
         np.testing.assert_array_equal(a, b)
 
 
+def small_children_rows(tree, schedule):
+    """The child-count walk: the rows of each wave pass's smaller
+    children (the last wave has no pass), from the model's own child
+    pointers: what a bag-free tree's wave passes find live."""
+    def rows(child):
+        return (tree.leaf_count[~child] if child < 0
+                else tree.internal_count[child])
+
+    small = [min(rows(tree.left_child[s]), rows(tree.right_child[s]))
+             for s in range(tree.num_leaves - 1)]
+    out, at = [], 0
+    for w in schedule[:-1]:
+        out.append(int(round(sum(small[at:at + w]))))
+        at += w
+    return out
+
+
 def test_hist_live_rows_counter():
-    """`global_metrics.meta["hist_live_rows"]`: while a tracer session is
-    live each grown tree leaves, a histogram pass, the rows that pass
-    multiplied: every row for the root, then each wave's smaller children
-    by the wave schedule (the last wave's pass is skipped), counted here
-    from the model's own child pointers. Untraced, nothing is recorded,
-    and traced or not an iteration fetches nothing from the device for
-    it: the trees stay there until the model is asked for."""
+    """`global_metrics.meta["hist_live_rows"]`: each tree whose passes
+    ran on the Mosaic kernels (interpret mode here) leaves, a pass, what
+    the kernel's steps counted, with no tracer session: every row for the
+    root, then each wave's rows in a slot's leaf, which on a bag-free
+    tree are the smaller children's rows counted from the model's own
+    child pointers. An iteration fetches nothing for it: the trees stay
+    on the device until the model is asked for. Passes on the XLA twins
+    are counted by nobody, and nothing is recorded."""
     from lightgbm_tpu.learner import HIST_SLOTS, _wave_schedule
     from lightgbm_tpu.obs.metrics import global_metrics
     from lightgbm_tpu.obs.trace import global_tracer
+    assert not global_tracer.enabled
     X, y = make_binary(3000)
     params = {"objective": "binary", "num_leaves": 31, "verbosity": -1,
               "min_data_in_leaf": 5}
     global_metrics.meta.pop("hist_live_rows", None)
     bst = lgb.Booster(params, lgb.Dataset(X, label=y))
     bst.update()
+    bst.model_to_string()
+    assert "hist_live_rows" not in global_metrics.meta
+
+    bst = lgb.Booster({**params, "tpu_hist_impl": "pallas"},
+                      lgb.Dataset(X, label=y))
+    bst.update()
     bst.update()
     # the counter reads a tree as it reaches the host, and an iteration
     # brings none there
     assert not bst._gbdt._host_models and len(bst._gbdt._device_records) == 2
-    bst.model_to_string()
     assert "hist_live_rows" not in global_metrics.meta
-
-    was = global_tracer.enabled
-    global_tracer.enable()
-    try:
-        bst = lgb.Booster(params, lgb.Dataset(X, label=y))
-        bst.update()
-        bst.update()
-        bst.model_to_string()
-    finally:
-        global_tracer.enabled = was
+    bst.model_to_string()
     trees = [t for it in bst._gbdt._host_models for t in it]
     recorded = global_metrics.meta["hist_live_rows"]
     assert len(recorded) == len(trees) == 2
@@ -602,30 +659,30 @@ def test_hist_live_rows_counter():
                               HIST_SLOTS)
     for tree, passes in zip(trees, recorded):
         assert tree.num_leaves == 31
-
-        def rows(child):
-            return (tree.leaf_count[~child] if child < 0
-                    else tree.internal_count[child])
-
-        small = [min(rows(tree.left_child[s]), rows(tree.right_child[s]))
-                 for s in range(tree.num_leaves - 1)]
         assert [p["pass"] for p in passes] == ["root"] + [
             f"w{i:02d}" for i in range(len(schedule) - 1)]
         assert passes[0]["rows_live"] == passes[0]["rows_passed"] == 3000
-        at = 0
+        assert passes[0]["k_tiles"] == passes[0]["k_tiles_full"]
+        assert passes[0]["chunks_squeezed"] == 0
+        assert [p["rows_live"] for p in passes[1:]] == \
+            small_children_rows(tree, schedule)
         for p, w in zip(passes[1:], schedule):
             assert p["slots"] == w and p["rows_passed"] == 3000
-            assert p["rows_live"] == sum(small[at:at + w]) <= 1500
-            at += w
-    # K-sub-tiles, with the kernel's chunk and sub-tile: a three-leaf tree
-    # of 100 rows (leaf 0 -> 50 + 50, then 10 + 40), chunks of 32 rows and
-    # sub-tiles of 8; only the first wave has a pass
+            assert 0 < p["rows_live"] <= 1500
+            # a chunk's live rows need a turn at least; none needs more
+            # than all of them
+            assert (p["rows_live"] * p["feature_blocks"]
+                    <= p["k_tiles"] * p["k_tile"])
+            assert p["k_tiles"] <= p["k_tiles_full"]
+    # a pass's counts to its record: a three-leaf tree of 100 rows, one
+    # wave pass (the last wave has none)
     from lightgbm_tpu.learner import hist_live_rows
-    rec = {"split_leaf": np.array([0, 0]), "num_leaves": 3,
-           "leaf_count": np.array([10.0, 50.0, 40.0])}
-    assert hist_live_rows(rec, num_data=100, num_leaves=3, wave_max=42,
-                          row_chunk=32, k_tile=8) == [
-        {"pass": "root", "slots": 1, "rows_live": 100, "rows_passed": 100,
-         "k_tiles": 16, "k_tiles_full": 16, "squeeze": "none"},
-        {"pass": "w00", "slots": 1, "rows_live": 50, "rows_passed": 100,
-         "k_tiles": 8, "k_tiles_full": 16, "squeeze": "lanes"}]
+    counts = np.array([[0, 16, 0, 16, 8, 1], [50, 8, 1, 16, 8, 1]])
+    assert hist_live_rows(counts, num_data=100, num_leaves=3,
+                          wave_max=42) == [
+        {"pass": "root", "slots": 1, "rows_passed": 100, "rows_live": 100,
+         "k_tiles": 16, "chunks_squeezed": 0, "k_tiles_full": 16,
+         "k_tile": 8, "feature_blocks": 1},
+        {"pass": "w00", "slots": 1, "rows_passed": 100, "rows_live": 50,
+         "k_tiles": 8, "chunks_squeezed": 1, "k_tiles_full": 16,
+         "k_tile": 8, "feature_blocks": 1}]

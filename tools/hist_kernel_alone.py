@@ -187,13 +187,13 @@ def main():
         rl = leaves[shares[-1]][:n]
         gh = jnp.stack([g, h, w], axis=1)
         kw = dict(max_bins=B, num_slots=SLOTS)
-        got = ph.hist_pallas_multi_int8(bins, gh, rl, ids, **kw)
+        got, _ = ph.hist_pallas_multi_int8(bins, gh, rl, ids, **kw)
         want = ph.hist_multi_int8_xla(raw, gh, rl, ids, **kw)
         emit(check="int8 against its XLA twin",
              equal=bool(jnp.array_equal(got, want)))
         gf, hf = binary_grad(score, label, None)
         one = jnp.ones_like(gf)
-        got = ph.hist_pallas_multi_fused(
+        got, _ = ph.hist_pallas_multi_fused(
             bins, score, label, None, one, rl, ids, grad_fn=binary_grad,
             precise="highest", **kw)
         want = ph.hist_multi_xla(raw, jnp.stack([gf, hf, one], axis=1), rl,
