@@ -895,6 +895,11 @@ class GBDT:
         if self._use_waved():
             kw["wave_max"] = self._resolved_wave_max()
             kw["subtract_siblings"] = bool(self.config.tpu_wave_subtract)
+            # rows sharded over the data-parallel mesh with no shard_map
+            # to run under: the wave partition keeps its XLA form
+            mesh = getattr(self, "mesh", None)
+            kw["rows_gspmd"] = (getattr(self, "_shard_mesh", None) is None
+                                and mesh is not None and mesh.size > 1)
         if self._bundle is not None:
             kw["bundle"] = self._bundle
             kw["num_bundle_bins"] = self._num_bundle_bins

@@ -377,6 +377,45 @@ def _sharded_pallas_multi(shard_mesh, *, max_bins: int,
     return multi
 
 
+def _wave_partition(shard_mesh, rows_gspmd: bool, num_leaves: int, bundle,
+                    has_categorical: bool):
+    """`part_ops.apply_wave_splits` as the waved grower calls it, on the
+    wave's arrays alone, in the form `part_ops.wave_form` gives the
+    storage (the Pallas kernel on accelerator backends). Under a shard
+    mesh the kernel runs once a shard: a pallas_call does not partition
+    under GSPMD, and a row's move needs only its own leaf and bins and
+    the wave's facts, which every shard holds. Where GSPMD partitions the
+    rows with no shard mesh to run it under (`rows_gspmd`: data-parallel
+    XLA histograms, a sharded stream), the XLA form, which GSPMD
+    partitions."""
+    def part(row_leaf, bins_fm, *wave):
+        return part_ops.apply_wave_splits(
+            row_leaf, bins_fm, *wave, num_leaves, bundle, has_categorical,
+            form="xla" if rows_gspmd else None)
+    if rows_gspmd or shard_mesh is None or shard_mesh.size == 1:
+        return part
+    from jax.sharding import PartitionSpec as P
+
+    from .parallel.mesh import shard_map as _shard_map
+    axes = tuple(shard_mesh.axis_names)
+    row_axes = axes if len(axes) > 1 else axes[0]
+
+    def sharded(row_leaf, bins_fm, *wave):
+        max_bins = wave[5].shape[1]                 # the cat masks, [W, B]
+        if part_ops.wave_form(bins_fm, max_bins, num_leaves, bundle,
+                              has_categorical) != "kernel":
+            return part(row_leaf, bins_fm, *wave)
+        n = row_leaf.shape[0]
+        row_leaf, bins_fm = _pad_rows((row_leaf, bins_fm), (0, 1), n,
+                                      shard_mesh.size, (0, 0))
+        fn = _shard_map(part, mesh=shard_mesh,
+                        in_specs=(P(row_axes), P(None, row_axes))
+                        + (P(),) * len(wave),
+                        out_specs=P(row_axes))
+        return fn(row_leaf, bins_fm, *wave)[:n]
+    return sharded
+
+
 def grow_tree(bins_fm: jax.Array,
               grad: jax.Array,
               hess: jax.Array,
@@ -1258,7 +1297,8 @@ def grow_tree_waved(bins_fm: jax.Array,
                     batched_partition=None,
                     fused_grad=None,
                     subtract_siblings: bool = True,
-                    hist_deterministic: bool = False):
+                    hist_deterministic: bool = False,
+                    rows_gspmd: bool = False):
     """Leaf-wise growth with waved (batched) histogram construction.
 
     fused_grad: optional (pointwise_fn, label, weight_or_None, score)
@@ -1282,11 +1322,17 @@ def grow_tree_waved(bins_fm: jax.Array,
     the XLA histogram paths (`deterministic_hist` knob).
 
     batched_partition: apply each wave's splits in one pass over the
-    rows (partition.apply_wave_splits: compare-and-select, no per-row
-    gather) instead of one apply_split pass per split. None = auto: on
-    for accelerator backends, off on the CPU backend; always off for
-    COO sparse storage, which has no [F, N] matrix to select from.
-    PERF.md sections 5 and 6 hold what each costs on the chip.
+    rows (partition.apply_wave_splits: on accelerator backends one Pallas
+    kernel for uint8 and packed storage, compare-and-select in XLA for EFB
+    and categorical splits; no per-row gather either way) instead of one
+    apply_split pass per split. None = auto: on for accelerator backends,
+    off on the CPU backend; always off for COO sparse storage, which has
+    no [F, N] matrix to select from. PERF.md sections 5 and 6 hold what
+    each costs on the chip.
+
+    rows_gspmd: the rows are partitioned by GSPMD over a mesh that is
+    not `shard_mesh` (data-parallel XLA histograms): the wave partition
+    then keeps its XLA form, which GSPMD partitions (`_wave_partition`).
 
     Identical split mathematics to `grow_tree`, but histogram builds are
     batched: splits are applied in waves; at each wave boundary ONE
@@ -1527,6 +1573,8 @@ def grow_tree_waved(bins_fm: jax.Array,
     if batched_partition is None:
         batched_partition = not hist_ops.cpu_backend()
     use_batched_partition = sparse_shape is None and batched_partition
+    partition_wave = _wave_partition(shard_mesh if use_mesh else None,
+                                     rows_gspmd, L, bundle, has_categorical)
 
     all_records = []
     all_valid = []
@@ -1552,18 +1600,19 @@ def grow_tree_waved(bins_fm: jax.Array,
 
         if use_batched_partition:
             # ONE partition pass for the whole wave (dense, EFB and
-            # packed layouts on accelerator backends; each row moves at
-            # most once per wave — see partition.apply_wave_splits). The
-            # COO and CPU paths partitioned inside wave_step instead.
+            # packed layouts on accelerator backends, in the form
+            # _wave_partition picks; each row moves at most once per
+            # wave — see partition.apply_wave_splits). The COO and CPU
+            # paths partitioned inside wave_step instead.
             with jax.named_scope(f"lgbm/partition/w{wi:02d}"):
-                row_leaf = part_ops.apply_wave_splits(
+                row_leaf = partition_wave(
                     row_leaf, bins_fm, ys["left_id"], ys["right_id"],
                     ys["record"]["split_feature"],
                     ys["record"]["split_bin_threshold"],
                     ys["record"]["split_default_left"],
                     ys["record"]["split_cat_mask"], ys["valid"],
                     meta.num_bins, meta.missing_type,
-                    meta.is_categorical, L, bundle, has_categorical)
+                    meta.is_categorical)
 
         if wi == len(schedule) - 1:
             # the tree is full: the children of the final wave can never
@@ -1758,14 +1807,18 @@ class StreamTreeGrower:
         """One slab's wave work: batched partition, then (except for
         the final wave, whose children can never split) the boundary
         histogram contribution — one upload serves both."""
+        mesh = getattr(self.plan, "mesh", None)
+        partition_wave = _wave_partition(
+            None, mesh is not None and mesh.size > 1, self.L, None,
+            self._has_cat)
+
         @jax.named_scope("lgbm/partition")
         def part(slab_, rl_, wave_, meta_):
-            return part_ops.apply_wave_splits(
+            return partition_wave(
                 rl_, slab_, wave_["left_id"], wave_["right_id"],
                 wave_["feat"], wave_["thr"], wave_["dleft"],
                 wave_["cmask"], wave_["valid"], meta_.num_bins,
-                meta_.missing_type, meta_.is_categorical, self.L, None,
-                self._has_cat)
+                meta_.missing_type, meta_.is_categorical)
 
         if not with_hist:
             return self._prog("wave_last", part)(slab, rl_slab, wave,

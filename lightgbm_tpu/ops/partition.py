@@ -14,6 +14,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import histogram as hist_ops, pallas_partition
 from .bin_pack import PackedBins, unpack_feature, unpack_rows
 from .split import MISSING_NAN
 from ..obs.metrics import global_metrics
@@ -198,7 +199,8 @@ def apply_wave_splits(row_leaf: jax.Array, bins_fm: jax.Array,
                       valid: jax.Array, num_bins: jax.Array,
                       missing_type: jax.Array, is_categorical: jax.Array,
                       num_leaves: int, bundle=None,
-                      has_categorical: bool = True) -> jax.Array:
+                      has_categorical: bool = True,
+                      form: str | None = None) -> jax.Array:
     """Apply a whole wave's W splits in ONE pass over the rows.
 
     A wave's split leaves are pairwise distinct and a leaf created
@@ -214,12 +216,30 @@ def apply_wave_splits(row_leaf: jax.Array, bins_fm: jax.Array,
     with the W split leaves, and its bin on that step's feature by
     comparing that feature with the F stored rows, so the pass reads
     row_leaf and every bin once. has_categorical (static) False traces
-    nothing categorical. PERF.md sections 5 and 6 (PR 27) hold what
-    the pass costs on the chip.
+    nothing categorical.
+
+    `form` (static; None: `wave_form`'s choice) is "kernel", the same
+    decision as one Pallas pass that reads row_leaf and the bins once and
+    writes row_leaf once (`pallas_partition.wave_partition`; interpret
+    mode on the CPU backend), or "xla", the fusions below. Both count
+    their traced call sites in `global_metrics.trace_counts` ("ops/partition_wave_kernel",
+    "ops/partition_wave") and publish the pass in
+    `global_metrics.meta["partition_wave"]`. PERF.md sections 5 and 6
+    hold what each costs on the chip.
     """
-    global_metrics.note_trace("ops/partition_wave")
     L = num_leaves
     B = cat_masks.shape[1]
+    if form is None:
+        form = wave_form(bins_fm, B, L, bundle, has_categorical)
+    pallas_partition.note_pass(form, row_leaf.shape[0], bins_fm)
+    if form == "kernel":
+        assert bundle is None and not has_categorical, form
+        global_metrics.note_trace("ops/partition_wave_kernel")
+        return pallas_partition.wave_partition(
+            row_leaf, bins_fm, leaf_ids, right_ids, features, thresholds,
+            default_lefts, valid, num_bins, missing_type, num_leaves=L,
+            max_bins=B, interpret=hist_ops.cpu_backend())
+    global_metrics.note_trace("ops/partition_wave")
     lids = jnp.where(valid, leaf_ids, L)
     m = row_leaf[None, :] == lids[:, None]              # [W, N], fused
     nan_code = jnp.where(missing_type[features] == MISSING_NAN,
@@ -250,6 +270,21 @@ def apply_wave_splits(row_leaf: jax.Array, bins_fm: jax.Array,
         go_left = jnp.where(r["is_cat"] == 1, cat_left, go_left)
     move = (r["hit"] == 1) & ~go_left
     return jnp.where(move, r["right"], row_leaf)
+
+
+def wave_form(bins_fm, max_bins: int, num_leaves: int, bundle=None,
+              has_categorical: bool = True) -> str:
+    """The form `apply_wave_splits` takes, by static facts alone:
+    "kernel" (`pallas_partition`) on accelerator backends for uint8 or
+    PackedBins storage with no EFB bundle and no categorical split, whose
+    split records fit 32 bits and whose grid step fits VMEM
+    (`pallas_partition.admits`); "xla" elsewhere (EFB, categorical,
+    uint16 bins, tables of more than 15,328 stored rows, the CPU
+    backend)."""
+    if hist_ops.cpu_backend() or bundle is not None or has_categorical:
+        return "xla"
+    return ("kernel" if pallas_partition.admits(bins_fm, max_bins, num_leaves)
+            else "xla")
 
 
 def apply_split(row_leaf: jax.Array, bins_fm: jax.Array,

@@ -72,7 +72,7 @@ QUICK_FILES = {
     "test_obs_export.py", "test_health.py", "test_resilience.py",
     "test_stream.py", "test_coldstart.py", "test_profile.py",
     "test_fleet.py", "test_watchdog.py", "test_shap.py",
-    "test_scatter.py",
+    "test_scatter.py", "test_partition_kernel.py",
 }
 
 

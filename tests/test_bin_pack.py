@@ -140,6 +140,39 @@ def test_packed_hist_bit_parity_quantized():
         np.asarray(ref_s))
 
 
+@pytest.mark.parametrize("vpb", [2, 4])
+def test_packed_partition_kernel_bit_parity(vpb):
+    """The wave partition's Pallas kernel (interpret mode) on PackedBins
+    reproduces the XLA form on the dense uint8 layout exactly (NaN
+    default-left routing, invalid wave entries), at two and four rows a
+    byte, with a short last bit-section."""
+    from lightgbm_tpu.ops import partition as part_ops
+    rng = np.random.RandomState(vpb)
+    N, F, L, W = 3001, 6, 15, 5
+    B = 15 if vpb == 2 else 3
+    for trial in range(3):
+        bins = rng.randint(0, B, (F, N)).astype(np.uint8)
+        pb = to_device(pack_bins_host(bins, B))
+        assert pb.vpb == vpb
+        valid = np.ones(W, bool)
+        valid[-1] = False
+        args = (jnp.asarray(rng.permutation(8)[:W].astype(np.int32)),
+                jnp.asarray((8 + np.arange(W)).astype(np.int32)),
+                jnp.asarray(rng.randint(0, F, W).astype(np.int32)),
+                jnp.asarray(rng.randint(0, B - 1, W).astype(np.int32)),
+                jnp.asarray(rng.rand(W) > 0.5), jnp.zeros((W, B), bool),
+                jnp.asarray(valid), jnp.full(F, B, jnp.int32),
+                jnp.asarray(rng.randint(0, 3, F).astype(np.int32)),
+                jnp.zeros(F, bool), L, None, False)
+        row_leaf = jnp.asarray(rng.randint(0, 8, N).astype(np.int32))
+        dense = part_ops.apply_wave_splits(row_leaf, jnp.asarray(bins),
+                                           *args, form="xla")
+        kernel = part_ops.apply_wave_splits(row_leaf, pb, *args,
+                                            form="kernel")
+        np.testing.assert_array_equal(np.asarray(dense), np.asarray(kernel))
+        assert (np.asarray(kernel) != np.asarray(row_leaf)).any()
+
+
 def test_packed_partition_bit_parity():
     """feature_bins / apply_split / apply_wave_splits on PackedBins must
     reproduce the dense uint8 layout exactly (incl. categorical bitsets,

@@ -416,28 +416,37 @@ def test_each_pass_kernel_keeps_its_own_scope(fused_iter_text, path):
                                                for i in range(12)]
 
 
+def _row_ops(table, shape):
+    """{instruction head: layer} of the fusions, and the wave partition's
+    kernel instructions, whose result is `shape`."""
+    return {head: lay for head, lay in table.items()
+            if ("fusion" in head.split(" = ")[0]
+                or head.startswith("%lgbm_partition_wave"))
+            and head.split(" = ")[1].startswith(shape + "{")}
+
+
 @pytest.mark.parametrize("path,shape,layer", [
     ("int8", "s32[16384]", "partition"), ("int8", "s8[3,16384]", "hist"),
     ("int8", "f32[16384]", "score"), ("float", "s32[16384]", "partition"),
     ("float", "f32[16384]", "score"), ("packed", "s32[16384]", "partition"),
-    ("packed", "s32[8192]", "partition"), ("packed", "f32[16384]", "score")],
+    ("packed", "s32[8192]", None), ("packed", "f32[16384]", "score")],
     indirect=["path"])
 def test_row_sized_fusions_are_one_layers(fused_iter_table, shape, layer):
-    """The row-sized fusions each fall under one layer: ``s32[N]`` the
-    wave partition's compare-and-select passes (PR 27; the ``u8[N]`` bin
-    gather it replaced is gone), ``s8[3, N]`` the int8 kernel's
-    operand, ``f32[N]`` the score update: its selects, written as the
-    vector they make before the add takes them (PR 31; the gather they
-    replaced is gone; PERF.md section 5); on PackedBins ``s32[N / 2]``
-    too, a bit-section's rows in ``bin_pack.unpack_rows`` (PR 35: the
-    whole [2, section] it made before left a fusion and a layout copy a
-    wave with no layer at all). The ``layer_*_s`` metrics are
-    defined by the scopes in the program: a change that moves a
-    ``named_scope`` moves seconds between them, and shows here first."""
-    got = {lay for head, lay in fused_iter_table.items()
-           if "fusion" in head.split(" = ")[0]
-           and head.split(" = ")[1].startswith(shape + "{")}
-    assert got == {layer}
+    """The row-sized operations each fall under one layer: ``s32[N]``
+    the wave partition, one Pallas kernel a wave
+    (``lgbm_partition_wave``; the compare-and-select fusions of its XLA
+    form and the ``u8[N]`` bin gather before them are gone), ``s8[3, N]`` the
+    int8 kernel's operand, ``f32[N]`` the score update: its selects,
+    written as the vector they make before the add takes them (the
+    gather they replaced is gone; PERF.md section 5). On PackedBins
+    nothing of a bit-section's rows, ``s32[N / 2]``, is written any
+    more: the XLA form's per-section unpack fusions (``unpack_rows``)
+    went into the kernel, which shifts a row's bits out of its byte in
+    VMEM. The ``layer_*_s`` metrics are defined by the scopes in the
+    program: a change that moves a ``named_scope`` moves seconds between
+    them, and shows here first."""
+    got = set(_row_ops(fused_iter_table, shape).values())
+    assert got == ({layer} if layer else set())
 
 
 def test_no_row_sized_fusion_in_the_split_layer(fused_iter_table):
@@ -536,10 +545,9 @@ def test_wide_float_iteration_fits_the_chip(one_chip):
     assert "gradient" not in table.values()
     for shape, layer in ((f"s32[{rows}]", "partition"),
                          (f"f32[{rows}]", "score")):
-        got = {lay for head, lay in table.items()
-               if "fusion" in head.split(" = ")[0]
-               and head.split(" = ")[1].startswith(shape + "{")}
+        got = set(_row_ops(table, shape).values())
         assert got == {layer}, (shape, got)
+    _one_partition_kernel_a_wave(compiled.as_text(), rows)
 
 
 def test_packed_iteration_places_every_row_sized_operation(one_chip):
@@ -567,14 +575,15 @@ def test_packed_iteration_places_every_row_sized_operation(one_chip):
                if head.startswith("%lgbm_hist_multi")}
     assert kernels == {KERNEL["packed"]}
     assert "gradient" not in table.values()
+    # a bit-section's rows (s32[section], s32[rows - section]) are no
+    # longer written out: the kernel unpacks a row's bits in VMEM
     for shape, layer in ((f"s32[{rows}]", "partition"),
-                         (f"s32[{section}]", "partition"),
-                         (f"s32[{rows - section}]", "partition"),
+                         (f"s32[{section}]", None),
+                         (f"s32[{rows - section}]", None),
                          (f"f32[{rows}]", "score")):
-        got = {lay for head, lay in table.items()
-               if "fusion" in head.split(" = ")[0]
-               and head.split(" = ")[1].startswith(shape + "{")}
-        assert got == {layer}, (shape, got)
+        got = set(_row_ops(table, shape).values())
+        assert got == ({layer} if layer else set()), (shape, got)
+    _one_partition_kernel_a_wave(text, rows)
     # outside the fused computations, whatever writes a row-sized result
     # and is no view, argument or asynchronous copy's half has a layer
     moves_nothing = (" bitcast(", " parameter(", " get-tuple-element(",
@@ -599,13 +608,17 @@ def test_packed_iteration_places_every_row_sized_operation(one_chip):
     assert len(unplaced) <= 2, unplaced
 
 
+@pytest.mark.parametrize("form", ["xla", "kernel"])
 @pytest.mark.parametrize("rows,features", [(1 << 20, 28), (1 << 17, 2000)])
 def test_wave_partition_keeps_row_sized_temporaries(one_chip, rows,
-                                                    features):
+                                                    features, form):
     """``apply_wave_splits`` alone, one full wave, at the narrow and the
     wide shape: what the program holds beside its operands stays under
-    four row-sized int32 vectors, so no [W, N] match matrix and no
-    [F, N] select is ever written out."""
+    four row-sized int32 vectors in the XLA form, so no [W, N] match
+    matrix and no [F, N] select is ever written out; the kernel,
+    which writes row_leaf in place (donated here, as the grower's is an
+    intermediate), holds less than one."""
+    from lightgbm_tpu.ops import histogram as hist_ops
     from lightgbm_tpu.ops import partition as part_ops
     s = _shapes(one_chip)
     w, b, leaves = SLOTS, 63, 255
@@ -614,8 +627,79 @@ def test_wave_partition_keeps_row_sized_temporaries(one_chip, rows,
                                        s((w,), jnp.bool_)]
     meta = [s((features,), jnp.int32)] * 2 + [s((features,), jnp.bool_)]
     fn = functools.partial(part_ops.apply_wave_splits, num_leaves=leaves,
-                           has_categorical=False)
-    mem = jax.jit(fn).lower(s((rows,), jnp.int32),
-                            s((features, rows), jnp.uint8), *step,
-                            *meta).compile().memory_analysis()
-    assert mem.temp_size_in_bytes < 4 * 4 * rows, mem
+                           has_categorical=False, form=form)
+    mp = pytest.MonkeyPatch()
+    # the Mosaic kernel, not its interpret mode: the program is the chip's
+    mp.setattr(hist_ops, "cpu_backend", lambda: False)
+    try:
+        compiled = jax.jit(fn, donate_argnums=0).lower(
+            s((rows,), jnp.int32), s((features, rows), jnp.uint8), *step,
+            *meta).compile()
+    finally:
+        mp.undo()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (4 if form == "xla" else 1) * 4 * rows, \
+        mem
+    assert ("lgbm_partition_wave" in compiled.as_text()) == (form == "kernel")
+
+
+@pytest.mark.parametrize("rows,features,max_bins,vpb", [
+    (1 << 14, 15_328, 63, 1), (1 << 14, 15_328, 15, 2),
+    (18_000, 1000, 3, 4)], ids=["widest-uint8", "widest-4bit", "2bit-wide"])
+def test_partition_kernel_compiles_at_its_edges(one_chip, rows, features,
+                                                max_bins, vpb):
+    """The partition kernel on the widest tables it admits, whose 1,024-
+    row steps come closest to its scoped VMEM (`pallas_partition.admits`:
+    a tile more keeps the XLA form), and on a 2-bit table of two row
+    blocks a section with its last bit-section empty: the chip's compiler
+    takes each."""
+    from lightgbm_tpu.ops import histogram as hist_ops
+    from lightgbm_tpu.ops import pallas_partition
+    from lightgbm_tpu.ops import partition as part_ops
+    s = _shapes(one_chip)
+    w, leaves = 5, 31
+    bins = _bins(s, rows, max_bins, vpb=vpb, features=features)
+    assert pallas_partition.admits(bins, max_bins, leaves)
+    step = [s((w,), jnp.int32)] * 4 + [s((w,), jnp.bool_),
+                                       s((w, max_bins), jnp.bool_),
+                                       s((w,), jnp.bool_)]
+    meta = [s((features,), jnp.int32)] * 2 + [s((features,), jnp.bool_)]
+    fn = functools.partial(part_ops.apply_wave_splits, num_leaves=leaves,
+                           has_categorical=False, form="kernel")
+    mp = pytest.MonkeyPatch()
+    mp.setattr(hist_ops, "cpu_backend", lambda: False)
+    try:
+        text = jax.jit(fn, donate_argnums=0).lower(
+            s((rows,), jnp.int32), bins, *step, *meta).compile().as_text()
+    finally:
+        mp.undo()
+    assert "lgbm_partition_wave" in text
+
+
+def _one_partition_kernel_a_wave(text, rows):
+    """A tree's 13 waves each write row_leaf once, by one kernel
+    instruction under its own ``lgbm/partition/wNN`` scope and in the
+    ``partition`` layer, and the partition layer holds no other
+    operation with a row-sized result."""
+    import re
+
+    from lightgbm_tpu.obs.profile import parse_scope_tables
+    table, scopes = parse_scope_tables(text)
+    row_sized = re.compile(rf"[\[,]{rows}[\],]")
+    ops = {head: scopes.get(head) for head, lay in table.items()
+           if lay == "partition"
+           and row_sized.search(head.split(" = ")[1].split("{")[0])}
+    assert all(h.startswith("%lgbm_partition_wave") for h in ops), ops
+    assert sorted(ops.values()) == [f"partition/w{i:02d}"
+                                    for i in range(13)], ops
+
+
+def test_int8_iteration_partitions_once_a_wave(one_chip):
+    """higgs-gpu63-int8.train's program at its own size, 63,000,000 x
+    28: one partition kernel a wave, in the partition layer. The packed
+    cell's program is held to the same in
+    test_packed_iteration_places_every_row_sized_operation, the wide
+    one's in test_wide_float_iteration_fits_the_chip."""
+    rows = 63_000_000
+    compiled = _compile_fused_iter(one_chip, "int8", rows=rows)
+    _one_partition_kernel_a_wave(compiled.as_text(), rows)

@@ -447,14 +447,15 @@ def _sequential(part_ops, c, bins, bundle=None):
     return np.asarray(seq)
 
 
-def _batched(part_ops, c, bins, L, bundle=None, has_categorical=True):
+def _batched(part_ops, c, bins, L, bundle=None, has_categorical=True,
+             form=None):
     return np.asarray(part_ops.apply_wave_splits(
         jnp.asarray(c["row_leaf"]), bins, jnp.asarray(c["leaves"]),
         jnp.asarray(c["rights"]), jnp.asarray(c["feats"]),
         jnp.asarray(c["thrs"]), jnp.asarray(c["dlefts"]),
         jnp.asarray(c["cmasks"]), jnp.asarray(c["valid"]),
         jnp.asarray(c["num_bins"]), jnp.asarray(c["missing"]),
-        jnp.asarray(c["is_cat"]), L, bundle, has_categorical))
+        jnp.asarray(c["is_cat"]), L, bundle, has_categorical, form=form))
 
 
 # the cases apply_wave_splits branches on (static shapes and storage
@@ -481,15 +482,25 @@ WAVE_CASES = {
     "efb-bundle": dict(N=900, F=7, B=16, L=15, W=5, bundle=True),
     "packed-4bit": dict(N=2500, F=6, B=15, L=15, W=5, packed=True),
     "packed-2bit": dict(N=4500, F=6, B=3, L=15, W=5, packed=True),
+    # the storage the Pallas kernel takes on the chip, each held
+    # to the kernel too: uint8 at the flagship tree, both packings
+    "kernel-W42-L255": dict(N=3000, F=28, B=63, L=255, W=42, live=213,
+                            has_categorical=False),
+    "kernel-packed-4bit": dict(N=2500, F=6, B=15, L=15, W=5, packed=True,
+                               has_categorical=False),
+    "kernel-packed-2bit": dict(N=4500, F=6, B=3, L=15, W=5, packed=True,
+                               has_categorical=False),
 }
 
 
+@pytest.mark.usefixtures("release_executables")
 @pytest.mark.parametrize("case", list(WAVE_CASES))
 def test_apply_wave_splits_matches_sequential(case):
     """The wave partition must be BIT-equal to the sequential
     apply_split chain it stands for: NaN default routing, invalid
     steps, categorical masks, every storage class and every record
-    width it packs."""
+    width it packs; where the storage admits the Pallas kernel
+    (`ops/pallas_partition`, interpret mode here), the kernel too."""
     from lightgbm_tpu.ops import partition as part_ops
     from lightgbm_tpu.ops.bin_pack import pack_bins_host, to_device
 
@@ -533,6 +544,9 @@ def test_apply_wave_splits_matches_sequential(case):
         seq = _sequential(part_ops, c, bins, bundle)
         got = _batched(part_ops, c, bins, L, bundle, has_cat)
         np.testing.assert_array_equal(seq, got)
+        if case.startswith("kernel"):
+            np.testing.assert_array_equal(seq, _batched(
+                part_ops, c, bins, L, bundle, has_cat, form="kernel"))
         if not c["valid"].any():
             np.testing.assert_array_equal(got, c["row_leaf"])
         else:
